@@ -1,0 +1,13 @@
+"""Compiles inside the window: EcDevice.compiles (ops/device.py, in
+/status) after the window less before.  Warm-up should leave none."""
+
+LAYER = "device"
+MOVES = "read_p99_ms"
+
+
+def read(run):
+    before = run.status_before.get("EcDevice", {}).get("compiles")
+    after = run.status_after.get("EcDevice", {}).get("compiles")
+    if before is None or after is None:
+        return None
+    return after - before

@@ -1,0 +1,11 @@
+"""1 - (union of the device's op intervals) / traced window, mean over
+the chips, in %, over the window's degraded reads."""
+
+from layers import idle_share
+
+LAYER = "device"
+MOVES = "read_p99_ms"
+
+
+def read(run):
+    return idle_share(run)
