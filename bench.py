@@ -206,16 +206,16 @@ def _e2e_one(base_dir, size_mb, reps=2, tracer=None, **enc_kw):
                     chrome = tracer.to_chrome()
                     attribution = _attribution(tracer, stats)
         mbps = round(raw_len / best_dt / 1e6, 1)
-        wall = stats.get("wall_s") or best_dt
         pipe = {k: round(v, 3) if isinstance(v, float) else v
                 for k, v in stats.items()}
-        # fraction of the wall the host was NOT blocked on the device
-        pipe["overlap_efficiency"] = round(
-            1.0 - stats.get("drain_wait_s", 0.0) / wall, 3)
         if spans is not None:
             pipe["spans"] = spans
         if attribution is not None:
             pipe["attribution"] = attribution
+            # fraction of the wall the host was NOT blocked on the
+            # device, from the same spans
+            pipe["overlap_efficiency"] = attribution.get(
+                "overlap_efficiency")
         return mbps, pipe, chrome
 
 
@@ -863,9 +863,8 @@ def _child(scratch_path: str, platform: str = "") -> None:
                 dt = time.perf_counter() - t0
                 stats = dict(enc.stats)
                 mbps = round(raw_len / dt / 1e6, 1)
-                wall = stats.get("wall_s") or dt
-                overlap = round(
-                    1.0 - stats.get("drain_wait_s", 0.0) / wall, 3)
+                attribution = _attribution(mc_tracer, stats)
+                overlap = attribution.get("overlap_efficiency")
                 entry = {"encode_mbps": mbps,
                          "dispatches": stats.get("dispatches"),
                          "overlap_efficiency": overlap}
@@ -886,7 +885,7 @@ def _child(scratch_path: str, platform: str = "") -> None:
                     ceiling = d2h * DATA_SHARDS_COUNT / PARITY_SHARDS_COUNT
                     mc["link_ceiling_mbps"] = round(ceiling, 1)
                     mc["e2e_link_efficiency"] = round(mbps / ceiling, 3)
-                mc["attribution"] = _attribution(mc_tracer, stats)
+                mc["attribution"] = attribution
                 mc["per_device"] = stats.get("per_device")
 
     section("multichip_encode", meas_multichip)

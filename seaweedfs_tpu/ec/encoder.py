@@ -49,10 +49,12 @@ from .layout import (
 DEFAULT_CHUNK = 4 * 1024 * 1024  # IO chunk; output is invariant to this
 
 
-def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx") -> None:
-    """`.idx` -> ascending-key `.ecx` (ec_encoder.go:27-54)."""
+def write_sorted_file_from_idx(base_file_name: str, ext: str = ".ecx") -> int:
+    """`.idx` -> ascending-key `.ecx` (ec_encoder.go:27-54).  Returns
+    the number of entries written."""
     db = MemDb.from_idx_file(base_file_name + ".idx")
     db.write_sorted_file(base_file_name + ext)
+    return len(db)
 
 
 

@@ -25,8 +25,8 @@ runs a depth-N asynchronous pipeline over fixed-shape dispatches:
     thread appends parity + its `.eci` crc stream in strict FIFO
     submission order — so checkpoint-resume and sidecar bytes are
     identical to the serial pipeline.  The critical thread only ever
-    blocks on the slot pool (`drain_wait_s`; `pipeline.drain_wait`
-    spans), while the wire time lands on the concurrent drain track
+    blocks on the slot pool (`pipeline.drain_wait` spans), while the
+    wire time lands on the concurrent drain track
     (`drain_s`; `pipeline.drain` spans off-thread) — the split the
     trace analyzer uses to tell "link-bound" from "drain-blocked";
   - host buffers are recycled from a small pool once their parity has
@@ -358,17 +358,8 @@ class StreamingEncoder:
         # unbounded growth would pin HBM-resident plane arrays forever
         self._plane_cache: OrderedDict[bytes, object] = OrderedDict()
         self._plane_cache_max = getattr(self, "_plane_cache_max_override", 8)
-        # per-call pipeline counters (read by bench.py's roofline section):
-        #   fill_s       host time filling input buffers from disk
-        #   write_s      host time writing shard outputs
-        #   drain_wait_s host time BLOCKED waiting for results — device
-        #                D2H fetches, or (host mode WITH the worker pool)
-        #                the not-yet-overlapped tail of the SIMD compute
-        #   dispatch_s   kernel submission; in SERIAL host mode (no pool,
-        #                single-core hosts) the whole SIMD compute lands
-        #                here instead
-        #   wall_s       whole-call wall clock
-        # overlap efficiency ~= 1 - drain_wait_s / wall_s
+        # per-call pipeline counters (_reset_stats); where the time of
+        # each stage went is the pipeline.* spans' job
         self.stats: dict[str, float] = {}
         # span tracer (observability/tracer.py): None follows the
         # process-global tracer, which is a no-op until enabled — the
@@ -502,9 +493,7 @@ class StreamingEncoder:
         # thread exists; drain threads mutate the DICT via the st
         # alias under _st_lock, never rebind the attribute
         self.stats = {  # weedlint: disable=W502 rebound before the drain threads exist
-                      "dispatches": 0, "fill_s": 0.0, "dispatch_s": 0.0,
-                      "write_s": 0.0, "drain_wait_s": 0.0, "setup_s": 0.0,
-                      "close_s": 0.0, "wall_s": 0.0, "bytes_in": 0,
+                      "dispatches": 0, "wall_s": 0.0, "bytes_in": 0,
                       "retries": 0, "fallbacks": 0, "worker_restarts": 0,
                       # integrity accounting: sidecar_s = crc build time
                       # on encodes, verify_s = survivor verification on
@@ -512,13 +501,13 @@ class StreamingEncoder:
                       # overhead figure)
                       "sidecar_s": 0.0, "verify_s": 0.0,
                       # async-drain accounting: drain_s = CONCURRENT
-                      # fetch time on the drainer track (drain_wait_s
-                      # stays "host thread BLOCKED"), parity_bytes_
-                      # drained = bytes actually pulled back across the
-                      # link (parity only — r/k of bytes_in, the proof
-                      # the drain never fetches data shards), drain_pool
-                      # = fetch threads the drainer ran with (0 = inline
-                      # serial drain)
+                      # fetch time on the drainer track (the host
+                      # thread's own blocking is the pipeline.drain_wait
+                      # spans), parity_bytes_drained = bytes actually
+                      # pulled back across the link (parity only — r/k
+                      # of bytes_in, the proof the drain never fetches
+                      # data shards), drain_pool = fetch threads the
+                      # drainer ran with (0 = inline serial drain)
                       "drain_s": 0.0, "parity_bytes_drained": 0,
                       "drain_pool": 0}
         self._restart_base = _restart_total()  # weedlint: disable=W502 rebound before the drain threads exist
@@ -800,7 +789,6 @@ class StreamingEncoder:
             in_arr = np.frombuffer(in_map, dtype=np.uint8)
             in_mv = memoryview(in_map)
             in_addr = in_arr.ctypes.data
-            st["setup_s"] = clock() - t_start
             # parity worker: a separate process mmaps the SAME file and
             # computes dispatch d+1's parity while this process sits in
             # pwrite for dispatch d — kernel-mode write time and SIMD
@@ -818,8 +806,7 @@ class StreamingEncoder:
             # inline compute without a lock.
             wstate: dict = {"worker": worker}
             slot_q: queue_mod.Queue = queue_mod.Queue()
-            ds = {"drain_s": 0.0, "write_s": 0.0, "fallback_s": 0.0,
-                  "parity_bytes": 0}
+            ds = {"drain_s": 0.0, "parity_bytes": 0}
             ds_lock = threading.Lock()
             drainer: Optional[AsyncDrainer] = None
 
@@ -887,15 +874,12 @@ class StreamingEncoder:
                     return parity  # slot recycles after the pwrite
                 with ds_lock:
                     ds["drain_s"] += fetch_s
-                t0 = clock()
                 with tr.span("pipeline.compute", dispatch=d_idx,
                              bytes=k * n):
                     matmul_ptrs(
                         mat,
                         [in_addr + base + i * block for i in range(k)],
                         [a + off for a in parity_mappings()], n)
-                with ds_lock:
-                    ds["fallback_s"] += clock() - t0
                 slot_q.put(slot)
                 return None
 
@@ -903,14 +887,11 @@ class StreamingEncoder:
                 if parity is None:  # fallback already stored via mmap
                     return
                 slot, n, off, base, block, d_idx = meta
-                t0 = clock()
                 with tr.span("pipeline.write", dispatch=d_idx,
                              kind="parity"):
                     for j in range(r):
                         os.pwrite(out_fds[k + j],
                                   memoryview(parity[j, :n]), off)
-                with ds_lock:
-                    ds["write_s"] += clock() - t0
                 # parity was pwritten straight from the shm out slot:
                 # only now may the worker compute into it again
                 slot_q.put(slot)
@@ -932,23 +913,19 @@ class StreamingEncoder:
                 except queue_mod.Empty:
                     pass
                 # every shm slot is in flight: the residual drain stall
-                t0 = clock()
-                try:
-                    with tr.span("pipeline.drain_wait"):
-                        deadline = time.monotonic() + max(
-                            4 * self.drain_timeout_s, 120.0)
-                        while True:
-                            try:
-                                return slot_q.get(timeout=0.2)
-                            except queue_mod.Empty:
-                                if drainer.error is not None:
-                                    raise drainer.error
-                                if time.monotonic() >= deadline:
-                                    raise RuntimeError(
-                                        "async drain stalled: no free "
-                                        "parity slot")
-                finally:
-                    st["drain_wait_s"] += clock() - t0
+                with tr.span("pipeline.drain_wait"):
+                    deadline = time.monotonic() + max(
+                        4 * self.drain_timeout_s, 120.0)
+                    while True:
+                        try:
+                            return slot_q.get(timeout=0.2)
+                        except queue_mod.Empty:
+                            if drainer.error is not None:
+                                raise drainer.error
+                            if time.monotonic() >= deadline:
+                                raise RuntimeError(
+                                    "async drain stalled: no free "
+                                    "parity slot")
 
             try:
                 out_off = 0
@@ -973,7 +950,6 @@ class StreamingEncoder:
                                      reason="dispatch_fault")
                         elif w is not None:
                             slot = acquire_slot()  # may block: backpressure
-                            t0 = clock()
                             submitted = False
                             with tr.span("pipeline.dispatch",
                                          dispatch=st["dispatches"],
@@ -996,26 +972,19 @@ class StreamingEncoder:
                                     tr.event("pipeline.fallback",
                                              dispatch=st["dispatches"],
                                              reason=reason)
-                            st["dispatch_s"] += clock() - t0
                             if submitted:
                                 d_idx = st["dispatches"]
                                 # data shards pwrite NOW, from the input
                                 # mapping, while the worker computes the
                                 # parity this dispatch just submitted
-                                t0 = clock()
                                 with tr.span("pipeline.write",
                                              dispatch=d_idx, kind="data"):
                                     for i in range(k):
                                         s = base + i * block
                                         os.pwrite(out_fds[i],
                                                   in_mv[s:s + n], out_off)
-                                st["write_s"] += clock() - t0
-                                # a blocking put on the bounded writer
-                                # queue is drain-stall time
-                                t0 = clock()
                                 drainer.submit((slot, n, out_off, base,
                                                 block, d_idx))
-                                st["drain_wait_s"] += clock() - t0
                                 st["dispatches"] += 1
                                 st["bytes_in"] += k * n
                                 out_off += n
@@ -1024,7 +993,6 @@ class StreamingEncoder:
                         # all k source rows fully inside the file: matmul
                         # in place from the mapping, parity stored
                         # straight into the output mappings
-                        t0 = clock()
                         with tr.span("pipeline.compute",
                                      dispatch=st["dispatches"], bytes=k * n):
                             matmul_ptrs(
@@ -1032,19 +1000,15 @@ class StreamingEncoder:
                                 [in_addr + base + i * block
                                  for i in range(k)],
                                 [a + out_off for a in parity_mappings()], n)
-                        st["dispatch_s"] += clock() - t0
-                        t0 = clock()
                         with tr.span("pipeline.write",
                                      dispatch=st["dispatches"], kind="data"):
                             for i in range(k):
                                 s = base + i * block
                                 os.pwrite(out_fds[i], in_mv[s:s + n],
                                           out_off)
-                        st["write_s"] += clock() - t0
                     else:
                         # tail entry: some rows cross EOF — stage through
                         # a zero-padded buffer (ec_encoder.go:172-176)
-                        t0 = clock()
                         with tr.span("pipeline.fill",
                                      dispatch=st["dispatches"], tail=True):
                             if tail_buf is None or tail_buf.shape[1] < n:
@@ -1056,8 +1020,6 @@ class StreamingEncoder:
                                 e = min(file_size, s + n)
                                 if e > s:
                                     tail_buf[i, :e - s] = in_arr[s:e]
-                        st["fill_s"] += clock() - t0
-                        t0 = clock()
                         buf = tail_buf[:, :n]
                         row = buf.strides[0]
                         with tr.span("pipeline.compute",
@@ -1067,24 +1029,19 @@ class StreamingEncoder:
                                 [buf.ctypes.data + i * row
                                  for i in range(k)],
                                 [a + out_off for a in parity_mappings()], n)
-                        st["dispatch_s"] += clock() - t0
-                        t0 = clock()
                         with tr.span("pipeline.write",
                                      dispatch=st["dispatches"], kind="data"):
                             for i in range(k):
                                 os.pwrite(out_fds[i], memoryview(buf[i]),
                                           out_off)
-                        st["write_s"] += clock() - t0
                     st["dispatches"] += 1
                     st["bytes_in"] += k * n
                     out_off += n
                 if drainer is not None:
                     # tail stall: the last in-flight parity finishes
                     # fetching + writing
-                    t0 = clock()
                     with tr.span("pipeline.drain_wait", final=True):
                         drainer.finish()
-                    st["drain_wait_s"] += clock() - t0
             finally:
                 if drainer is not None:
                     if drainer.inflight:
@@ -1107,8 +1064,6 @@ class StreamingEncoder:
                     # released below (the fetch fallback reads in_addr)
                     drainer.abort()
                     st["drain_s"] += ds["drain_s"]
-                    st["write_s"] += ds["write_s"]
-                    st["dispatch_s"] += ds["fallback_s"]
                     st["parity_bytes_drained"] += ds["parity_bytes"]
                 # the view and exported memoryview must drop before the
                 # mmap closes or close() raises BufferError
@@ -1118,7 +1073,6 @@ class StreamingEncoder:
             self._finish_sidecar_backfill(out_base, st, clock)
             ok = True
         finally:
-            t0 = clock()
             for pm in parity_maps:
                 try:
                     pm.close()
@@ -1129,7 +1083,6 @@ class StreamingEncoder:
             in_f.close()
             for f in outs:
                 f.close()
-            st["close_s"] = clock() - t0
             st["wall_s"] = clock() - t_start
             st["worker_restarts"] = int(_restart_total() -
                                         self._restart_base)
@@ -1291,8 +1244,7 @@ class StreamingEncoder:
         flags = {"degraded": False}  # terminal fault: rest goes CPU
         # concurrent-side accounting (drainer fetch threads + writer
         # thread own these keys; folded into st once the threads join)
-        ds = {"drain_s": 0.0, "write_s": 0.0, "sidecar_s": 0.0,
-              "fallback_s": 0.0, "parity_bytes": 0}
+        ds = {"drain_s": 0.0, "sidecar_s": 0.0, "parity_bytes": 0}
         ds_lock = threading.Lock()
         slot_q: queue_mod.Queue = queue_mod.Queue()
         drainer: Optional[AsyncDrainer] = None
@@ -1302,7 +1254,7 @@ class StreamingEncoder:
             — the only place kernel output crosses back to the host.
             Runs on the drainer's fetch pool in async mode, inline on
             the pipeline thread in serial mode.  Returns
-            (parity[:, :u], fetch_s, fallback_s, fetched_bytes)."""
+            (parity[:, :u], fetch_s, fetched_bytes)."""
             parity_dev, u, bi, d_idx, nfills = meta
             is_proc = isinstance(parity_dev, tuple) and \
                 parity_dev[0] == "proc"
@@ -1356,25 +1308,21 @@ class StreamingEncoder:
                     self._proc_worker is not None:
                 self._merge_worker_span(tr, self._proc_worker,
                                         root.span_id, d_idx)
-            fb_s = 0.0
             if parity is None:
                 # the input buffer is still intact: slots recycle only
                 # after their dispatch is fetched (or recomputed here),
                 # so the CPU codec can recompute losslessly
-                t0 = clock()
                 with tr.span("pipeline.fallback", dispatch=d_idx,
                              reason=reason):
                     parity = self._cpu_parity(bufs[bi][:, :u])
-                fb_s = clock() - t0
                 self._note_fallback(st, reason)
-            return parity[:, :u], fetch_s, fb_s, nbytes
+            return parity[:, :u], fetch_s, nbytes
 
         def drain_write_core(meta, parity):
             """Write ONE dispatch's parity + crc stream and advance the
             FIFO checkpoint; runs on the writer thread in async mode.
-            Returns (write_s, sidecar_s)."""
+            Returns the crc time (sidecar_s)."""
             parity_dev, u, bi, d_idx, nfills = meta
-            t0 = clock()
             sc = 0.0
             # entries pack side by side, so each parity row's bytes for
             # this dispatch are one contiguous slice
@@ -1391,31 +1339,26 @@ class StreamingEncoder:
                     for j in range(r):
                         sb.update(k + j, parity[j, :u])
                     sc = clock() - t1
-            w_s = clock() - t0
             # dispatch d_idx is fully drained AND written on every shard:
             # advance the resume checkpoint past its entries/bytes
             ck_e, ck_b = self._ckpt
             self._ckpt = (ck_e + nfills, ck_b + u)  # weedlint: disable=W502 writer thread owns the checkpoint while draining; the producer reads it only after the drainer is joined (happens-before)
-            return w_s, sc
+            return sc
 
         def drain_one():
             """Serial drain: fetch + write inline on the pipeline thread
             (fetch time IS host-blocked time here)."""
             meta = pending.popleft()
-            parity, fetch_s, fb_s, nbytes = drain_fetch_core(meta)
-            st["drain_wait_s"] += fetch_s
-            st["dispatch_s"] += fb_s
+            parity, _, nbytes = drain_fetch_core(meta)
             st["parity_bytes_drained"] += nbytes
-            w_s, sc = drain_write_core(meta, parity)
-            st["write_s"] += w_s
+            sc = drain_write_core(meta, parity)
             st["sidecar_s"] += sc
             free.append(meta[2])
 
         def drain_fetch_async(meta):
-            parity, fetch_s, fb_s, nbytes = drain_fetch_core(meta)
+            parity, fetch_s, nbytes = drain_fetch_core(meta)
             with ds_lock:
                 ds["drain_s"] += fetch_s
-                ds["fallback_s"] += fb_s
                 ds["parity_bytes"] += nbytes
             if not (isinstance(meta[0], tuple) and meta[0][0] == "proc"):
                 # device/host handles: the fetched parity is an
@@ -1426,9 +1369,8 @@ class StreamingEncoder:
             return parity
 
         def drain_write_async(meta, parity):
-            w_s, sc = drain_write_core(meta, parity)
+            sc = drain_write_core(meta, parity)
             with ds_lock:
-                ds["write_s"] += w_s
                 ds["sidecar_s"] += sc
             if isinstance(meta[0], tuple) and meta[0][0] == "proc":
                 # proc parity is a VIEW into the shm out slot (same
@@ -1446,23 +1388,19 @@ class StreamingEncoder:
                 pass
             # every slot is in flight: THIS is the pipeline's residual
             # drain stall — the one the async drain exists to shrink
-            t0 = clock()
-            try:
-                with tr.span("pipeline.drain_wait"):
-                    deadline = time.monotonic() + max(
-                        4 * self.drain_timeout_s, 120.0)
-                    while True:
-                        try:
-                            return slot_q.get(timeout=0.2)
-                        except queue_mod.Empty:
-                            if drainer.error is not None:
-                                raise drainer.error
-                            if time.monotonic() >= deadline:
-                                raise RuntimeError(
-                                    "async drain stalled: no free "
-                                    "dispatch slot")
-            finally:
-                st["drain_wait_s"] += clock() - t0
+            with tr.span("pipeline.drain_wait"):
+                deadline = time.monotonic() + max(
+                    4 * self.drain_timeout_s, 120.0)
+                while True:
+                    try:
+                        return slot_q.get(timeout=0.2)
+                    except queue_mod.Empty:
+                        if drainer.error is not None:
+                            raise drainer.error
+                        if time.monotonic() >= deadline:
+                            raise RuntimeError(
+                                "async drain stalled: no free "
+                                "dispatch slot")
 
         if self._drain_async_enabled():
             # multi-buffered async drain: worker-backed encodes fetch on
@@ -1490,7 +1428,6 @@ class StreamingEncoder:
                         return
                     d_idx = st["dispatches"]
                     buf = bufs[bi]
-                    t0 = clock()
                     with tr.span("pipeline.fill", dispatch=d_idx,
                                  bytes=k * used):
                         for col, n, row_start, block, off in fills:
@@ -1512,7 +1449,6 @@ class StreamingEncoder:
                                         dat, n, row_start + i * block + off)
                         if used < b:
                             buf[:, used:] = 0
-                    st["fill_s"] += clock() - t0
                     # injected dispatch fault: THIS dispatch goes CPU,
                     # the pipeline stays on its engine
                     dispatch_fault = False
@@ -1521,7 +1457,6 @@ class StreamingEncoder:
                             faultinject.hit("ec.dispatch")
                         except Exception:
                             dispatch_fault = True
-                    t0 = clock()
                     with tr.span("pipeline.dispatch", dispatch=d_idx,
                                  bytes=k * used):
                         if flags["degraded"] or dispatch_fault:
@@ -1568,13 +1503,11 @@ class StreamingEncoder:
                                          reason="device_dispatch",
                                          error=f"{type(e).__name__}: {e}")
                                 parity_dev = self._cpu_parity(buf[:, :used])
-                    st["dispatch_s"] += clock() - t0
                     st["dispatches"] += 1
                     st["bytes_in"] += k * used
                     # data shards pass through from the host buffer while
                     # the device computes parity; packed entries make each
                     # shard's bytes one contiguous slice
-                    t0 = clock()
                     with tr.span("pipeline.write", dispatch=d_idx,
                                  kind="data"):
                         for i in range(k):
@@ -1586,7 +1519,6 @@ class StreamingEncoder:
                             for i in range(k):
                                 sb.update(i, buf[i, :used])
                             st["sidecar_s"] += clock() - t1
-                    st["write_s"] += clock() - t0
                     meta = (parity_dev, used, bi, d_idx, len(fills))
                     fills, used = [], 0
                     if drainer is not None:
@@ -1596,11 +1528,8 @@ class StreamingEncoder:
                         # below.  Backpressure is normally the slot
                         # pool, but the bounded writer queue can also
                         # push back (fast fetch over a slow shard
-                        # disk recycles device slots before the write):
-                        # that block is drain-stall time too
-                        t0 = clock()
+                        # disk recycles device slots before the write)
                         drainer.submit(meta)
-                        st["drain_wait_s"] += clock() - t0
                     else:
                         pending.append(meta)
                         if len(pending) > self.depth:
@@ -1609,7 +1538,6 @@ class StreamingEncoder:
                             drain_one()
                     bi = acquire_slot()
 
-                st["setup_s"] = clock() - t_start
                 setup.__exit__(None, None, None)
                 setup = None
                 entries = _plan_entries(file_size, k, large_block_size,
@@ -1624,12 +1552,9 @@ class StreamingEncoder:
                 flush()
                 if drainer is not None:
                     # tail stall: the last in-flight dispatches finish
-                    # fetching + writing; host-blocked time lands in
-                    # drain_wait_s like any other drain stall
-                    t0 = clock()
+                    # fetching + writing
                     with tr.span("pipeline.drain_wait", final=True):
                         drainer.finish()
-                    st["drain_wait_s"] += clock() - t0
                 else:
                     while pending:
                         drain_one()
@@ -1660,9 +1585,7 @@ class StreamingEncoder:
                 # fold the concurrent drain/writer accounting into the
                 # call stats now that the threads have joined
                 st["drain_s"] += ds["drain_s"]
-                st["write_s"] += ds["write_s"]
                 st["sidecar_s"] += ds["sidecar_s"]
-                st["dispatch_s"] += ds["fallback_s"]
                 st["parity_bytes_drained"] += ds["parity_bytes"]
             if pending and self._proc_worker is not None:
                 # abnormal exit with submitted-but-undrained jobs: their
@@ -1670,11 +1593,9 @@ class StreamingEncoder:
                 # encode's) seq stream — abandon the worker; the retry
                 # respawns fresh (mmap path does the same)
                 self._abandon_proc_worker()
-            t0 = clock()
             with tr.span("pipeline.close"):
                 for f in outputs:
                     f.close()
-            st["close_s"] = clock() - t0
             st["wall_s"] = clock() - t_start
             st["worker_restarts"] = int(_restart_total() -
                                         self._restart_base)
@@ -1758,8 +1679,7 @@ class StreamingEncoder:
             raise
         ok = False
         flags = {"degraded": False}  # terminal fault: rest goes CPU
-        ds = {"drain_s": 0.0, "write_s": 0.0, "sidecar_s": 0.0,
-              "fallback_s": 0.0, "parity_bytes": 0}
+        ds = {"drain_s": 0.0, "sidecar_s": 0.0, "parity_bytes": 0}
         ds_lock = threading.Lock()
         dev_drain_s = [0.0] * nd   # guarded-by: ds_lock
         dev_dispatches = [0] * nd  # producer-only
@@ -1827,18 +1747,15 @@ class StreamingEncoder:
                         reasons[ji] = "device_fetch"
                         flags["degraded"] = True
             fetch_s = clock() - t0
-            fb_s = 0.0
             for ji, (handle, u, slot, d_idx, nfills, off) \
                     in enumerate(jobs):
                 if parities[ji] is None:
                     # slot buffer still intact (slots recycle below,
                     # after fetch-or-recompute): lossless CPU recompute
-                    t1 = clock()
                     with tr.span("pipeline.fallback", dispatch=d_idx,
                                  device=dev_i, reason=reasons[ji]):
                         parities[ji] = self._cpu_parity(
                             dev_bufs[dev_i][slot][:, :u])
-                    fb_s += clock() - t1
                     self._note_fallback(st, reasons[ji])
                     tr.event("pipeline.fallback", dispatch=d_idx,
                              device=dev_i, reason=reasons[ji])
@@ -1846,7 +1763,6 @@ class StreamingEncoder:
                 slot_qs[dev_i].put(slot)
             with ds_lock:
                 ds["drain_s"] += fetch_s
-                ds["fallback_s"] += fb_s
                 ds["parity_bytes"] += nbytes
                 dev_drain_s[dev_i] += fetch_s
             return parities
@@ -1856,7 +1772,6 @@ class StreamingEncoder:
             known shard offsets (cross-lane order-free), then the
             ordered tracker retires crc + checkpoint."""
             dev_i, jobs = meta
-            t0 = clock()
             with tr.span("pipeline.write", device=dev_i,
                          dispatch=jobs[0][3], kind="parity"):
                 for (handle, u, slot, d_idx, nfills, off), parity \
@@ -1867,8 +1782,6 @@ class StreamingEncoder:
                     with comp_lock:
                         comp[d_idx] = (parity, u, nfills)
                         _retire_locked()
-            with ds_lock:
-                ds["write_s"] += clock() - t0
 
         drainers = DrainerGroup(nd, drain_fetch_dev, drain_write_dev,
                                 queue_depth=slots_per_dev + 2)
@@ -1879,11 +1792,7 @@ class StreamingEncoder:
             jobs, batches[dev_i] = batches[dev_i], []
             if not jobs:
                 return
-            # a blocking put on a lane's bounded writer queue is
-            # drain-stall time, same as the staged pipeline
-            t0 = clock()
             drainers.submit(dev_i, (dev_i, jobs))
-            st["drain_wait_s"] += clock() - t0
 
         def acquire_slot(dev_i: int) -> int:
             err = drainers.error
@@ -1895,24 +1804,20 @@ class StreamingEncoder:
                 pass
             # every slot of THIS device is in flight: the residual
             # drain stall, attributed to the lane that back-pressured
-            t0 = clock()
-            try:
-                with tr.span("pipeline.drain_wait", device=dev_i):
-                    deadline = time.monotonic() + max(
-                        4 * self.drain_timeout_s, 120.0)
-                    while True:
-                        try:
-                            return slot_qs[dev_i].get(timeout=0.2)
-                        except queue_mod.Empty:
-                            err = drainers.error
-                            if err is not None:
-                                raise err
-                            if time.monotonic() >= deadline:
-                                raise RuntimeError(
-                                    "mesh drain stalled: no free slot "
-                                    f"on device {dev_i}")
-            finally:
-                st["drain_wait_s"] += clock() - t0
+            with tr.span("pipeline.drain_wait", device=dev_i):
+                deadline = time.monotonic() + max(
+                    4 * self.drain_timeout_s, 120.0)
+                while True:
+                    try:
+                        return slot_qs[dev_i].get(timeout=0.2)
+                    except queue_mod.Empty:
+                        err = drainers.error
+                        if err is not None:
+                            raise err
+                        if time.monotonic() >= deadline:
+                            raise RuntimeError(
+                                "mesh drain stalled: no free slot "
+                                f"on device {dev_i}")
 
         try:
             with open(dat_path, "rb") as dat:
@@ -1928,7 +1833,6 @@ class StreamingEncoder:
                     dev_i = d_idx % nd  # round-robin across the slice
                     slot = acquire_slot(dev_i)
                     buf = dev_bufs[dev_i][slot]
-                    t0 = clock()
                     with tr.span("pipeline.fill", dispatch=d_idx,
                                  device=dev_i, bytes=k * used):
                         for col, n, row_start, block, off in fills:
@@ -1945,14 +1849,12 @@ class StreamingEncoder:
                                         row_start + i * block + off)
                         if used < b:
                             buf[:, used:] = 0
-                    st["fill_s"] += clock() - t0
                     dispatch_fault = False
                     if faultinject._points:
                         try:
                             faultinject.hit("ec.dispatch")
                         except Exception:
                             dispatch_fault = True
-                    t0 = clock()
                     with tr.span("pipeline.dispatch", dispatch=d_idx,
                                  device=dev_i, bytes=k * used):
                         if flags["degraded"] or dispatch_fault:
@@ -1986,11 +1888,9 @@ class StreamingEncoder:
                                          reason="device_dispatch",
                                          error=f"{type(e).__name__}: {e}")
                                 handle = self._cpu_parity(buf[:, :used])
-                    st["dispatch_s"] += clock() - t0
                     st["dispatches"] += 1
                     st["bytes_in"] += k * used
                     dev_dispatches[dev_i] += 1
-                    t0 = clock()
                     with tr.span("pipeline.write", dispatch=d_idx,
                                  kind="data"):
                         for i in range(k):
@@ -2000,7 +1900,6 @@ class StreamingEncoder:
                             for i in range(k):
                                 sb.update(i, buf[i, :used])
                             st["sidecar_s"] += clock() - t1
-                    st["write_s"] += clock() - t0
                     batches[dev_i].append(
                         (handle, used, slot, d_idx, len(fills), out_off))
                     out_off += used
@@ -2008,7 +1907,6 @@ class StreamingEncoder:
                     if len(batches[dev_i]) >= coalesce:
                         submit_batch(dev_i)
 
-                st["setup_s"] = clock() - t_start
                 setup.__exit__(None, None, None)
                 setup = None
                 entries = _plan_entries(file_size, k, large_block_size,
@@ -2025,10 +1923,8 @@ class StreamingEncoder:
                     submit_batch(dev_i)
                 # tail stall: every lane's in-flight dispatches finish
                 # fetching + writing
-                t0 = clock()
                 with tr.span("pipeline.drain_wait", final=True):
                     drainers.finish()
-                st["drain_wait_s"] += clock() - t0
                 if nxt[0] != st["dispatches"]:
                     raise RuntimeError(
                         f"mesh completion tracker retired {nxt[0]} of "
@@ -2050,19 +1946,15 @@ class StreamingEncoder:
             if not ok:
                 drainers.abort()
             st["drain_s"] += ds["drain_s"]
-            st["write_s"] += ds["write_s"]
             st["sidecar_s"] += ds["sidecar_s"]
-            st["dispatch_s"] += ds["fallback_s"]
             st["parity_bytes_drained"] += ds["parity_bytes"]
             st["per_device"] = {
                 str(i): {"dispatches": dev_dispatches[i],
                          "drain_s": round(dev_drain_s[i], 4)}
                 for i in range(nd)}
-            t0 = clock()
             with tr.span("pipeline.close"):
                 for f in outputs:
                     f.close()
-            st["close_s"] = clock() - t0
             st["wall_s"] = clock() - t_start
             st["worker_restarts"] = int(_restart_total() -
                                         self._restart_base)
@@ -2134,7 +2026,6 @@ class StreamingEncoder:
                     m.madvise(mmap_mod.MADV_SEQUENTIAL)
             in_arrs = [np.frombuffer(m, dtype=np.uint8) for m in in_maps]
             in_addr = [a.ctypes.data for a in in_arrs]
-            st["setup_s"] = clock() - t_start
             try:
                 for offset in range(0, shard_size, b):
                     n = min(b, shard_size - offset)
@@ -2162,21 +2053,18 @@ class StreamingEncoder:
                         st["verify_s"] += clock() - t0
                         if corrupt is not None:
                             raise CorruptSurvivor(*corrupt)
-                    t0 = clock()
                     with tr.span("pipeline.compute",
                                  dispatch=st["dispatches"],
                                  bytes=len(survivors) * n):
                         matmul_ptrs(rec,
                                     [a + offset for a in in_addr],
                                     [a + offset for a in out_addrs], n)
-                    st["dispatch_s"] += clock() - t0
                     st["dispatches"] += 1
                     st["bytes_in"] += len(survivors) * n
             finally:
                 del in_arrs
             ok = True
         finally:
-            t0 = clock()
             for m in out_maps:
                 try:
                     m.close()
@@ -2186,7 +2074,6 @@ class StreamingEncoder:
                 m.close()
             for f in in_fs + out_fs:
                 f.close()
-            st["close_s"] = clock() - t0
             if not ok:
                 for m in missing:
                     try:
@@ -2321,15 +2208,11 @@ class StreamingEncoder:
 
         def drain_one():
             out_dev, n, bi, d_idx = pending.popleft()
-            t0 = clock()
             with tr.span("pipeline.drain", dispatch=d_idx):
                 out = self._fetch(out_dev)
-            st["drain_wait_s"] += clock() - t0
-            t0 = clock()
             with tr.span("pipeline.write", dispatch=d_idx, kind="rebuilt"):
                 for row_i, m in enumerate(missing):
                     outputs[m].write(out[row_i, :n])
-            st["write_s"] += clock() - t0
             free.append(bi)
 
         ok = False
@@ -2341,14 +2224,12 @@ class StreamingEncoder:
                 bi = free.popleft()
                 buf = bufs[bi]
                 d_idx = st["dispatches"]
-                t0 = clock()
                 with tr.span("pipeline.fill", dispatch=d_idx,
                              bytes=len(survivors) * n):
                     for row_i, s in enumerate(survivors):
                         preadv_into(inputs[s], [buf[row_i, :n]], offset)
                     if n < b:
                         buf[:, n:] = 0
-                st["fill_s"] += clock() - t0
                 if sidecar is not None:
                     # verify before dispatch: corrupt bytes must never
                     # reach the reconstruction matmul (the raised
@@ -2364,12 +2245,10 @@ class StreamingEncoder:
                         if bad is not None:
                             raise CorruptSurvivor(s, bad)
                     st["verify_s"] += clock() - t0
-                t0 = clock()
                 with tr.span("pipeline.dispatch", dispatch=d_idx,
                              bytes=len(survivors) * n):
                     pending.append((self._dispatch(planes, buf), n, bi,
                                     d_idx))
-                st["dispatch_s"] += clock() - t0
                 st["dispatches"] += 1
                 st["bytes_in"] += len(survivors) * n
                 if len(pending) > self.depth:

@@ -1,10 +1,6 @@
 """Thread-safe span tracer with Chrome trace-event + Prometheus exporters.
 
-The EC pipeline's four coarse counters (fill_s/dispatch_s/write_s/
-drain_wait_s in ec/streaming.py) say WHERE time went in aggregate but not
-WHEN: BENCH_r05 showed drain_wait_s eating ~90% of the e2e wall with no
-way to see which dispatch, stage, or host/device boundary it vanished
-into.  This module turns those counters into per-dispatch spans:
+Spans say WHERE time went and WHEN, per dispatch or per request:
 
   with tracer.span("pipeline.dispatch", dispatch=3, bytes=n):
       ...
@@ -23,6 +19,12 @@ Design constraints, in order:
     worker process's serializable span log (export_log/ingest_log, or
     the overlap workers' timed acks fed through add_span) merges into
     the parent's timeline without id collisions;
+  - on the device trace's clock: once a device engine has set up
+    (ops/device.py installs `annotation_hook`), every live span opened
+    through span() also enters a profiler annotation named after it,
+    with its span_id as the one argument, so a jax.profiler trace shows
+    the span on its /host:CPU plane beside the device ops.  Spans
+    recorded after the fact (add_span, event) are not annotated;
   - exportable two ways: to_chrome() emits Chrome trace_event JSON
     (load in chrome://tracing or https://ui.perfetto.dev), and an
     optional Prometheus bridge observes every span's duration into a
@@ -35,7 +37,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 from . import context as _trace_context
 
@@ -45,8 +47,18 @@ from . import context as _trace_context
 _EPOCH_WALL = time.time() - time.perf_counter()
 
 
-def _now() -> float:
+def now() -> float:
+    """The spans' clock: wall-anchored seconds.  Stamps taken with it
+    can be handed to Tracer.add_span."""
     return _EPOCH_WALL + time.perf_counter()
+
+
+# profiler annotation factory, called as hook(name, span_id=...) on
+# every live span's enter; it returns a context manager that the span
+# enters and leaves on its own thread.  None until a device engine sets
+# up (ops/device.py: jax.profiler.TraceAnnotation) — this package never
+# imports JAX itself.
+annotation_hook: Optional[Callable[..., Any]] = None
 
 
 class Span:
@@ -109,6 +121,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
     @property
     def span_id(self):
         return None
@@ -123,9 +138,11 @@ NOOP_SPAN = _NOOP
 class _SpanCtx:
     """Live span context manager: records on exit, nests via the
     tracer's per-thread stack, tags the span with the exception type on
-    an abnormal exit."""
+    an abnormal exit.  With an annotation hook installed it also holds
+    the span's profiler annotation open from enter to exit."""
 
-    __slots__ = ("tracer", "name", "attrs", "span_id", "parent_id", "t0")
+    __slots__ = ("tracer", "name", "attrs", "span_id", "parent_id", "t0",
+                 "ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
@@ -148,11 +165,22 @@ class _SpanCtx:
             self.parent_id = (ctx.span_id or None) if ctx else None
         self.span_id = tr._next_id()
         stack.append(self.span_id)
-        self.t0 = _now()
+        hook = annotation_hook
+        self.ann = None
+        if hook is not None:
+            self.ann = hook(self.name, span_id=self.span_id)
+            self.ann.__enter__()
+        self.t0 = now()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attributes known only once the span's work is done."""
+        self.attrs.update(attrs)
+
     def __exit__(self, exc_type, exc, tb):
-        t1 = _now()
+        t1 = now()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         tr = self.tracer
         stack = tr._stack.ids
         if stack and stack[-1] == self.span_id:
@@ -237,7 +265,7 @@ class Tracer:
         like a supervisor restart, an engine fallback decision, or a
         degraded bind, where the interesting thing is THAT it happened
         and its attrs, not how long it took."""
-        t = _now()
+        t = now()
         return self.add_span(name, t, t, **attrs)
 
     def current_span_id(self) -> Optional[str]:

@@ -8,12 +8,20 @@ that is only allowed when the process was pinned to the CPU on purpose
 The persistent compile cache lives where ``JAX_COMPILATION_CACHE_DIR``
 says; without it, at ``<repo>/.jax_cache`` (a fixed path: the path is
 part of JAX's cache key, so a moving directory never hits).
+
+The same once-per-process set-up puts the program's spans on the
+profiler's clock: every span the tracer opens then also enters a
+``jax.profiler.TraceAnnotation``, so a profiler trace of this process
+shows it on the ``/host:CPU`` plane beside the device ops.  Each compile
+request is recorded as a ``device.compile`` span.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+
+from ..observability import tracer as _tracer
 
 CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -63,10 +71,17 @@ def _count_hit(event: str, **_kw) -> None:  # thread-entry
             _compile_counts["cache_hits"] += 1
 
 
-def _count_compile(event: str, _secs: float, **_kw) -> None:  # thread-entry
+def _count_compile(event: str, secs: float, **_kw) -> None:  # thread-entry
     if event == _COMPILE_EVENT:
         with _lock:
             _compile_counts["compile_requests"] += 1
+        tr = _tracer.get_tracer()
+        if tr.enabled:
+            # JAX reports the duration once the compile is done: the
+            # span ends now, under the step that compiled
+            t1 = _tracer.now()
+            tr.add_span("device.compile", t1 - secs, t1,
+                        parent_id=tr.current_span_id())
 
 
 def ensure_compile_cache() -> None:
@@ -74,7 +89,8 @@ def ensure_compile_cache() -> None:
     JAX when JAX_COMPILATION_CACHE_DIR is set, else at CACHE_DIR.  Only
     on a TPU — the CPU backend is a correctness surface and its
     executables are not worth keeping.  Also counts compile requests
-    and cache hits for device_doc()."""
+    and cache hits for device_doc(), and installs the tracer's
+    profiler annotation."""
     global _cache_set_up
     import jax
 
@@ -82,6 +98,7 @@ def ensure_compile_cache() -> None:
         if _cache_set_up:
             return
         _cache_set_up = True
+    _tracer.annotation_hook = jax.profiler.TraceAnnotation
     jax.monitoring.register_event_listener(_count_hit)
     jax.monitoring.register_event_duration_secs_listener(_count_compile)
     if "JAX_COMPILATION_CACHE_DIR" in os.environ \

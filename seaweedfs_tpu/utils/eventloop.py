@@ -61,6 +61,8 @@ import time
 from collections import deque
 from typing import Callable, Optional
 
+from ..observability.tracer import now as _trace_now
+
 # per-connection bounds, mirroring the threaded servers' guards
 MAX_HEADER_BYTES = 1 << 16          # 64KB request line + headers
 MAX_HEADERS = 100
@@ -143,14 +145,16 @@ class _LoopHandler:
 
     __slots__ = ("server", "rfile", "wfile", "client_address", "command",
                  "path", "headers", "close_connection", "_out", "_conn",
-                 "queue_wait_s")
+                 "queue_t0", "queue_wait_s")
 
     def __init__(self, server, conn: "_Conn", body: bytes, peer):
         self.server = server
         self._conn = conn
         # dispatch-queue wait: stamped by the worker-handoff closure
-        # (loop-enqueue -> worker pickup); the inline fast path leaves
-        # it 0.  Router.dispatch feeds it to the resource ledger.
+        # (loop-enqueue at queue_t0 -> worker pickup, on the tracer's
+        # clock); the inline fast path leaves it None / 0.  Router.dispatch
+        # feeds it to the resource ledger and the http.queue_wait span.
+        self.queue_t0 = None
         self.queue_wait_s = 0.0
         self.rfile = io.BytesIO(body)
         self.wfile = _ConnWriter(conn)
@@ -901,12 +905,13 @@ class Reactor:
             conn.request_done(close=h.close_connection)
             return True
 
-        t_submit = time.monotonic()
+        t_submit = _trace_now()
 
         def run():
             # queue wait = loop enqueue -> worker pickup; the ledger
             # reads it off the handler at settle
-            h.queue_wait_s = time.monotonic() - t_submit
+            h.queue_t0 = t_submit
+            h.queue_wait_s = _trace_now() - t_submit
             try:
                 router.dispatch(h, command)
             except Exception:
@@ -944,7 +949,7 @@ class Reactor:
         with conn._lock:
             conn.busy = True
 
-        t_submit = time.monotonic()
+        t_submit = _trace_now()
 
         def run():
             from .framing import serve_frame
@@ -953,7 +958,7 @@ class Reactor:
                                 conn.peer[0],
                                 ledger=getattr(lst.owner, "ledger",
                                                None),
-                                queue_wait_s=time.monotonic()
+                                queue_wait_s=_trace_now()
                                 - t_submit)
             conn.enqueue(frame)
             conn.request_done(close=False)

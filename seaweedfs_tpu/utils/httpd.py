@@ -355,7 +355,18 @@ class Router:
                             and not admission.exempt(path)
                         if tctx is not None:
                             with tracer.span(f"http.{self.name}.{fn.__name__}",
-                                             method=method, path=path):
+                                             method=method, path=path) as sp:
+                                qt0 = getattr(handler, "queue_t0", None)
+                                if qt0 is not None:
+                                    # the reactor's pool handoff, on the
+                                    # tracer's clock: it ends before the
+                                    # request span starts, and is kept
+                                    # under it so a stitched trace stays
+                                    # one tree
+                                    tracer.add_span(
+                                        "http.queue_wait", qt0,
+                                        qt0 + handler.queue_wait_s,
+                                        parent_id=sp.span_id, path=path)
                                 resp = fn(req)
                         else:
                             resp = fn(req)

@@ -22,6 +22,8 @@ from ..ec.ec_volume import EcVolume, NeedleNotFoundError
 from ..ec.integrity import ShardCorruptError
 from ..ec.layout import to_ext
 from ..ec import encoder as ec_encoder
+from ..observability import get_tracer
+from ..observability.tracer import NOOP_SPAN
 from ..storage.needle import Needle
 from ..storage.super_block import ReplicaPlacement
 from ..storage.ttl import TTL
@@ -744,15 +746,17 @@ class Store:
     def ec_generate(self, vid: int, collection: str = "",
                     engine: Optional[str] = None) -> None:
         """VolumeEcShardsGenerate: .dat -> .ec00..13 + .ecx + mark readonly."""
-        # quiesce the native plane for the encode: writes fall back to the
-        # (reopened, idx-replayed) Python engine; reads keep working.
-        # The finally-reattach re-registers read_only, so the plane keeps
-        # serving reads of the frozen volume while shards spread
-        self.native_detach(vid)
-        try:
-            self._ec_generate_locked(vid, engine)
-        finally:
-            self.native_reattach(vid)
+        with get_tracer().span("store.ec_generate", vid=vid):
+            # quiesce the native plane for the encode: writes fall back
+            # to the (reopened, idx-replayed) Python engine; reads keep
+            # working.  The finally-reattach re-registers read_only, so
+            # the plane keeps serving reads of the frozen volume while
+            # shards spread
+            self.native_detach(vid)
+            try:
+                self._ec_generate_locked(vid, engine)
+            finally:
+                self.native_reattach(vid)
 
     def _ec_generate_locked(self, vid: int,
                             engine: Optional[str] = None) -> None:
@@ -780,19 +784,21 @@ class Store:
                 self.last_ec_stats = enc.stats
             else:
                 ec_encoder.write_ec_files(base, self.rs(engine))
-            ec_encoder.write_sorted_file_from_idx(base)
+            with get_tracer().span("store.ecx_sort") as sp:
+                sp.set(needles=ec_encoder.write_sorted_file_from_idx(base))
 
     def ec_rebuild(self, vid: int, collection: str = "",
                    engine: Optional[str] = None) -> list[int]:
         """VolumeEcShardsRebuild: regenerate missing local shards."""
-        base = self._ec_base(vid, collection)
-        name = engine or self.ec_engine_name
-        if name in ("tpu", "mesh"):
-            enc = self._streaming_encoder(name)
-            rebuilt = enc.rebuild_files(base)
-            self.last_ec_stats = enc.stats
-            return rebuilt
-        return ec_encoder.rebuild_ec_files(base, self.rs(engine))
+        with get_tracer().span("store.ec_rebuild", vid=vid):
+            base = self._ec_base(vid, collection)
+            name = engine or self.ec_engine_name
+            if name in ("tpu", "mesh"):
+                enc = self._streaming_encoder(name)
+                rebuilt = enc.rebuild_files(base)
+                self.last_ec_stats = enc.stats
+                return rebuilt
+            return ec_encoder.rebuild_ec_files(base, self.rs(engine))
 
     def _streaming_encoder(self, engine: str = "tpu"):
         # explicit device engines only: never auto-detected
@@ -840,23 +846,29 @@ class Store:
 
     def ec_delete_shards(self, vid: int, shard_ids: list[int],
                          collection: str = "") -> None:
-        base = self._ec_base(vid, collection)
-        was_mounted = vid in self.ec_volumes
-        if was_mounted:
-            self.ec_unmount(vid)
-        for sid in shard_ids:
-            p = base + to_ext(sid)
-            if os.path.exists(p):
-                os.remove(p)
-        if not glob.glob(base + ".ec[0-9][0-9]"):
-            # last shard gone: drop the index, journal, crc sidecar, and
-            # any quarantined .bad evidence files with it
-            for path in [base + ext for ext in (".ecx", ".ecj", ".eci")] \
-                    + glob.glob(base + ".ec[0-9][0-9].bad"):
-                if os.path.exists(path):
-                    os.remove(path)
-        elif was_mounted:
-            self.ec_mount(vid, collection)
+        with get_tracer().span("store.ec_delete", vid=vid) as sp:
+            base = self._ec_base(vid, collection)
+            was_mounted = vid in self.ec_volumes
+            if was_mounted:
+                self.ec_unmount(vid)
+            removed = unlinked = 0
+            for sid in shard_ids:
+                p = base + to_ext(sid)
+                if os.path.exists(p):
+                    unlinked += os.path.getsize(p)
+                    os.remove(p)
+                    removed += 1
+            if not glob.glob(base + ".ec[0-9][0-9]"):
+                # last shard gone: drop the index, journal, crc sidecar,
+                # and any quarantined .bad evidence files with it
+                for path in [base + ext for ext in (".ecx", ".ecj", ".eci")] \
+                        + glob.glob(base + ".ec[0-9][0-9].bad"):
+                    if os.path.exists(path):
+                        unlinked += os.path.getsize(path)
+                        os.remove(path)
+            elif was_mounted:
+                self.ec_mount(vid, collection)
+            sp.set(shards=removed, bytes=unlinked)
 
     def ec_shard_read(self, vid: int, shard_id: int, offset: int,
                       length: int) -> bytes:
@@ -870,45 +882,57 @@ class Store:
                        ) -> tuple[bytes, int]:
         """ReadEcShardNeedle (store_ec.go:125-163): local shards first, then
         remote shard reads, then on-the-fly reconstruction via fetch_remote
-        (vid, shard_id, offset, length) -> bytes.  Returns (record, size)."""
-        ev = self.ec_volumes.get(vid)
-        if ev is None:
-            raise KeyError(f"ec volume {vid} not found")
-        offset, size, intervals = ev.locate_ec_shard_needle(key)
-        from ..storage.types import size_is_deleted
+        (vid, shard_id, offset, length) -> bytes.  Returns (record, size).
+        Its span counts the needle's intervals, those read from a local
+        shard, and those recovered otherwise (remote or reconstructed)."""
+        tr = get_tracer()
+        with (tr.span("store.read_ec_needle", vid=vid)
+              if tr.enabled else NOOP_SPAN) as sp:
+            ev = self.ec_volumes.get(vid)
+            if ev is None:
+                raise KeyError(f"ec volume {vid} not found")
+            offset, size, intervals = ev.locate_ec_shard_needle(key)
+            from ..storage.types import size_is_deleted
 
-        if size_is_deleted(size):
-            raise NeedleNotFoundError(f"needle {key} deleted")
-        out = []
-        for iv in intervals:
-            shard_id, shard_offset = iv.to_shard_id_and_offset(
-                ev.large_block_size, ev.small_block_size, ev.data_shards)
-            piece = None
-            if shard_id in ev.shards and shard_id not in ev.corrupt_shards:
-                try:
-                    # sidecar-verified read (ec/integrity.py): a crc
-                    # mismatch demotes the shard for the whole mount and
-                    # self-heals below via remote fetch / reconstruction
-                    # instead of serving rotted bytes
-                    piece = ev._verified_read(shard_id, shard_offset,
-                                              iv.size).tobytes()
-                except ShardCorruptError:
-                    ev._note_corrupt(shard_id)
-                    piece = None
-                except OSError:
-                    # bad sector/dying disk: treat the shard as absent and
-                    # self-heal through the degraded-read paths below
-                    piece = None
-            if piece is None and fetch_remote is not None:
-                try:
-                    piece = fetch_remote(vid, shard_id, shard_offset, iv.size)
-                except Exception:
-                    piece = None
-            if piece is None:
-                piece = ev.reconstruct_interval(shard_id, shard_offset,
-                                                iv.size, self.rs())
-            out.append(piece)
-        return b"".join(out), size
+            if size_is_deleted(size):
+                raise NeedleNotFoundError(f"needle {key} deleted")
+            out = []
+            local = 0
+            for iv in intervals:
+                shard_id, shard_offset = iv.to_shard_id_and_offset(
+                    ev.large_block_size, ev.small_block_size, ev.data_shards)
+                piece = None
+                if shard_id in ev.shards \
+                        and shard_id not in ev.corrupt_shards:
+                    try:
+                        # sidecar-verified read (ec/integrity.py): a crc
+                        # mismatch demotes the shard for the whole mount
+                        # and self-heals below via remote fetch /
+                        # reconstruction instead of serving rotted bytes
+                        piece = ev._verified_read(shard_id, shard_offset,
+                                                  iv.size).tobytes()
+                        local += 1
+                    except ShardCorruptError:
+                        ev._note_corrupt(shard_id)
+                        piece = None
+                    except OSError:
+                        # bad sector/dying disk: treat the shard as
+                        # absent and self-heal through the degraded-read
+                        # paths below
+                        piece = None
+                if piece is None and fetch_remote is not None:
+                    try:
+                        piece = fetch_remote(vid, shard_id, shard_offset,
+                                             iv.size)
+                    except Exception:
+                        piece = None
+                if piece is None:
+                    piece = ev.reconstruct_interval(shard_id, shard_offset,
+                                                    iv.size, self.rs())
+                out.append(piece)
+            sp.set(intervals=len(intervals), local_reads=local,
+                   reconstructed=len(intervals) - local)
+            return b"".join(out), size
 
     def ec_delete_needle(self, vid: int, key: int) -> None:
         ev = self.ec_volumes.get(vid)

@@ -19,6 +19,7 @@ from seaweedfs_tpu.ec.codec import ReedSolomon
 from seaweedfs_tpu.ec.layout import to_ext
 from seaweedfs_tpu.ec.streaming import (StreamingEncoder, _plan_entries,
                                         default_drain_pool)
+from seaweedfs_tpu.observability import Tracer
 
 RNG = np.random.default_rng(0x5EA)
 
@@ -187,9 +188,10 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
                            large_block_size=large, small_block_size=small)
 
     def run(async_drain):
+        tracer = Tracer(capacity=1 << 14)
         enc = StreamingEncoder(10, 4, engine="host", zero_copy=False,
                                overlap="none", depth=2,  # N = 3 buffers
-                               async_drain=async_drain)
+                               async_drain=async_drain, tracer=tracer)
         enc.dispatch_b = 1 << 18
         order: list[int] = []
         real_dispatch = enc._dispatch
@@ -214,7 +216,14 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
                             large_block_size=large, small_block_size=small)
         finally:
             fi.clear()
-        eff = 1.0 - enc.stats["drain_wait_s"] / enc.stats["wall_s"]
+        # host-blocked time: the producer thread's drain stalls, and
+        # in the inline serial drain its own fetches
+        spans = tracer.snapshot()
+        producer = next(s.tid for s in spans
+                        if s.name == "pipeline.encode_file")
+        blocked = sum(s.duration for s in spans if s.tid == producer
+                      and s.name in ("pipeline.drain_wait", "pipeline.drain"))
+        eff = 1.0 - blocked / enc.stats["wall_s"]
         return enc, out, order, eff
 
     enc, out, order, eff = run(async_drain=True)

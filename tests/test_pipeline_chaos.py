@@ -146,8 +146,11 @@ def test_sigkill_worker_mid_encode_completes(volume):
 
     def run():
         try:
-            # drain delay stretches the encode so the kill lands inside
-            fi.enable("ec.drain", delay=0.01)
+            # drain delay stretches the encode so the kill lands inside:
+            # 7 dispatches at 0.05 s outlast the 0.1 s before the kill
+            # on an idle host too (at 0.01 s an unloaded encode could
+            # finish first, and no restart was ever seen)
+            fi.enable("ec.drain", delay=0.05)
             enc.encode_file(base + ".dat", out,
                             large_block_size=LARGE, small_block_size=SMALL)
         except Exception as e:  # pragma: no cover - the drill's failure

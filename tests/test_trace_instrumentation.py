@@ -104,15 +104,16 @@ try:
         tracer.clear()
         enc.encode_file(str(dat), str(workdir / ("cold%d" % i)))
         wall = enc.stats["wall_s"]
+        spans = tracer.snapshot()
         by_stage = {}
-        for sp in tracer.snapshot():
+        for sp in spans:
             if sp.name.startswith("pipeline.") \
                     and sp.attrs.get("dispatch") is not None:
                 st = sp.name.split(".", 1)[1]
                 by_stage[st] = by_stage.get(st, 0.0) + sp.duration
-        counted = sum(enc.stats[k] for k in
-                      ("fill_s", "dispatch_s", "write_s", "drain_wait_s",
-                       "setup_s", "close_s"))
+        counted = sum(by_stage.values()) + sum(
+            sp.duration for sp in spans
+            if sp.name in ("pipeline.setup", "pipeline.close"))
         res = {"ratio": sum(by_stage.values()) / wall,
                "counted_ratio": counted / wall,
                "by_stage": by_stage,
@@ -141,7 +142,7 @@ finally:
         res = json.loads(line[len("RESULT "):])
         assert res["stages"] == sorted(STAGES), res
         assert 0.90 <= res["ratio"] <= 1.02, res
-        # stage counters + setup/close account for the whole wall
+        # stage spans + the setup/close spans account for the whole wall
         assert 0.93 <= res["counted_ratio"] <= 1.02, res
         # and the Chrome export of the same run round-trips
         assert res["chrome_x"] >= 4 * res["dispatches"]
