@@ -315,7 +315,7 @@ class StreamingEncoder:
             if engine == "mesh":
                 # per-device dispatch queues: each device computes WHOLE
                 # dispatches (single-device kernel geometry — a multiple
-                # of 64 keeps the u32 transfer packing and the XLA
+                # of 64 keeps the u32 word layout and the XLA
                 # layouts happy), so the throughput lever is N dispatches
                 # in flight across the slice, not a sharded matmul
                 from ..parallel.mesh import (device_encode_fn,
@@ -430,11 +430,13 @@ class StreamingEncoder:
         return p
 
     def _dispatch(self, planes, buf: np.ndarray):
-        """Device mode, async: returns an unfetched device array
-        [R, dispatch_b//4] u32 (the transfer packing — see _pack_u32_lanes)
-        with the D2H copy already queued behind the kernel, so the fetch
-        streams down while later dispatches compute.  Host mode: the SIMD
-        codec runs synchronously and the parity comes back finished."""
+        """Device mode, async: the [K, dispatch_b] u8 buffer goes up as
+        [K, dispatch_b//4] u32 words (a free view: C-contiguous, width a
+        multiple of 4) and comes back an unfetched [R, dispatch_b//4] u32
+        device array in the same word layout (ops/gf_matmul.py), with the
+        D2H copy already queued behind the kernel, so the fetch streams
+        down while later dispatches compute.  Host mode: the SIMD codec
+        runs synchronously and the parity comes back finished."""
         if self.engine == "host":
             if self._host_pool is None:
                 return self._host_engine.matmul(planes, buf)
@@ -456,7 +458,7 @@ class StreamingEncoder:
             from ..ops.gf_matmul import (gf_matmul_pallas_packed,
                                          gf_matmul_xla_packed)
 
-            dev = self._jax.device_put(buf)
+            dev = self._jax.device_put(buf.view(np.uint32))
             if self.on_tpu:
                 out = gf_matmul_pallas_packed(planes, dev)
             else:
@@ -1870,11 +1872,12 @@ class StreamingEncoder:
                         else:
                             try:
                                 # committed device_put batches the whole
-                                # [k, b] H2D to THIS device; the jitted
-                                # kernel (donated input on TPU) leaves a
-                                # packed u32 handle with its D2H queued
+                                # [k, b] H2D, as [k, b//4] u32 words, to
+                                # THIS device; the jitted kernel (donated
+                                # input on TPU) leaves a u32 word handle
+                                # with its D2H queued
                                 darr = self._jax.device_put(
-                                    buf, devs[dev_i])
+                                    buf.view(np.uint32), devs[dev_i])
                                 handle = self._dev_encode(
                                     planes_dev[dev_i], darr)
                                 handle.copy_to_host_async()

@@ -20,6 +20,16 @@ Two implementations, byte-identical to each other and to the numpy CPU
 engine (differential-tested):
   - `gf_matmul_xla`: pure jnp, XLA fuses unpack+matmul+pack
   - `gf_matmul_pallas`: fused Pallas kernel tiled over B
+
+Each comes in two layouts.  Bytes: data [K, B] u8 -> [R, B] u8, for
+reconstructs of any width (TpuEngine).  Words (`*_packed`): data
+[K, B//4] u32 -> [R, B//4] u32, the streaming pipeline's dispatches.
+Byte q of word j is column 4j + q, as a little-endian host's
+``.view(np.uint32)`` / ``.view(np.uint8)`` sees it, and the matmul is
+independent per column, so the word path runs the bit-plane matmul once
+per byte lane ((w >> 8q) & 0xFF in, its bytes OR-ed back at << 8q): a
+quarter of the elements cross the host link each way, and no lane ever
+moves on the device (strided lane slices lower to gathers on a TPU).
 """
 
 from __future__ import annotations
@@ -55,97 +65,113 @@ def expand_matrix_bitplanes(gmat: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(a.transpose(1, 0, 3, 2).reshape(8 * r, 8 * k))
 
 
-def _unpack_bitplanes(data: jnp.ndarray) -> jnp.ndarray:
+def _unpack_bitplanes(data: jnp.ndarray, shift: int = 0) -> jnp.ndarray:
     """[K, B] u8/i32 -> [8K, B] 0/1 i32, rows bit-plane-major to match the A
-    layout.  Static concat of 2D shifts — no 3D intermediates (Mosaic-safe)."""
+    layout: row j*K + k is bit shift+j of data[k].  Static concat of 2D
+    shifts — no 3D intermediates (Mosaic-safe)."""
     d = data.astype(jnp.int32)
-    return jnp.concatenate([(d >> j) & 1 for j in range(8)], axis=0)
+    return jnp.concatenate([(d >> (shift + j)) & 1 for j in range(8)],
+                           axis=0)
 
 
-def _pack_bits(bits: jnp.ndarray, r: int) -> jnp.ndarray:
-    """[8R, B] 0/1 i32 (rows bit-major: row i*R + r) -> [R, B] u8.
+def _pack_bits(bits: jnp.ndarray, r: int, dtype=jnp.uint8) -> jnp.ndarray:
+    """[8R, B] 0/1 i32 (rows bit-major: row i*R + r) -> [R, B] bytes.
     Contiguous static row-slices only — no 3D or strided ops (Mosaic-safe)."""
     out = bits[0:r]
     for i in range(1, 8):
         out = out | (bits[i * r : (i + 1) * r] << i)
-    return out.astype(jnp.uint8)
+    return out.astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def gf_matmul_xla(a_planes: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
-    """a_planes [8R, 8K] u8 (from expand_matrix_bitplanes), data [K, B] u8
-    -> [R, B] u8."""
-    r8 = a_planes.shape[0]
+def _gf_bytes(a_planes: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
+    """[8R, 8K] planes . [K, B] u8 -> [R, B] u8: one MXU matmul, mod 2."""
+    # v5e MXU does native int8 x int8 -> int32; unpack must go through i32
+    # (Mosaic has no packed u8 shifts), the dot runs in i8
     bits = _unpack_bitplanes(data).astype(jnp.int8)
     acc = jnp.dot(a_planes.astype(jnp.int8), bits,
                   preferred_element_type=jnp.int32)
-    return _pack_bits(acc & 1, r8 // 8)
+    return _pack_bits(acc & 1, a_planes.shape[0] // 8)
+
+
+def _gf_words(a_planes: jnp.ndarray, words: jnp.ndarray) -> jnp.ndarray:
+    """[8R, 8K] planes . [K, W] u32 -> [R, W] u32: the byte matmul once per
+    byte lane q, each lane's bytes OR-ed into the output word at << 8q."""
+    a = a_planes.astype(jnp.int8)
+    r = a_planes.shape[0] // 8
+    w = jax.lax.bitcast_convert_type(words, jnp.int32)
+    out = None
+    for q in range(4):
+        bits = _unpack_bitplanes(w, 8 * q).astype(jnp.int8)
+        acc = jnp.dot(a, bits, preferred_element_type=jnp.int32)
+        lane = _pack_bits(acc & 1, r, jnp.int32) << (8 * q)
+        out = lane if out is None else out | lane
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
+
+
+@jax.jit
+def gf_matmul_xla(a_planes: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
+    """a_planes [8R, 8K] u8 (from expand_matrix_bitplanes), data [K, B] u8
+    -> [R, B] u8."""
+    return _gf_bytes(a_planes, data)
+
+
+@jax.jit
+def gf_matmul_xla_packed(a_planes: jnp.ndarray,
+                         words: jnp.ndarray) -> jnp.ndarray:
+    """gf_matmul_xla in the word layout: words [K, B//4] u32 -> [R, B//4]
+    u32."""
+    return _gf_words(a_planes, words)
 
 
 def _gf_kernel(a_ref, d_ref, o_ref):
-    # v5e MXU does native int8 x int8 -> int32; unpack must go through i32
-    # (Mosaic has no packed u8 shifts), the dot runs in i8
-    bits = _unpack_bitplanes(d_ref[:])  # [8K, TB] i32
-    acc = jnp.dot(a_ref[:].astype(jnp.int8), bits.astype(jnp.int8),
-                  preferred_element_type=jnp.int32)  # [8R, TB]
-    o_ref[:] = _pack_bits(acc & 1, o_ref.shape[0])
+    o_ref[:] = _gf_bytes(a_ref[:], d_ref[:])
 
 
-@functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
+def _gf_word_kernel(a_ref, d_ref, o_ref):
+    o_ref[:] = _gf_words(a_ref[:], d_ref[:])
+
+
+@functools.partial(jax.jit, static_argnames=("tile_b", "interpret", "words"))
 def gf_matmul_pallas(a_planes: jnp.ndarray, data: jnp.ndarray,
                      tile_b: int = DEFAULT_TILE_B,
-                     interpret: bool = False) -> jnp.ndarray:
+                     interpret: bool = False,
+                     words: bool = False) -> jnp.ndarray:
     """Fused Pallas kernel: grid over B tiles; A resident in VMEM; unpack,
-    one MXU matmul, mod-2, repack — no 8x bit expansion ever hits HBM."""
+    MXU matmul, mod-2, repack — no 8x bit expansion ever hits HBM.
+    `tile_b` counts columns (bytes) in both layouts; with `words` data is
+    [K, B//4] u32 and so is the output, tile_b//4 words a block."""
     r8, k8 = a_planes.shape
-    k, b = data.shape
-    assert k8 == 8 * k and b % tile_b == 0
-    grid = (b // tile_b,)
+    k, n = data.shape
+    tile = tile_b // 4 if words else tile_b
+    assert k8 == 8 * k and n % tile == 0
+    dtype = jnp.uint32 if words else jnp.uint8
     return pl.pallas_call(
-        _gf_kernel,
-        out_shape=jax.ShapeDtypeStruct((r8 // 8, b), jnp.uint8),
-        grid=grid,
+        _gf_word_kernel if words else _gf_kernel,
+        out_shape=jax.ShapeDtypeStruct((r8 // 8, n), dtype),
+        grid=(n // tile,),
         in_specs=[
             pl.BlockSpec((r8, k8), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((k, tile_b), lambda i: (0, i), memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((r8 // 8, tile_b), lambda i: (0, i),
+        out_specs=pl.BlockSpec((r8 // 8, tile), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
     )(a_planes, data)
 
 
-def _pack_u32_lanes(p: jnp.ndarray) -> jnp.ndarray:
-    """[R, B] u8 -> [R, B//4] u32: 4 consecutive lane bytes per word,
-    little-endian, so a host-side ``.view(uint8)`` restores the exact byte
-    stream.  The streaming pipeline pulls parity through this packing:
-    a quarter of the elements per fetch.  Strided lane slices
-    (not a [R, B/4, 4] reshape+bitcast) on purpose: the 3-D intermediate
-    picks up a T(8,128) tiled layout with 32x padding and OOMs HBM."""
-    w = p.astype(jnp.uint32)
-    return (w[:, 0::4] | (w[:, 1::4] << 8) | (w[:, 2::4] << 16)
-            | (w[:, 3::4] << 24))
-
-
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
-def gf_matmul_pallas_packed(a_planes: jnp.ndarray, data: jnp.ndarray,
+def gf_matmul_pallas_packed(a_planes: jnp.ndarray, words: jnp.ndarray,
                             tile_b: int = DEFAULT_TILE_B,
                             interpret: bool = False) -> jnp.ndarray:
-    """gf_matmul_pallas fused with the u32 transfer packing; B % 4 == 0."""
-    return _pack_u32_lanes(
-        gf_matmul_pallas(a_planes, data, tile_b=tile_b, interpret=interpret))
-
-
-@jax.jit
-def gf_matmul_xla_packed(a_planes: jnp.ndarray,
-                         data: jnp.ndarray) -> jnp.ndarray:
-    """gf_matmul_xla fused with the u32 transfer packing; B % 4 == 0."""
-    return _pack_u32_lanes(gf_matmul_xla(a_planes, data))
+    """gf_matmul_pallas in the word layout: words [K, B//4] u32 ->
+    [R, B//4] u32, the whole dispatch one kernel."""
+    return gf_matmul_pallas(a_planes, words, tile_b=tile_b,
+                            interpret=interpret, words=True)
 
 
 def unpack_u32_host(words: np.ndarray, width: int) -> np.ndarray:
-    """Host-side inverse of _pack_u32_lanes: [R, width//4] u32 -> [R, width]
-    u8 (no copy beyond the fetch buffer when already little-endian)."""
+    """The word layout's output back to bytes: [R, width//4] u32 -> [R,
+    width] u8 (no copy beyond the fetch buffer when already little-endian)."""
     arr = np.ascontiguousarray(words)
     if arr.dtype.byteorder == ">":  # pragma: no cover - TPU hosts are LE
         arr = arr.astype("<u4")

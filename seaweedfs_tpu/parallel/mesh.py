@@ -82,9 +82,9 @@ def parse_device_spec(spec=None, devices: list | None = None) -> list:
 
 def device_encode_fn(on_tpu: bool = False, tile_b: int = 0,
                      donate: bool | None = None):
-    """Single-device jitted packed encode for the per-device dispatch
-    queues (`-ec.engine=mesh`): (planes [8R, 8K], data [K, B]) ->
-    [R, B//4] u32 transfer-packed parity.
+    """Single-device jitted encode for the per-device dispatch queues
+    (`-ec.engine=mesh`), in the word layout (ops/gf_matmul.py): (planes
+    [8R, 8K], data [K, B//4] u32) -> [R, B//4] u32 parity.
 
     The data buffer is DONATED on real accelerators so XLA reuses the
     dispatch's H2D staging block instead of holding both copies in HBM;
@@ -92,20 +92,16 @@ def device_encode_fn(on_tpu: bool = False, tile_b: int = 0,
     and ignores it).  One returned callable serves every device in the
     slice: jit specializes per input placement, so committed
     device_put inputs pin the compute to their device."""
-    from ..ops.gf_matmul import (DEFAULT_TILE_B, _pack_u32_lanes,
-                                 gf_matmul_pallas, gf_matmul_xla)
+    from ..ops.gf_matmul import (DEFAULT_TILE_B, gf_matmul_pallas_packed,
+                                 gf_matmul_xla_packed)
     if donate is None:
         donate = on_tpu
     if on_tpu:
-        tb = int(tile_b) or DEFAULT_TILE_B
-
-        def _enc(a_planes, data):
-            return _pack_u32_lanes(gf_matmul_pallas(a_planes, data,
-                                                    tile_b=tb))
+        enc = functools.partial(gf_matmul_pallas_packed,
+                                tile_b=int(tile_b) or DEFAULT_TILE_B)
     else:
-        def _enc(a_planes, data):
-            return _pack_u32_lanes(gf_matmul_xla(a_planes, data))
-    return jax.jit(_enc, donate_argnums=(1,) if donate else ())
+        enc = gf_matmul_xla_packed
+    return jax.jit(enc, donate_argnums=(1,) if donate else ())
 
 
 def factor_mesh(n_devices: int) -> tuple[int, int, int]:

@@ -7,6 +7,8 @@ the module fixture: only one process may load libtpu, and the worker
 that runs this file keeps it until it exits.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -45,20 +47,34 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _spec(shape, sharding):
-    return jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
+def _spec(shape, sharding, dtype=jnp.uint8):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_pallas_packed_at_streaming_dispatch_width(one_chip):
-    """The kernel every `-ec.engine=tpu` encode dispatch runs, at the
-    width StreamingEncoder picks for dispatch_mb=8."""
+def _words(shape, sharding):
+    return _spec(shape, sharding, jnp.uint32)
+
+
+@pytest.mark.parametrize("rows", [4, 1])
+def test_pallas_packed_at_streaming_dispatch_width(one_chip, rows):
+    """The kernel every `-ec.engine=tpu` encode and rebuild dispatch
+    runs, at the width StreamingEncoder picks for dispatch_mb=8, in the
+    word layout: the whole module is the one Pallas kernel, its ROOT,
+    with no gather (the lane packing it replaces lowered to four)."""
     from seaweedfs_tpu.ops.gf_matmul import gf_matmul_pallas_packed
 
     b = (8 << 20) // TILE * TILE
     text = gf_matmul_pallas_packed.lower(
-        _spec((8 * R, 8 * K), one_chip), _spec((K, b), one_chip),
+        _spec((8 * rows, 8 * K), one_chip), _words((K, b // 4), one_chip),
         tile_b=TILE).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert re.search(r"^HloModule jit_gf_matmul_pallas_packed\b", text,
+                     re.M)
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*ROOT %gf_matmul_pallas(\.\d+)? = ", calls[0]), \
+        calls[0]
+    assert "tpu_custom_call" in calls[0]
+    assert "gather" not in text
 
 
 @pytest.mark.parametrize("rows", [4, 1])
@@ -79,7 +95,7 @@ def test_device_encode_fn_on_tpu(one_chip):
 
     text = device_encode_fn(on_tpu=True).lower(
         _spec((8 * R, 8 * K), one_chip),
-        _spec((K, 2 * TILE), one_chip)).compile().as_text()
+        _words((K, 2 * TILE // 4), one_chip)).compile().as_text()
     assert "tpu_custom_call" in text
 
 
