@@ -123,10 +123,10 @@ class MeshEngine:
 
     This is the codec-level face of `-ec.engine=mesh`: ReedSolomon
     encode/verify/reconstruct route through it unchanged, and output is
-    byte-identical to CpuEngine (differential-test contract).  The
-    streaming pipeline's per-device dispatch queues are the OTHER face
-    of the same flag — concurrent whole dispatches rather than one
-    sharded matmul — built in ec/streaming.py on top of
+    byte-identical to CpuEngine (differential-test contract).  Encodes
+    and rebuilds of whole volumes do not come here: under `tpu` and
+    `mesh` alike the streaming pipeline (ec/streaming.py) runs whole
+    dispatches on each device of the slice through
     parallel.mesh.device_encode_fn."""
 
     name = "mesh"

@@ -158,8 +158,7 @@ class StreamingEncoder:
                  parity_shards: int = PARITY_SHARDS_COUNT,
                  matrix_kind: str = "vandermonde",
                  dispatch_mb: int = 8, depth: int = 3,
-                 engine: str = "auto", mesh: Optional[bool] = None,
-                 devices: Optional[str] = None,
+                 engine: str = "auto", devices: Optional[str] = None,
                  zero_copy: bool = True, overlap: str = "auto",
                  tracer=None, drain_timeout_s: float = 30.0,
                  max_worker_restarts: int = 3,
@@ -172,24 +171,20 @@ class StreamingEncoder:
         and the host SIMD codec otherwise (jax-on-CPU is a correctness
         surface, ~200x slower than the AVX2 codec); 'device' forces the
         jax path (tests exercise the XLA kernels with it); 'host' forces
-        the SIMD codec; 'mesh' is the per-device dispatch-queue plane
-        (`-ec.engine=mesh`): whole dispatches round-robin across the
-        device slice, each device with its own dispatch queue, slot
-        pool and drain lane (overlap.DrainerGroup) — N concurrent
-        dispatches in flight instead of serializing on device 0.
+        the SIMD codec; 'mesh' is 'device' under the name of
+        `-ec.engine=mesh`.
 
-        mesh: None shards each dispatch over ALL visible devices
-        (parallel/mesh.py dp x sp x tp shard_map) whenever more than one
-        is present, so `-ec.engine=tpu` on a multi-chip host uses every
-        chip; True forces the mesh path, False forces single-device.
-        (Only meaningful for engine='device'; the 'mesh' engine's
-        per-device queues ignore it.)
-
-        devices: engine='mesh' device selection, the `-ec.mesh.devices`
+        devices: the device engines' slice, the `-ec.mesh.devices`
         vocabulary (parallel.mesh.parse_device_spec): ''/None/'all' =
         every visible device, 'N' = the first N, 'i,j,k' = exactly
         those indices.  Validated here so a bad flag fails at server
-        start, not at first encode.
+        start, not at first encode.  The slice picks the encode plane:
+        one device runs the staged pipeline; more run the per-device
+        dispatch-queue plane (_encode_file_mesh), whole dispatches
+        round-robin across the slice, each device with its own slot
+        pool and drain lane (overlap.DrainerGroup) and the same word
+        kernel one device runs, nothing exchanged between devices.
+        Rebuilds dispatch to the slice's first device.
 
         Self-healing knobs: drain_timeout_s bounds every wait on a
         parity worker ack (a stalled worker surfaces as a fault, never a
@@ -259,11 +254,11 @@ class StreamingEncoder:
         # abandoned (killed, shm kept) workers whose buffers may still
         # back live views; fully closed once the encode call unwinds
         self._stale_workers: list = []  # guarded-by: _st_lock
-        self._mesh = None
-        self._mesh_encode = None
-        # per-device dispatch-queue plane (engine="mesh")
-        self._queue_devs = None
-        self._dev_encode = None
+        # the device engines' slice and word kernel (None in host mode)
+        self._devs = None
+        self._kernel = None
+        # the queue plane's slot buffers, kept between its encodes
+        self._queue_bufs = None  # guarded-by: _st_lock
         b = dispatch_mb << 20
         if engine == "host":
             self.on_tpu = False
@@ -303,50 +298,21 @@ class StreamingEncoder:
 
             from ..ops.device import device_backend, ensure_compile_cache
             from ..ops.gf_matmul import DEFAULT_TILE_B, expand_matrix_bitplanes
+            from ..parallel.mesh import device_encode_fn, parse_device_spec
 
             self._jax = jax
             self._expand = expand_matrix_bitplanes
             # a TPU, or a process pinned to the CPU — never a silent CPU
             self.on_tpu = device_backend() == "tpu"
             ensure_compile_cache()
-            # one fixed dispatch width: multiple of the pallas tile on TPU
-            if self.on_tpu:
-                b = max(DEFAULT_TILE_B, (b // DEFAULT_TILE_B) * DEFAULT_TILE_B)
-            if engine == "mesh":
-                # per-device dispatch queues: each device computes WHOLE
-                # dispatches (single-device kernel geometry — a multiple
-                # of 64 keeps the u32 word layout and the XLA
-                # layouts happy), so the throughput lever is N dispatches
-                # in flight across the slice, not a sharded matmul
-                from ..parallel.mesh import (device_encode_fn,
-                                             parse_device_spec)
-
-                self._queue_devs = parse_device_spec(devices)
-                self._dev_encode = device_encode_fn(on_tpu=self.on_tpu)
-                if not self.on_tpu:
-                    b = max(64, (b // 64) * 64)
-                # one plane copy per device stays cached
-                self._plane_cache_max_override = max(
-                    8, 2 * len(self._queue_devs))
-            else:
-                # multi-chip: shard every dispatch over the full device
-                # mesh (dp over stripe rows, sp over byte columns, psum
-                # over the tp contraction) — `-ec.engine=tpu` must use
-                # every chip
-                ndev = len(jax.devices())
-                if mesh is None:
-                    mesh = ndev > 1
-                if mesh:
-                    from ..parallel.mesh import (factor_mesh, make_mesh,
-                                                 sharded_encode_fn)
-
-                    dp, sp, tp = factor_mesh(ndev)
-                    self._mesh = make_mesh(dp, sp, tp)
-                    self._mesh_dims = (dp, sp, tp)
-                    self._mesh_encode = sharded_encode_fn(self._mesh)
-                    # the dispatch width must split evenly over dp*sp
-                    q = dp * sp * (DEFAULT_TILE_B if self.on_tpu else 64)
-                    b = max(q, (b // q) * q)
+            self._devs = parse_device_spec(devices)
+            # every device of the slice runs the one-device word kernel
+            # on whole dispatches
+            self._kernel = device_encode_fn(on_tpu=self.on_tpu)
+            # one fixed dispatch width: a multiple of the pallas tile on
+            # TPU, of 64 (the u32 word layout) on the CPU
+            q = DEFAULT_TILE_B if self.on_tpu else 64
+            b = max(q, (b // q) * q)
         self.dispatch_b = b
         self.depth = depth
         # same matrix family as ReedSolomon so shards are byte-identical
@@ -355,9 +321,10 @@ class StreamingEncoder:
         self._mat_rows = np.ascontiguousarray(self.matrix[data_shards:])
         # LRU: a long-lived volume server cycles geometries and rebuild
         # matrices (every distinct erasure pattern is a distinct key) —
-        # unbounded growth would pin HBM-resident plane arrays forever
+        # unbounded growth would pin HBM-resident plane arrays forever;
+        # one copy per device of the slice stays cached
         self._plane_cache: OrderedDict[bytes, object] = OrderedDict()
-        self._plane_cache_max = getattr(self, "_plane_cache_max_override", 8)
+        self._plane_cache_max = max(8, 2 * len(self._devs or ()))
         # per-call pipeline counters (_reset_stats); where the time of
         # each stage went is the pipeline.* spans' job
         self.stats: dict[str, float] = {}
@@ -381,28 +348,18 @@ class StreamingEncoder:
                         worker_pid=worker.worker_pid)
 
     # --- kernel dispatch --------------------------------------------------
-    def _planes(self, rows: np.ndarray):
-        """Device mode: cached bit-plane expansion resident in HBM.
-        Host mode: the raw GF(2^8) rows, consumed by the SIMD codec."""
+    def _planes(self, rows: np.ndarray, dev: int = 0):
+        """Device mode: the bit-plane expansion, cached resident on
+        device `dev` of the slice, so no dispatch waits on a plane
+        transfer.  Host mode: the raw GF(2^8) rows, consumed by the SIMD
+        codec."""
         rows = np.ascontiguousarray(rows)
         if self.engine == "host":
             return rows
-        key = rows.tobytes() + bytes([rows.shape[0]])
+        key = rows.tobytes() + bytes([rows.shape[0], dev & 0xFF])
         p = self._plane_cache.get(key)
         if p is None:
-            import jax.numpy as jnp
-
-            if self._mesh is not None:
-                # pre-place with the shard_map's in_spec sharding so the
-                # jitted call never reshards the (hot, cached) planes
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
-
-                p = self._jax.device_put(
-                    self._expand(rows),
-                    NamedSharding(self._mesh, P(None, "tp")))
-            else:
-                p = jnp.asarray(self._expand(rows))
+            p = self._jax.device_put(self._expand(rows), self._devs[dev])
             self._plane_cache[key] = p  # weedlint: disable=W502 producer-only LRU: _planes runs on the critical thread, never on drain threads
             if len(self._plane_cache) > self._plane_cache_max:
                 self._plane_cache.popitem(last=False)
@@ -410,59 +367,22 @@ class StreamingEncoder:
             self._plane_cache.move_to_end(key)
         return p
 
-    def _planes_dev(self, rows: np.ndarray, dev_index: int):
-        """engine="mesh": the bit-plane expansion committed to ONE
-        device of the slice — each dispatch queue computes against its
-        own resident copy, so no queue ever waits on a cross-device
-        plane transfer.  Same LRU as _planes (the cache cap is raised
-        to 2x the slice size at construction)."""
-        rows = np.ascontiguousarray(rows)
-        key = rows.tobytes() + bytes([rows.shape[0], dev_index & 0xFF])
-        p = self._plane_cache.get(key)
-        if p is None:
-            p = self._jax.device_put(self._expand(rows),
-                                     self._queue_devs[dev_index])
-            self._plane_cache[key] = p  # weedlint: disable=W502 producer-only LRU: _planes_dev runs on the critical thread, never on drain threads
-            if len(self._plane_cache) > self._plane_cache_max:
-                self._plane_cache.popitem(last=False)
-        else:
-            self._plane_cache.move_to_end(key)
-        return p
-
-    def _dispatch(self, planes, buf: np.ndarray):
-        """Device mode, async: the [K, dispatch_b] u8 buffer goes up as
-        [K, dispatch_b//4] u32 words (a free view: C-contiguous, width a
-        multiple of 4) and comes back an unfetched [R, dispatch_b//4] u32
-        device array in the same word layout (ops/gf_matmul.py), with the
-        D2H copy already queued behind the kernel, so the fetch streams
-        down while later dispatches compute.  Host mode: the SIMD codec
-        runs synchronously and the parity comes back finished."""
+    def _dispatch(self, planes, buf: np.ndarray, dev: int = 0):
+        """Device mode, async: the [K, dispatch_b] u8 buffer goes up to
+        device `dev` of the slice as [K, dispatch_b//4] u32 words (a
+        free view: C-contiguous, width a multiple of 4) and comes back
+        an unfetched [R, dispatch_b//4] u32 device array in the same
+        word layout (ops/gf_matmul.py), with the D2H copy already queued
+        behind the kernel, so the fetch streams down while later
+        dispatches compute.  Host mode: the SIMD codec runs
+        synchronously and the parity comes back finished."""
         if self.engine == "host":
             if self._host_pool is None:
                 return self._host_engine.matmul(planes, buf)
             return self._host_pool.submit(self._host_engine.matmul,
                                           planes, buf)
-        if self._mesh_encode is not None:
-            # multi-chip: view the byte stream as a [dp, b/dp] stripe
-            # grid and let the shard_map place dp x sp blocks per chip
-            from ..parallel.mesh import shard_data
-
-            dp, sp, tp = self._mesh_dims
-            k = buf.shape[0]
-            dev = shard_data(self._mesh,
-                             buf.reshape(k, dp, self.dispatch_b // dp))
-            out = self._mesh_encode(planes, dev)  # [R, dp, b/dp] u8
-            # the devices holding this dispatch's parity blocks
-            self.stats["devices"] = len(out.sharding.device_set)  # weedlint: disable=W502 _dispatch runs on the producer thread only; drain threads never touch this key
-        else:
-            from ..ops.gf_matmul import (gf_matmul_pallas_packed,
-                                         gf_matmul_xla_packed)
-
-            dev = self._jax.device_put(buf.view(np.uint32))
-            if self.on_tpu:
-                out = gf_matmul_pallas_packed(planes, dev)
-            else:
-                out = gf_matmul_xla_packed(planes, dev)
+        out = self._kernel(planes, self._jax.device_put(
+            buf.view(np.uint32), self._devs[dev]))
         out.copy_to_host_async()
         return out
 
@@ -485,8 +405,6 @@ class StreamingEncoder:
         from ..ops.gf_matmul import unpack_u32_host
 
         words = np.asarray(out_dev)
-        if words.ndim == 3:  # mesh path: unpacked u8 [R, dp, b/dp]
-            return words.reshape(words.shape[0], -1)
         return unpack_u32_host(words, words.shape[1] * 4)
 
     # --- encode -----------------------------------------------------------
@@ -1114,9 +1032,10 @@ class StreamingEncoder:
                 matmul_ptrs)
         retries = 0
         start_entry = start_byte = 0
-        # the mesh plane shares the staged pipeline's checkpoint-resume
+        # the slice picks the plane: per-device queues on more than one
+        # device, which share the staged pipeline's checkpoint-resume
         # contract (self._ckpt) so the retry loop below serves both
-        attempt = (self._encode_file_mesh if self.engine == "mesh"
+        attempt = (self._encode_file_mesh if len(self._devs or ()) > 1
                    else self._encode_file_staged)
         try:
             while True:
@@ -1175,10 +1094,11 @@ class StreamingEncoder:
         t_start = clock()
         planes = self._planes(self.matrix[k:])
         file_size = os.path.getsize(dat_path)
+        st["devices"] = 1 if self._devs else 0
         tr = self._tracer()
         root = tr.span("pipeline.encode_file", path=dat_path,
                        bytes=file_size, mode="staged", engine=self.engine,
-                       resume_entry=start_entry)
+                       devices=st["devices"], resume_entry=start_entry)
         root.__enter__()
         # setup covers output opens (O_TRUNC over existing shards frees
         # their page cache — real, attributable time), buffer allocation
@@ -1607,14 +1527,15 @@ class StreamingEncoder:
                           large_block_size: int, small_block_size: int,
                           start_entry: int = 0, start_byte: int = 0,
                           retries: int = 0) -> None:
-        """One attempt of the per-device dispatch-queue plane
-        (`-ec.engine=mesh`): whole dispatches round-robin across the
-        device slice, so N dispatches compute and transfer concurrently
-        instead of serializing on device 0.
+        """One attempt of the per-device dispatch-queue plane (a slice
+        of more than one device): whole dispatches round-robin across
+        the slice, so N dispatches compute and transfer concurrently
+        instead of serializing on device 0, and no byte crosses between
+        devices.
 
-        Per device: a slot pool of donated host staging buffers (one
-        committed device_put batches the whole [k, b] H2D), a dispatch
-        queue, and its own drain lane (overlap.DrainerGroup) — a slow
+        Per device: a slot pool of host staging buffers (one committed
+        device_put batches the whole [k, b] H2D), a dispatch queue, and
+        its own drain lane (overlap.DrainerGroup) — a slow
         device back-pressures only its own queue.  Up to `coalesce`
         dispatches ride one drain call per device, so several D2H
         transfers amortize one wire turnaround when the link is the
@@ -1631,8 +1552,7 @@ class StreamingEncoder:
         the CPU codec exactly like the staged path (the CPU parity rides
         the same lane as a plain ndarray handle)."""
         k, r, b = self.k, self.r, self.dispatch_b
-        devs = self._queue_devs
-        nd = len(devs)
+        nd = len(self._devs)
         # several dispatches per drain call when a thin link dominates;
         # on CPU/GPU backends the "transfer" is a memcpy — keep latency
         coalesce = 2 if self.on_tpu else 1
@@ -1643,8 +1563,7 @@ class StreamingEncoder:
         self._ckpt = (start_entry, start_byte)  # weedlint: disable=W502 producer writes it before the drain lanes start; the writer lanes advance it under comp_lock and the producer re-reads only after the group is joined
         clock = time.perf_counter
         t_start = clock()
-        planes_dev = [self._planes_dev(self.matrix[k:], i)
-                      for i in range(nd)]
+        planes_dev = [self._planes(self.matrix[k:], i) for i in range(nd)]
         file_size = os.path.getsize(dat_path)
         tr = self._tracer()
         root = tr.span("pipeline.encode_file", path=dat_path,
@@ -1669,9 +1588,19 @@ class StreamingEncoder:
                     f = open(p, "wb")
                 outputs.append(f)
             out_fds = [f.fileno() for f in outputs]
-            dev_bufs = [[np.zeros((k, b), dtype=np.uint8)
-                         for _ in range(slots_per_dev)]
-                        for _ in range(nd)]
+            # the slots outnumber what a few dispatches reuse (12 on
+            # four TPUs; a 1 GiB volume is 13 dispatches), so fresh
+            # buffers would page-fault on nearly every fill: reuse the
+            # last clean encode's.  Taken under the lock, so concurrent
+            # encodes never share them
+            with self._st_lock:
+                dev_bufs, self._queue_bufs = self._queue_bufs, None
+            if dev_bufs is None or len(dev_bufs) != nd or \
+                    len(dev_bufs[0]) != slots_per_dev or \
+                    dev_bufs[0][0].shape != (k, b):
+                dev_bufs = [[np.zeros((k, b), dtype=np.uint8)
+                             for _ in range(slots_per_dev)]
+                            for _ in range(nd)]
         except BaseException:
             for f in outputs:
                 f.close()
@@ -1871,16 +1800,8 @@ class StreamingEncoder:
                                      device=dev_i, reason=reason)
                         else:
                             try:
-                                # committed device_put batches the whole
-                                # [k, b] H2D, as [k, b//4] u32 words, to
-                                # THIS device; the jitted kernel (donated
-                                # input on TPU) leaves a u32 word handle
-                                # with its D2H queued
-                                darr = self._jax.device_put(
-                                    buf.view(np.uint32), devs[dev_i])
-                                handle = self._dev_encode(
-                                    planes_dev[dev_i], darr)
-                                handle.copy_to_host_async()
+                                handle = self._dispatch(planes_dev[dev_i],
+                                                        buf, dev_i)
                             except (KeyboardInterrupt, SystemExit):
                                 raise
                             except Exception as e:
@@ -1946,7 +1867,12 @@ class StreamingEncoder:
             exc = sys.exc_info() if not ok else (None, None, None)
             if setup is not None:  # failed before the loop started
                 setup.__exit__(*exc)
-            if not ok:
+            if ok:
+                # every dispatch was fetched, so no transfer still reads
+                # the slots; after a fault one may, so they are dropped
+                with self._st_lock:
+                    self._queue_bufs = dev_bufs
+            else:
                 drainers.abort()
             st["drain_s"] += ds["drain_s"]
             st["sidecar_s"] += ds["sidecar_s"]

@@ -15,11 +15,14 @@ stripes per launch and shard them across chips.  Three mesh axes, all real:
 Collectives ride the mesh exactly like a sharded matmul's — psum over tp —
 so XLA lays them on ICI.  dp/sp need no communication (parity is pointwise
 in the byte-stream).
+
+The shard_map serves ec/codec.py's MeshEngine.  The streaming encode and
+rebuild do not use it: on a slice of several devices each device runs
+whole dispatches through device_encode_fn, and nothing crosses between
+them (ec/streaming.py).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -80,28 +83,17 @@ def parse_device_spec(spec=None, devices: list | None = None) -> list:
     return [devices[i] for i in idxs]
 
 
-def device_encode_fn(on_tpu: bool = False, tile_b: int = 0,
-                     donate: bool | None = None):
-    """Single-device jitted encode for the per-device dispatch queues
-    (`-ec.engine=mesh`), in the word layout (ops/gf_matmul.py): (planes
-    [8R, 8K], data [K, B//4] u32) -> [R, B//4] u32 parity.
+def device_encode_fn(on_tpu: bool = False):
+    """The jitted one-device encode that every streaming dispatch runs,
+    in the word layout (ops/gf_matmul.py): (planes [8R, 8K], data
+    [K, B//4] u32) -> [R, B//4] u32 parity.  On a TPU the Pallas kernel
+    (gf_matmul_pallas_packed, tile DEFAULT_TILE_B), on the CPU its XLA
+    twin.  One callable serves every device in the slice: jit
+    specializes per input placement, so committed device_put inputs
+    pin the compute to their device."""
+    from ..ops.gf_matmul import gf_matmul_pallas_packed, gf_matmul_xla_packed
 
-    The data buffer is DONATED on real accelerators so XLA reuses the
-    dispatch's H2D staging block instead of holding both copies in HBM;
-    donation is skipped on cpu backends (unsupported there — jax warns
-    and ignores it).  One returned callable serves every device in the
-    slice: jit specializes per input placement, so committed
-    device_put inputs pin the compute to their device."""
-    from ..ops.gf_matmul import (DEFAULT_TILE_B, gf_matmul_pallas_packed,
-                                 gf_matmul_xla_packed)
-    if donate is None:
-        donate = on_tpu
-    if on_tpu:
-        enc = functools.partial(gf_matmul_pallas_packed,
-                                tile_b=int(tile_b) or DEFAULT_TILE_B)
-    else:
-        enc = gf_matmul_xla_packed
-    return jax.jit(enc, donate_argnums=(1,) if donate else ())
+    return gf_matmul_pallas_packed if on_tpu else gf_matmul_xla_packed
 
 
 def factor_mesh(n_devices: int) -> tuple[int, int, int]:

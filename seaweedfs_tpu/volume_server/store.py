@@ -172,7 +172,7 @@ class Store:
         self.ec_collections: dict[int, str] = {}
         self.volume_size_limit = 30 * 1000 * 1000 * 1000
         self.ec_engine_name = ec_engine
-        # -ec.mesh.devices: device selection for the mesh encode plane
+        # -ec.mesh.devices: the device slice of -ec.engine=mesh
         # (parallel.mesh.parse_device_spec vocabulary); "" = all devices.
         self.ec_mesh_devices = ec_mesh_devices
         # stats of the last streaming encode/rebuild (admin responses)
@@ -776,9 +776,9 @@ class Store:
             name = engine or self.ec_engine_name
             if name in ("tpu", "mesh"):
                 # overlapped device pipeline (ec/streaming.py), not the
-                # serial read->matmul->write loop; "mesh" spreads whole
-                # dispatches across per-device queues instead of
-                # sharding each one
+                # serial read->matmul->write loop; on several devices
+                # both engines spread whole dispatches across
+                # per-device queues
                 enc = self._streaming_encoder(name)
                 enc.encode_file(base + ".dat", base)
                 self.last_ec_stats = enc.stats
@@ -801,7 +801,9 @@ class Store:
             return ec_encoder.rebuild_ec_files(base, self.rs(engine))
 
     def _streaming_encoder(self, engine: str = "tpu"):
-        # explicit device engines only: never auto-detected
+        # explicit device engines only: never auto-detected.  tpu and
+        # mesh share one encode plane, chosen by the slice's size
+        # (ec/streaming.py); mesh adds only the -ec.mesh.devices slice
         stream = "mesh" if engine == "mesh" else "device"
         cache = getattr(self, "_stream_encs", None)
         if cache is None:

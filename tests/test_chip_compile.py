@@ -90,18 +90,27 @@ def test_pallas_reconstruct_rows(one_chip, rows):
 
 
 def test_device_encode_fn_on_tpu(one_chip):
-    """The per-device queue kernel of `-ec.engine=mesh` (donated input)."""
+    """The kernel each chip of a slice runs in the per-device queues
+    (`-ec.engine=tpu|mesh` on several chips), as the benchmark finds it
+    (benchmark/kernels.py): the module jit_gf_matmul_pallas_packed, its
+    one custom call the ROOT gf_matmul_pallas Pallas kernel."""
     from seaweedfs_tpu.parallel.mesh import device_encode_fn
 
     text = device_encode_fn(on_tpu=True).lower(
         _spec((8 * R, 8 * K), one_chip),
         _words((K, 2 * TILE // 4), one_chip)).compile().as_text()
-    assert "tpu_custom_call" in text
+    assert re.search(r"^HloModule jit_gf_matmul_pallas_packed\b", text,
+                     re.M)
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*ROOT %gf_matmul_pallas(\.\d+)? = ", calls[0]), \
+        calls[0]
+    assert "tpu_custom_call" in calls[0]
 
 
 def test_sharded_encode_fn_on_v5e_2x2(topo):
-    """`-ec.engine=tpu` on a four-chip host: dp x sp x tp = 1 x 2 x 2,
-    the tp contraction folded with an all-reduce."""
+    """MeshEngine's codec matmul on a four-chip host: dp x sp x tp =
+    1 x 2 x 2, the tp contraction folded with an all-reduce."""
     from seaweedfs_tpu.parallel.mesh import (factor_mesh, make_mesh,
                                              sharded_encode_fn)
 

@@ -261,7 +261,7 @@ def test_large_response_streams_to_fast_client(plain_server):
 
 def test_shed_deadline_trace_reqlog_through_reactor():
     from seaweedfs_tpu.observability import (disable_tracing,
-                                             enable_tracing,
+                                             enable_tracing, sample_rate,
                                              set_sample_rate)
     from seaweedfs_tpu.observability.reqlog import get_recorder
     from seaweedfs_tpu.utils.admission import AdmissionController
@@ -282,6 +282,7 @@ def test_shed_deadline_trace_reqlog_through_reactor():
     srv = serve(r, "127.0.0.1", 0)
     port = srv.server_address[1]
     enable_tracing()
+    prev_rate = sample_rate()
     set_sample_rate(0.0)
     rec = get_recorder()
     rec.start(sample=1.0, reset=True)
@@ -322,6 +323,8 @@ def test_shed_deadline_trace_reqlog_through_reactor():
         rec.stop()
         rec.clear()
         disable_tracing()
+        # a later test's spans must not inherit the 0 rate
+        set_sample_rate(prev_rate)
         stop_server(srv)
 
 
