@@ -10,9 +10,13 @@ runs a depth-N asynchronous pipeline over fixed-shape dispatches:
     (n bytes per shard at computed offsets, packed side-by-side into a
     [data_shards, DISPATCH_B] host buffer — many small-block rows share
     one dispatch, large-block rows are chunked across dispatches);
-  - every dispatch has the SAME shape, so XLA compiles exactly one
-    kernel (tail dispatches are zero-padded, and parity-of-zeros is
-    zeros, which is simply not written out);
+  - on one device (the staged plane) a dispatch of whole small-block
+    rows needs no fill: it goes up as a [rows, K, block] view of the
+    read-only mapped `.dat`, which the kernel reads in file order
+    (ops/gf_matmul.py) and whose parity comes back as a slot's would;
+  - every dispatch has the SAME width, so XLA compiles one kernel per
+    input layout (tail dispatches are zero-padded, and parity-of-zeros
+    is zeros, which is simply not written out);
   - dispatch d+1's host fill and the data-shard writes (data bytes are
     a host-side pass-through) overlap the device compute of dispatch d:
     `device_put` + the jitted kernel return immediately, and the parity
@@ -150,6 +154,66 @@ def _plan_entries(file_size: int, k: int, large: int, small: int,
         start += small * k
 
 
+class _DatMap:
+    """The `.dat` mapped read-only for one device encode attempt, so a
+    dispatch of whole small-block rows goes to the device as a view of
+    the file's own bytes, [rows, K, block] in file order, with no fill.
+
+    The device transfer may alias a view (zero-copy host buffers, always
+    on the CPU backend), so the map closes only once every dispatch that
+    viewed it was fetched and its views dropped: close() reports False
+    while one lives, and the encoder closes it again later."""
+
+    def __init__(self, path: str, size: int):
+        import mmap as mmap_mod
+
+        self._mod = mmap_mod
+        self.size = size
+        with open(path, "rb") as f:
+            # the map holds its own descriptor
+            self._mm = mmap_mod.mmap(f.fileno(), size,
+                                     access=mmap_mod.ACCESS_READ)
+        try:
+            self._mm.madvise(mmap_mod.MADV_SEQUENTIAL)
+        except OSError:
+            pass
+        self.arr = np.frombuffer(self._mm, dtype=np.uint8)
+
+    def rows(self, start: int, g: int, k: int, block: int) -> np.ndarray:
+        """Rows [start, start + g*k*block) as [g, k, block] u8, their
+        pages pre-faulted in one call (MADV_POPULATE_READ, Linux 5.14+)
+        so the device copy and the data writes take no fault a page."""
+        n = g * k * block
+        try:
+            self._mm.madvise(getattr(self._mod, "MADV_POPULATE_READ", 22),
+                             start, n)
+        except (OSError, ValueError):
+            pass
+        return self.arr[start:start + n].reshape(g, k, block)
+
+    def close(self) -> bool:
+        """Unmap unless a view still lives; True once unmapped."""
+        self.arr = None
+        if self._mm is None:
+            return True
+        try:
+            self._mm.close()
+        except BufferError:
+            return False
+        self._mm = None
+        return True
+
+
+def _shard_major(src: np.ndarray, used: int) -> np.ndarray:
+    """A dispatch's input as [K, used] shard-major bytes, the CPU
+    codec's layout: a slot's first columns, or a row group's rows side
+    by side (a copy)."""
+    if src.ndim == 3:
+        g, k, block = src.shape
+        return src.transpose(1, 0, 2).reshape(k, g * block)
+    return src[:, :used]
+
+
 class StreamingEncoder:
     """File-level EC encode/rebuild through the bit-plane TPU kernel with
     an overlapped host-IO / device-compute pipeline."""
@@ -257,8 +321,8 @@ class StreamingEncoder:
         # the device engines' slice and word kernel (None in host mode)
         self._devs = None
         self._kernel = None
-        # the queue plane's slot buffers, kept between its encodes
-        self._queue_bufs = None  # guarded-by: _st_lock
+        # the staged or queue plane's slot buffers, kept between encodes
+        self._slot_bufs = None  # guarded-by: _st_lock
         b = dispatch_mb << 20
         if engine == "host":
             self.on_tpu = False
@@ -310,9 +374,11 @@ class StreamingEncoder:
             # on whole dispatches
             self._kernel = device_encode_fn(on_tpu=self.on_tpu)
             # one fixed dispatch width: a multiple of the pallas tile on
-            # TPU, of 64 (the u32 word layout) on the CPU
+            # TPU, of 64 (the u32 word layout) on the CPU; a row group's
+            # block must be one too (_row_group)
             q = DEFAULT_TILE_B if self.on_tpu else 64
             b = max(q, (b // q) * q)
+            self._row_q = q
         self.dispatch_b = b
         self.depth = depth
         # same matrix family as ReedSolomon so shards are byte-identical
@@ -368,12 +434,13 @@ class StreamingEncoder:
         return p
 
     def _dispatch(self, planes, buf: np.ndarray, dev: int = 0):
-        """Device mode, async: the [K, dispatch_b] u8 buffer goes up to
-        device `dev` of the slice as [K, dispatch_b//4] u32 words (a
-        free view: C-contiguous, width a multiple of 4) and comes back
-        an unfetched [R, dispatch_b//4] u32 device array in the same
-        word layout (ops/gf_matmul.py), with the D2H copy already queued
-        behind the kernel, so the fetch streams down while later
+        """Device mode, async: the [K, dispatch_b] u8 slot, or a row
+        group's [rows, K, block] u8 view of the mapped `.dat`, goes up
+        to device `dev` of the slice as u32 words (a free view:
+        C-contiguous, last axis a multiple of 4) and comes back an
+        unfetched [R, dispatch_b//4] u32 device array in the same word
+        layout (ops/gf_matmul.py) either way, with the D2H copy already
+        queued behind the kernel, so the fetch streams down while later
         dispatches compute.  Host mode: the SIMD codec runs
         synchronously and the parity comes back finished."""
         if self.engine == "host":
@@ -381,10 +448,126 @@ class StreamingEncoder:
                 return self._host_engine.matmul(planes, buf)
             return self._host_pool.submit(self._host_engine.matmul,
                                           planes, buf)
+        # rows land on a TPU in its default layout for [rows, K, W],
+        # K-major, and the jitted kernel starts with a copy to the
+        # row-major layout it reads: that copy is part of the dispatch
         out = self._kernel(planes, self._jax.device_put(
             buf.view(np.uint32), self._devs[dev]))
         out.copy_to_host_async()
         return out
+
+    def _map_dat(self, dat_path: str, file_size: int, large: int,
+                 small: int) -> Optional[_DatMap]:
+        """The `.dat` mapped for row-group dispatches, or None where no
+        dispatch can be one: the host engine, or a volume shorter than
+        one group, or blocks that do not divide the dispatch."""
+        b = self.dispatch_b
+        if self._devs is None or file_size < self.k * b or not any(
+                blk and b % blk == 0 and blk % self._row_q == 0
+                for blk in (large, small)):
+            return None
+        return _DatMap(dat_path, file_size)
+
+    def _row_group(self, fills: list, used: int,
+                   dat_map: Optional[_DatMap]) -> Optional[np.ndarray]:
+        """A packed dispatch as one [rows, K, block] view of the mapped
+        `.dat`, where its entries are dispatch_b // block consecutive
+        whole rows of one block size, all inside the file; else None,
+        and the dispatch is filled into a slot."""
+        if dat_map is None or used != self.dispatch_b:
+            return None
+        _, _, start, block, _ = fills[0]
+        step = self.k * block
+        if len(fills) * block != used or block % self._row_q or \
+                start + len(fills) * step > dat_map.size:
+            return None
+        for j, (_, n, row_start, blk, off) in enumerate(fills):
+            if off or n != block or blk != block or \
+                    row_start != start + j * step:
+                return None
+        return dat_map.rows(start, len(fills), self.k, block)
+
+    def _fill(self, tr, dat, buf: np.ndarray, fills: list, used: int,
+              d_idx: int, **attrs) -> None:
+        """Read a dispatch's packed entries into its slot, shard-major,
+        and zero the columns past them."""
+        k = self.k
+        with tr.span("pipeline.fill", dispatch=d_idx, bytes=k * used,
+                     **attrs):
+            for col, n, row_start, block, off in fills:
+                if off == 0 and n == block:
+                    # whole-block entry: the k per-shard reads are
+                    # CONTIGUOUS in the file ([row_start, +k*block)) —
+                    # one vectored read straight into the k strided
+                    # buffer slices, no intermediate copy
+                    preadv_into(dat, [buf[i, col:col + n]
+                                      for i in range(k)], row_start)
+                else:
+                    for i in range(k):
+                        buf[i, col:col + n] = pread_padded(
+                            dat, n, row_start + i * block + off)
+            if used < buf.shape[1]:
+                buf[:, used:] = 0
+
+    def _count_dispatch(self, st: dict, mapped: bool) -> None:
+        """One dispatch of the staged or queue plane, in the call's
+        stats and the process counters /status reports (EcPipeline
+        `dispatches`, `mapped_dispatches`)."""
+        from ..stats import ec_pipeline_metrics
+
+        st["dispatches"] += 1
+        st["mapped_dispatches"] += mapped
+        ec_pipeline_metrics().dispatches.inc("map" if mapped else "slot")
+
+    def _write_data(self, outputs: list, sb, src: np.ndarray,
+                    used: int) -> float:
+        """Data shards pass through from the dispatch's input: shard i
+        takes a slot's row i, or block i of each row of a group in
+        order; the `.eci` crc stream takes each slice right after its
+        write, while the slice is still in the CPU's cache (one vectored
+        write a shard, crc after, ran slower on a v5e host).  Returns
+        the crc time."""
+        crc_s = 0.0
+        for i in range(self.k):
+            for p in (src[:, i] if src.ndim == 3 else (src[i, :used],)):
+                outputs[i].write(memoryview(p))
+                if sb is not None:
+                    t0 = time.perf_counter()
+                    sb.update(i, p)
+                    crc_s += time.perf_counter() - t0
+        return crc_s
+
+    def _take_slots(self, nd: int, per_dev: int) -> list:
+        """[nd][per_dev] slot buffers: the last clean encode's where the
+        geometry matches, else None each, a slot's buffer allocated by
+        its first fill (a dispatch of mapped rows takes the slot's token
+        alone).  Kept buffers spare the fills the page faults of fresh
+        memory, which cost the queue plane 18% of its MB/s on four
+        chips; taken under the lock, so concurrent encodes never share
+        them."""
+        with self._st_lock:
+            kept, self._slot_bufs = self._slot_bufs, None
+        if kept is not None and len(kept) == nd and all(
+                len(s) == per_dev and all(
+                    x is None or x.shape == (self.k, self.dispatch_b)
+                    for x in s) for s in kept):
+            return kept
+        return [[None] * per_dev for _ in range(nd)]
+
+    def _keep_slots(self, slots: list) -> None:
+        """After a clean encode every dispatch was fetched, so no
+        transfer still reads the slots; after a fault one may, and the
+        caller drops them."""
+        with self._st_lock:
+            self._slot_bufs = slots
+
+    def _close_map(self, dat_map: Optional[_DatMap]) -> None:
+        """Close an attempt's map, or leave it to _reap_stale_workers
+        while a view lives (a transfer not yet released, a fault's
+        traceback)."""
+        if dat_map is not None and not dat_map.close():
+            with self._st_lock:
+                self._stale_workers.append(dat_map)
 
     def _fetch(self, out_dev) -> np.ndarray:  # thread-entry
         """Blocking fetch + host-side unpack back to [R, dispatch-width]
@@ -414,6 +597,9 @@ class StreamingEncoder:
         # alias under _st_lock, never rebind the attribute
         self.stats = {  # weedlint: disable=W502 rebound before the drain threads exist
                       "dispatches": 0, "wall_s": 0.0, "bytes_in": 0,
+                      # dispatches sent as views of the mapped .dat
+                      # (_row_group), no fill
+                      "mapped_dispatches": 0,
                       "retries": 0, "fallbacks": 0, "worker_restarts": 0,
                       # integrity accounting: sidecar_s = crc build time
                       # on encodes, verify_s = survivor verification on
@@ -522,15 +708,23 @@ class StreamingEncoder:
         # the encode's flush/drain closures form reference cycles that
         # keep shm-backed buffer views alive past the call's return;
         # collect them now so close() can actually release the mappings
-        # (rare path: only runs after a mid-encode worker abandonment)
+        # (rare path: only runs after a mid-encode worker abandonment
+        # or a failed device attempt)
         import gc
 
         gc.collect()
+        keep = []
         for w in stale:
             try:
-                w.close()
+                if w.close() is False:
+                    # a mapped .dat whose view the exception being
+                    # handled still holds: the next reap closes it
+                    keep.append(w)
             except Exception:  # pragma: no cover - best-effort teardown
                 pass
+        if keep:
+            with self._st_lock:
+                self._stale_workers.extend(keep)
 
     # --- zero-copy host path ----------------------------------------------
     def _native_ptrs(self):
@@ -1144,10 +1338,11 @@ class StreamingEncoder:
                         tr.event("pipeline.fallback", reason="worker_spawn",
                                  error=f"{type(e).__name__}: {e}")
             # process overlap: dispatch buffers ARE the shared-memory pool
-            bufs = self._proc_worker.bufs \
-                if self._proc_worker is not None \
-                else [np.zeros((k, b), dtype=np.uint8)
-                      for _ in range(self.depth + 1)]
+            slots = None if self._proc_worker is not None \
+                else self._take_slots(1, self.depth + 1)
+            bufs = self._proc_worker.bufs if slots is None else slots[0]
+            dat_map = self._map_dat(dat_path, file_size, large_block_size,
+                                    small_block_size)
         except BaseException:
             # the main finally never runs if setup dies: close what
             # opened and unwind the span stack
@@ -1159,8 +1354,8 @@ class StreamingEncoder:
             raise
         free: deque[int] = deque(range(len(bufs)))
         # (parity handle, packed width, buffer index, dispatch index,
-        #  entries packed into the dispatch)
-        pending: deque[tuple[object, int, int, int, int]] = deque()
+        #  entries packed into the dispatch, mapped rows or None)
+        pending: deque[tuple] = deque()
 
         ok = False
         flags = {"degraded": False}  # terminal fault: rest goes CPU
@@ -1170,14 +1365,13 @@ class StreamingEncoder:
         ds_lock = threading.Lock()
         slot_q: queue_mod.Queue = queue_mod.Queue()
         drainer: Optional[AsyncDrainer] = None
-
         def drain_fetch_core(meta):
             """Fetch (or fault/fallback-recompute) ONE dispatch's parity
             — the only place kernel output crosses back to the host.
             Runs on the drainer's fetch pool in async mode, inline on
             the pipeline thread in serial mode.  Returns
             (parity[:, :u], fetch_s, fetched_bytes)."""
-            parity_dev, u, bi, d_idx, nfills = meta
+            parity_dev, u, bi, d_idx, nfills, rows = meta
             is_proc = isinstance(parity_dev, tuple) and \
                 parity_dev[0] == "proc"
             parity = None
@@ -1231,12 +1425,14 @@ class StreamingEncoder:
                 self._merge_worker_span(tr, self._proc_worker,
                                         root.span_id, d_idx)
             if parity is None:
-                # the input buffer is still intact: slots recycle only
-                # after their dispatch is fetched (or recomputed here),
-                # so the CPU codec can recompute losslessly
+                # the input is still intact: slots recycle only after
+                # their dispatch is fetched (or recomputed here), and the
+                # map outlives every dispatch, so the CPU codec can
+                # recompute losslessly
                 with tr.span("pipeline.fallback", dispatch=d_idx,
                              reason=reason):
-                    parity = self._cpu_parity(bufs[bi][:, :u])
+                    parity = self._cpu_parity(_shard_major(
+                        bufs[bi] if rows is None else rows, u))
                 self._note_fallback(st, reason)
             return parity[:, :u], fetch_s, nbytes
 
@@ -1244,7 +1440,7 @@ class StreamingEncoder:
             """Write ONE dispatch's parity + crc stream and advance the
             FIFO checkpoint; runs on the writer thread in async mode.
             Returns the crc time (sidecar_s)."""
-            parity_dev, u, bi, d_idx, nfills = meta
+            parity_dev, u, bi, d_idx, nfills, _ = meta
             sc = 0.0
             # entries pack side by side, so each parity row's bytes for
             # this dispatch are one contiguous slice
@@ -1349,28 +1545,16 @@ class StreamingEncoder:
                     if not used:
                         return
                     d_idx = st["dispatches"]
-                    buf = bufs[bi]
-                    with tr.span("pipeline.fill", dispatch=d_idx,
-                                 bytes=k * used):
-                        for col, n, row_start, block, off in fills:
-                            if off == 0 and n == block:
-                                # whole-block entry: the k per-shard reads
-                                # are CONTIGUOUS in the file
-                                # ([row_start, +k*block)) — one vectored
-                                # read straight into the k strided buffer
-                                # slices, no intermediate copy (small rows
-                                # always take this path; chunked 1GB rows
-                                # fall through)
-                                preadv_into(
-                                    dat,
-                                    [buf[i, col:col + n] for i in range(k)],
-                                    row_start)
-                            else:
-                                for i in range(k):
-                                    buf[i, col:col + n] = pread_padded(
-                                        dat, n, row_start + i * block + off)
-                        if used < b:
-                            buf[:, used:] = 0
+                    # a whole group of rows goes as a view of the mapped
+                    # .dat; anything else is filled into the slot
+                    rows = self._row_group(fills, used, dat_map)
+                    if rows is None:
+                        if bufs[bi] is None:
+                            bufs[bi] = np.empty((k, b), dtype=np.uint8)
+                        src = bufs[bi]
+                        self._fill(tr, dat, src, fills, used, d_idx)
+                    else:
+                        src = rows
                     # injected dispatch fault: THIS dispatch goes CPU,
                     # the pipeline stays on its engine
                     dispatch_fault = False
@@ -1380,11 +1564,13 @@ class StreamingEncoder:
                         except Exception:
                             dispatch_fault = True
                     with tr.span("pipeline.dispatch", dispatch=d_idx,
-                                 bytes=k * used):
+                                 bytes=k * used,
+                                 source="slot" if rows is None else "map"):
                         if flags["degraded"] or dispatch_fault:
                             reason = ("degraded" if flags["degraded"]
                                       else "dispatch_fault")
-                            parity_dev = self._cpu_parity(buf[:, :used])
+                            parity_dev = self._cpu_parity(
+                                _shard_major(src, used))
                             self._note_fallback(st, reason)
                             # on the trace too: a fallback decision that
                             # leaves no span would let a degraded run
@@ -1409,10 +1595,11 @@ class StreamingEncoder:
                                 self._note_fallback(st, reason)
                                 tr.event("pipeline.fallback",
                                          dispatch=d_idx, reason=reason)
-                                parity_dev = self._cpu_parity(buf[:, :used])
+                                parity_dev = self._cpu_parity(
+                                    _shard_major(src, used))
                         else:
                             try:
-                                parity_dev = self._dispatch(planes, buf)
+                                parity_dev = self._dispatch(planes, src)
                             except (KeyboardInterrupt, SystemExit):
                                 raise
                             except Exception as e:
@@ -1424,24 +1611,19 @@ class StreamingEncoder:
                                          dispatch=d_idx,
                                          reason="device_dispatch",
                                          error=f"{type(e).__name__}: {e}")
-                                parity_dev = self._cpu_parity(buf[:, :used])
-                    st["dispatches"] += 1
+                                parity_dev = self._cpu_parity(
+                                    _shard_major(src, used))
+                    self._count_dispatch(st, rows is not None)
                     st["bytes_in"] += k * used
-                    # data shards pass through from the host buffer while
-                    # the device computes parity; packed entries make each
-                    # shard's bytes one contiguous slice
+                    # data shards pass through from the host input while
+                    # the device computes parity; crc time rides the
+                    # write stage (see the parity-side note), sidecar_s
+                    # sub-counts it
                     with tr.span("pipeline.write", dispatch=d_idx,
                                  kind="data"):
-                        for i in range(k):
-                            outputs[i].write(memoryview(buf[i, :used]))
-                        if sb is not None:
-                            # crc time rides the write stage (see the
-                            # parity-side note), sidecar_s sub-counts it
-                            t1 = clock()
-                            for i in range(k):
-                                sb.update(i, buf[i, :used])
-                            st["sidecar_s"] += clock() - t1
-                    meta = (parity_dev, used, bi, d_idx, len(fills))
+                        st["sidecar_s"] += self._write_data(
+                            outputs, sb, src, used)
+                    meta = (parity_dev, used, bi, d_idx, len(fills), rows)
                     fills, used = [], 0
                     if drainer is not None:
                         # async: hand the dispatch to the drainer and
@@ -1515,6 +1697,10 @@ class StreamingEncoder:
                 # encode's) seq stream — abandon the worker; the retry
                 # respawns fresh (mmap path does the same)
                 self._abandon_proc_worker()
+            pending.clear()
+            self._close_map(dat_map)
+            if ok and slots is not None:
+                self._keep_slots(slots)
             with tr.span("pipeline.close"):
                 for f in outputs:
                     f.close()
@@ -1588,19 +1774,7 @@ class StreamingEncoder:
                     f = open(p, "wb")
                 outputs.append(f)
             out_fds = [f.fileno() for f in outputs]
-            # the slots outnumber what a few dispatches reuse (12 on
-            # four TPUs; a 1 GiB volume is 13 dispatches), so fresh
-            # buffers would page-fault on nearly every fill: reuse the
-            # last clean encode's.  Taken under the lock, so concurrent
-            # encodes never share them
-            with self._st_lock:
-                dev_bufs, self._queue_bufs = self._queue_bufs, None
-            if dev_bufs is None or len(dev_bufs) != nd or \
-                    len(dev_bufs[0]) != slots_per_dev or \
-                    dev_bufs[0][0].shape != (k, b):
-                dev_bufs = [[np.zeros((k, b), dtype=np.uint8)
-                             for _ in range(slots_per_dev)]
-                            for _ in range(nd)]
+            dev_bufs = self._take_slots(nd, slots_per_dev)
         except BaseException:
             for f in outputs:
                 f.close()
@@ -1763,23 +1937,12 @@ class StreamingEncoder:
                     d_idx = st["dispatches"]
                     dev_i = d_idx % nd  # round-robin across the slice
                     slot = acquire_slot(dev_i)
+                    if dev_bufs[dev_i][slot] is None:
+                        dev_bufs[dev_i][slot] = np.empty((k, b),
+                                                         dtype=np.uint8)
                     buf = dev_bufs[dev_i][slot]
-                    with tr.span("pipeline.fill", dispatch=d_idx,
-                                 device=dev_i, bytes=k * used):
-                        for col, n, row_start, block, off in fills:
-                            if off == 0 and n == block:
-                                preadv_into(
-                                    dat,
-                                    [buf[i, col:col + n]
-                                     for i in range(k)],
-                                    row_start)
-                            else:
-                                for i in range(k):
-                                    buf[i, col:col + n] = pread_padded(
-                                        dat, n,
-                                        row_start + i * block + off)
-                        if used < b:
-                            buf[:, used:] = 0
+                    self._fill(tr, dat, buf, fills, used, d_idx,
+                               device=dev_i)
                     dispatch_fault = False
                     if faultinject._points:
                         try:
@@ -1787,7 +1950,8 @@ class StreamingEncoder:
                         except Exception:
                             dispatch_fault = True
                     with tr.span("pipeline.dispatch", dispatch=d_idx,
-                                 device=dev_i, bytes=k * used):
+                                 device=dev_i, bytes=k * used,
+                                 source="slot"):
                         if flags["degraded"] or dispatch_fault:
                             # the CPU parity rides the same lane as a
                             # plain ndarray handle: ordering, slot
@@ -1812,20 +1976,15 @@ class StreamingEncoder:
                                          reason="device_dispatch",
                                          error=f"{type(e).__name__}: {e}")
                                 handle = self._cpu_parity(buf[:, :used])
-                    st["dispatches"] += 1
+                    self._count_dispatch(st, False)
                     st["bytes_in"] += k * used
                     dev_dispatches[dev_i] += 1
                     with tr.span("pipeline.write", dispatch=d_idx,
                                  kind="data"):
-                        for i in range(k):
-                            outputs[i].write(memoryview(buf[i, :used]))
-                        if sb is not None:
-                            t1 = clock()
-                            for i in range(k):
-                                sb.update(i, buf[i, :used])
-                            st["sidecar_s"] += clock() - t1
-                    batches[dev_i].append(
-                        (handle, used, slot, d_idx, len(fills), out_off))
+                        st["sidecar_s"] += self._write_data(
+                            outputs, sb, buf, used)
+                    batches[dev_i].append((handle, used, slot, d_idx,
+                                           len(fills), out_off))
                     out_off += used
                     fills, used = [], 0
                     if len(batches[dev_i]) >= coalesce:
@@ -1868,10 +2027,7 @@ class StreamingEncoder:
             if setup is not None:  # failed before the loop started
                 setup.__exit__(*exc)
             if ok:
-                # every dispatch was fetched, so no transfer still reads
-                # the slots; after a fault one may, so they are dropped
-                with self._st_lock:
-                    self._queue_bufs = dev_bufs
+                self._keep_slots(dev_bufs)
             else:
                 drainers.abort()
             st["drain_s"] += ds["drain_s"]

@@ -23,7 +23,10 @@ engine (differential-tested):
 
 Each comes in two layouts.  Bytes: data [K, B] u8 -> [R, B] u8, for
 reconstructs of any width (TpuEngine).  Words (`*_packed`): data
-[K, B//4] u32 -> [R, B//4] u32, the streaming pipeline's dispatches.
+[K, B//4] u32 -> [R, B//4] u32, the streaming pipeline's dispatches; or
+[G, K, W] u32 -> [R, G*W] u32, G whole rows of a `.dat` as the file lays
+them out (row g's K blocks of W words back to back), whose parity comes
+back in the 2-D layout of the same columns packed row after row.
 Byte q of word j is column 4j + q, as a little-endian host's
 ``.view(np.uint32)`` / ``.view(np.uint8)`` sees it, and the matmul is
 independent per column, so the word path runs the bit-plane matmul once
@@ -119,7 +122,9 @@ def gf_matmul_xla(a_planes: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
 def gf_matmul_xla_packed(a_planes: jnp.ndarray,
                          words: jnp.ndarray) -> jnp.ndarray:
     """gf_matmul_xla in the word layout: words [K, B//4] u32 -> [R, B//4]
-    u32."""
+    u32, or [G, K, W] u32 rows -> [R, G*W] u32."""
+    if words.ndim == 3:
+        words = words.transpose(1, 0, 2).reshape(words.shape[1], -1)
     return _gf_words(a_planes, words)
 
 
@@ -139,10 +144,14 @@ def gf_matmul_pallas(a_planes: jnp.ndarray, data: jnp.ndarray,
     """Fused Pallas kernel: grid over B tiles; A resident in VMEM; unpack,
     MXU matmul, mod-2, repack — no 8x bit expansion ever hits HBM.
     `tile_b` counts columns (bytes) in both layouts; with `words` data is
-    [K, B//4] u32 and so is the output, tile_b//4 words a block."""
+    [K, B//4] u32 and so is the output, tile_b//4 words a block, or
+    [G, K, W] u32 rows (see _gf_rows_call)."""
     r8, k8 = a_planes.shape
-    k, n = data.shape
     tile = tile_b // 4 if words else tile_b
+    if data.ndim == 3:
+        assert words
+        return _gf_rows_call(a_planes, data, tile, interpret)
+    k, n = data.shape
     assert k8 == 8 * k and n % tile == 0
     dtype = jnp.uint32 if words else jnp.uint8
     return pl.pallas_call(
@@ -159,12 +168,40 @@ def gf_matmul_pallas(a_planes: jnp.ndarray, data: jnp.ndarray,
     )(a_planes, data)
 
 
+def _gf_rows_call(a_planes: jnp.ndarray, rows: jnp.ndarray, tile: int,
+                  interpret: bool) -> jnp.ndarray:
+    """[G, K, W] u32 rows -> [R, G*W] u32: the word kernel's body over a
+    (G, W//tile) grid, block (K, tile) of row g in, block g*(W//tile) + i
+    of the 2-D output out, so row g's parity lands in columns
+    [g*W, (g+1)*W) as if its words had been packed shard-major."""
+    r8, k8 = a_planes.shape
+    g, k, w = rows.shape
+    assert k8 == 8 * k and w % tile == 0
+    nt = w // tile
+    return pl.pallas_call(
+        _gf_word_kernel,
+        out_shape=jax.ShapeDtypeStruct((r8 // 8, g * w), jnp.uint32),
+        grid=(g, nt),
+        in_specs=[
+            pl.BlockSpec((r8, k8), lambda gi, i: (0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((None, k, tile), lambda gi, i: (gi, 0, i),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((r8 // 8, tile),
+                               lambda gi, i: (0, gi * nt + i),
+                               memory_space=pltpu.VMEM),
+        interpret=interpret,
+    )(a_planes, rows)
+
+
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def gf_matmul_pallas_packed(a_planes: jnp.ndarray, words: jnp.ndarray,
                             tile_b: int = DEFAULT_TILE_B,
                             interpret: bool = False) -> jnp.ndarray:
     """gf_matmul_pallas in the word layout: words [K, B//4] u32 ->
-    [R, B//4] u32, the whole dispatch one kernel."""
+    [R, B//4] u32, or [G, K, W] u32 rows -> [R, G*W] u32, the whole
+    dispatch one kernel."""
     return gf_matmul_pallas(a_planes, words, tile_b=tile_b,
                             interpret=interpret, words=True)
 

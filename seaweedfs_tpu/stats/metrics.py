@@ -448,6 +448,12 @@ class ECPipelineMetrics:
             "SeaweedFS_ec_engine_fallbacks_total",
             "EC dispatches that fell back to the CPU codec.",
             labels=("reason",))
+        self.dispatches = registry.counter(
+            "SeaweedFS_ec_dispatches_total",
+            "Staged and per-device-queue EC encode dispatches, by where "
+            "their input came from: map (rows viewed in the mapped .dat) "
+            "or slot (filled into a staging buffer).",
+            labels=("source",))
         self.degraded_binds = registry.counter(
             "SeaweedFS_server_degraded_binds_total",
             "Servers that came up without their framed-TCP plane "
@@ -464,6 +470,8 @@ class ECPipelineMetrics:
                 int(sum(self.engine_fallbacks.snapshot().values())),
             "degraded_binds":
                 int(sum(self.degraded_binds.snapshot().values())),
+            "dispatches": int(sum(self.dispatches.snapshot().values())),
+            "mapped_dispatches": int(self.dispatches.value("map")),
         }
 
 

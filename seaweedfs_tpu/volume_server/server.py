@@ -1767,7 +1767,8 @@ class VolumeServer:
             st = self.store.last_ec_stats
             return {"device": device_doc(),
                     "encoder": {k: st[k] for k in (
-                        "dispatches", "bytes_in", "wall_s", "retries",
+                        "dispatches", "mapped_dispatches", "bytes_in",
+                        "wall_s", "retries",
                         "fallbacks", "worker_restarts", "devices",
                         "per_device") if k in st}}
 

@@ -77,6 +77,29 @@ def test_pallas_packed_at_streaming_dispatch_width(one_chip, rows):
     assert "gather" not in text
 
 
+def test_pallas_packed_takes_mapped_rows(one_chip):
+    """A dispatch of eight 1 MiB rows viewed in the mapped `.dat`,
+    [8, K, 262144] u32, put on the chip as the encoder puts it: the
+    module is named as the benchmark finds it, its ROOT the one Pallas
+    kernel, with no gather; the chip's default layout for the rows is
+    K-major, so the relayout to the row-major layout the kernel reads
+    is a copy inside the same module (the dispatch's time, not the
+    transfer's); its parity is [R, 8 MiB/4] u32 as a slot dispatch's."""
+    from seaweedfs_tpu.ops.gf_matmul import gf_matmul_pallas_packed
+
+    rows = _words((8, K, (1 << 20) // 4), one_chip)
+    compiled = gf_matmul_pallas_packed.lower(
+        _spec((8 * R, 8 * K), one_chip), rows, tile_b=TILE).compile()
+    text = compiled.as_text()
+    assert re.search(r"^HloModule jit_gf_matmul_pallas_packed\b", text,
+                     re.M)
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*ROOT %gf_matmul_pallas(\.\d+)? = "
+                    r"u32\[4,2097152\]", calls[0]), calls[0]
+    assert "gather" not in text
+
+
 @pytest.mark.parametrize("rows", [4, 1])
 def test_pallas_reconstruct_rows(one_chip, rows):
     """TpuEngine's kernel for a 4-row (parity / 4-shard rebuild) and a

@@ -407,3 +407,176 @@ def test_file_parity_worker_death_falls_back_serial(tmp_path):
         assert _shards(base, 14) == _shards(ref, 14)
     finally:
         enc._drop_file_worker()
+
+
+# --- whole groups of small-block rows, dispatched from the mapped .dat ---
+# 1 KiB blocks and 4 KiB dispatches: a group is 4 rows, 40 KiB of .dat
+MAP_LARGE, MAP_SMALL, MAP_B = 1 << 20, 1024, 4096
+GROUP = 10 * MAP_B
+ROW = 10 * MAP_SMALL
+
+
+def _device_enc(kernel="xla", **kw):
+    """The staged plane (one device) at the mapped geometry; "pallas"
+    runs the chip's word kernel in interpret mode, one tile a block."""
+    import functools
+
+    from seaweedfs_tpu.ops.gf_matmul import (INTERPRET_TILE_B,
+                                             gf_matmul_pallas_packed)
+
+    enc = StreamingEncoder(10, 4, engine="device", devices="1", **kw)
+    enc.dispatch_b = MAP_B
+    if kernel == "pallas":
+        enc._kernel = functools.partial(gf_matmul_pallas_packed,
+                                        tile_b=INTERPRET_TILE_B,
+                                        interpret=True)
+        enc._row_q = INTERPRET_TILE_B
+    return enc
+
+
+def _files(base, total=14):
+    return _shards(base, total) + [open(base + ".eci", "rb").read()]
+
+
+def _dat_mapped(path) -> bool:
+    with open("/proc/self/maps") as f:
+        return path in f.read()
+
+
+@pytest.mark.parametrize("kernel,async_drain", [
+    ("xla", None), ("pallas", None), ("xla", False)],
+    ids=["xla", "pallas", "xla-serial-drain"])
+@pytest.mark.parametrize("size,want", [
+    (3 * GROUP, (3, 3)),                  # an exact multiple of a group
+    (3 * GROUP + 3 * ROW + 77, (4, 3)),   # last group ends past EOF
+    (GROUP - 1, (1, 0)),                  # smaller than one group
+], ids=["groups", "partial-row", "under-a-group"])
+def test_mapped_rows_byte_identical(tmp_path, size, want, kernel,
+                                    async_drain):
+    """Whole groups go to the kernel as [rows, K, W] views of the mapped
+    .dat, the rest through a slot: shards and the .eci sidecar equal the
+    CPU encoder's, and each dispatch says where its input came from,
+    with the async drain and the serial one."""
+    base = _write_dat(tmp_path, size)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = _device_enc(kernel, tracer=tracer, async_drain=async_drain)
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    assert _files(base) == _files(ref)
+    assert (enc.stats["dispatches"], enc.stats["mapped_dispatches"]) == want
+    spans = tracer.snapshot()
+    sources = [s.attrs["source"] for s in spans
+               if s.name == "pipeline.dispatch"]
+    assert sources.count("map") == want[1] and len(sources) == want[0]
+    assert enc.stats["fallbacks"] == 0
+    assert not _dat_mapped(base + ".dat")
+
+
+def test_mapped_rows_resume_mid_group(tmp_path):
+    """A resume from a checkpoint inside a group: the dispatches after it
+    pack whole rows again and go mapped, and the shards and sidecar
+    repaired past the checkpoint equal a clean encode's."""
+    base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    enc = _device_enc()
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    ck_entry, ck_byte = 6, 6 * MAP_SMALL  # group 1 holds entries 4..7
+    for i in range(14):
+        with open(base + to_ext(i), "r+b") as f:
+            f.seek(ck_byte)
+            f.write(b"\xAA" * len(f.read()))
+    enc._encode_file_staged(base + ".dat", base, MAP_LARGE, MAP_SMALL,
+                            start_entry=ck_entry, start_byte=ck_byte)
+    assert enc.stats["mapped_dispatches"] == 2  # entries 6..9, 10..13
+    assert _files(base) == _files(ref)
+
+
+def test_large_block_rows_take_slots(tmp_path):
+    """Large-block rows chunked across dispatches (8 KiB blocks, 4 KiB
+    dispatches) are filled into slots; the small rows after them go
+    mapped; bytes as the CPU encoder's."""
+    large = 2 * MAP_B
+    base = _write_dat(tmp_path, 3 * 10 * large)  # 2 large rows, 8 small
+    ref = _cpu_reference(tmp_path, base, large, MAP_SMALL)
+    enc = _device_enc()
+    enc.encode_file(base + ".dat", base, large, MAP_SMALL)
+    assert (enc.stats["dispatches"], enc.stats["mapped_dispatches"]) == (6, 2)
+    assert _files(base) == _files(ref)
+
+
+@pytest.mark.parametrize("point", ["ec.dispatch", "ec.drain"])
+def test_mapped_dispatch_fault_recomputes_from_view(tmp_path, point):
+    """A dispatch or drain fault on a mapped dispatch falls back to the
+    CPU codec, which reads the dispatch's rows from the map: the bytes
+    stay the CPU encoder's and the fallback is counted."""
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+    from seaweedfs_tpu.utils import faultinject as fi
+
+    base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = _device_enc(tracer=tracer)
+    f0 = ec_pipeline_metrics().totals()["engine_fallbacks"]
+    fi.enable(point, error_rate=1.0, max_hits=1)  # the first: mapped
+    try:
+        enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    finally:
+        fi.clear()
+    assert _files(base) == _files(ref)
+    assert enc.stats["fallbacks"] == 1
+    assert ec_pipeline_metrics().totals()["engine_fallbacks"] - f0 == 1
+    reason = point.split(".")[1] + "_fault"
+    assert any(s.attrs.get("reason") == reason and s.attrs["dispatch"] == 0
+               for s in tracer.snapshot() if s.name == "pipeline.fallback")
+    assert enc.stats["mapped_dispatches"] == 3
+
+
+@pytest.mark.parametrize("lag_s", [0.0, 0.05], ids=["even", "data-lags"])
+def test_map_closed_after_clean_and_failed_attempts(tmp_path, monkeypatch,
+                                                    lag_s):
+    """The .dat map is closed after a clean encode, and after an attempt
+    that failed with a mapped dispatch in flight (the retry resumes from
+    the checkpoint): no BufferError, no map of the .dat left, whether the
+    data writes keep pace with the drain or lag it."""
+    import time
+
+    base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    enc = _device_enc(depth=1)
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    assert not _dat_mapped(base + ".dat")
+
+    real, calls = StreamingEncoder._write_data, []
+
+    def failing_third(self, outputs, sb, src, used):
+        calls.append(1)
+        time.sleep(lag_s)
+        if len(calls) == 3:  # dispatch 2's data
+            raise OSError("injected data write failure")
+        return real(self, outputs, sb, src, used)
+
+    monkeypatch.setattr(StreamingEncoder, "_write_data", failing_third)
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    assert enc.stats["retries"] == 1
+    assert not _dat_mapped(base + ".dat")
+    assert _files(base) == _files(ref)
+
+
+def test_volume_geometry_maps_twelve_of_thirteen(tmp_path):
+    """The benchmark volume's row arithmetic (1,073,890,064 bytes, 1 MiB
+    rows, 8 MiB dispatches) at a 1,024th of its size: 103 rows, 13
+    dispatches, the last partial, 12 mapped; the process counters that
+    /status reports as EcPipeline count them."""
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+
+    size = 1_073_890_064 // 1024
+    base = _write_dat(tmp_path, size)
+    enc = _device_enc()
+    enc.dispatch_b = 8 * MAP_SMALL
+    t0 = ec_pipeline_metrics().totals()
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    t1 = ec_pipeline_metrics().totals()
+    assert (enc.stats["dispatches"], enc.stats["mapped_dispatches"]) == (13, 12)
+    assert (t1["dispatches"] - t0["dispatches"],
+            t1["mapped_dispatches"] - t0["mapped_dispatches"]) == (13, 12)
+

@@ -77,3 +77,57 @@ def test_mesh_queue_encode_takes_words():
                                          data.view(np.uint32))
     assert np.array_equal(unpack_u32_host(np.asarray(out), 4096),
                           CpuEngine().matmul(m, data))
+
+
+@pytest.mark.parametrize("tiles", [1, 2])
+@pytest.mark.parametrize("groups", [1, 3])
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_row_groups_equal_per_row_results(impl, groups, tiles):
+    """[G, K, W] u32 rows, as a mapped `.dat` lays them out, give the
+    per-row 2-D results side by side: [R, G*W], row g in columns
+    [g*W, (g+1)*W)."""
+    m = np.ascontiguousarray(MATRIX[K:])
+    planes = expand_matrix_bitplanes(m)
+    width = tiles * INTERPRET_TILE_B
+    rows = np.stack([_data("ramp" if g % 2 else "random", width,
+                           seed=50 + g) for g in range(groups)])
+    out = np.asarray(_word_matmul(impl, planes, rows.view(np.uint32)))
+    assert out.dtype == np.uint32 and out.shape == (4, groups * width // 4)
+    per_row = [np.asarray(_word_matmul(impl, planes, rows[g].view(np.uint32)))
+               for g in range(groups)]
+    assert np.array_equal(out, np.concatenate(per_row, axis=1))
+    got = unpack_u32_host(out, groups * width)
+    want = CpuEngine().matmul(m, np.concatenate(list(rows), axis=1))
+    assert np.array_equal(got, want)
+
+
+# sha256 of the 2-D word programs' jaxprs before the row axis came in:
+# rebuilds, the slot dispatches and the queue plane run exactly these
+_PROGRAMS_2D = {
+    "pallas": "d712aa620b7b7567857a469ecf7dd4a13318f7252452c17fa57cd7dbdafed267",
+    "pallas-interpret":
+        "378c3d6d948031e68e9d6ae59edbc2c41911114a72ecebe7f17dd62907538fb7",
+    "xla": "54192b0537368cc6c61e474b72bd67f85e3fd8b95f4d16e3d5e2f6b0cf0ad3d2",
+}
+
+
+@pytest.mark.parametrize("impl", sorted(_PROGRAMS_2D))
+def test_2d_word_program_unchanged(impl):
+    import functools
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    if impl == "xla":
+        fn, width = gf_matmul_xla_packed, 4096
+    else:
+        tile, interpret = ((65536, False) if impl == "pallas"
+                           else (INTERPRET_TILE_B, True))
+        fn = functools.partial(gf_matmul_pallas_packed, tile_b=tile,
+                               interpret=interpret)
+        width = (8 << 20) // 4
+    jaxpr = str(jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct((32, 80), jnp.uint8),
+        jax.ShapeDtypeStruct((K, width), jnp.uint32)))
+    assert hashlib.sha256(jaxpr.encode()).hexdigest() == _PROGRAMS_2D[impl]

@@ -95,7 +95,7 @@ def test_queue_slots_kept_only_after_clean_encodes(tmp_path, monkeypatch):
     dat = np.random.default_rng(77).integers(0, 256, DAT_SIZE,
                                              dtype=np.uint8)
     enc, _, base = _encode(tmp_path, "v", "4", dat.tobytes())
-    kept = enc._queue_bufs
+    kept = enc._slot_bufs
     assert len(kept) == 4  # one slot pool a device
 
     size = rs.shard_size(DAT_SIZE, LARGE, SMALL)
@@ -108,7 +108,7 @@ def test_queue_slots_kept_only_after_clean_encodes(tmp_path, monkeypatch):
         assert _shards(base) == want
 
     encode_again()
-    assert enc._queue_bufs is kept
+    assert enc._slot_bufs is kept
 
     pwrite, failed = os.pwrite, []
 
@@ -121,4 +121,53 @@ def test_queue_slots_kept_only_after_clean_encodes(tmp_path, monkeypatch):
     monkeypatch.setattr(os, "pwrite", pwrite_once_failing)
     encode_again()
     assert failed and enc.stats["retries"] == 1
-    assert enc._queue_bufs is not None and enc._queue_bufs is not kept
+    assert enc._slot_bufs is not None and enc._slot_bufs is not kept
+
+
+@pytest.mark.parametrize("n_dev,fault", [(1, None), (4, None),
+                                         (4, "ec.dispatch"), (4, "ec.drain")])
+def test_mapped_rows_on_slices(tmp_path, n_dev, fault):
+    """One device (the staged plane) sends a dispatch of whole
+    small-block rows as one view of the mapped .dat (1 KiB rows, 4 KiB
+    dispatches: 4 rows a group); the queue plane, round-robin over four
+    devices, fills every dispatch into its slots; a fault recomputes the
+    dispatch on the CPU from its slot.  Shards equal the reference, the
+    sidecar the one-device encode's."""
+    from seaweedfs_tpu.utils import faultinject as fi
+
+    small, size = 1024, 7 * 10 * 4096 + 3 * 10 * 1024 + 77
+    dat = np.random.default_rng(300 + n_dev).integers(0, 256, size,
+                                                      dtype=np.uint8)
+
+    def encode(name, devices):
+        tracer = Tracer(capacity=1 << 12)
+        enc = StreamingEncoder(10, 4, engine="device", devices=devices,
+                               tracer=tracer)
+        enc.dispatch_b = DISPATCH_B
+        base = str(tmp_path / name)
+        with open(base + ".dat", "wb") as f:
+            f.write(dat.tobytes())
+        enc.encode_file(base + ".dat", base, large_block_size=1 << 20,
+                        small_block_size=small)
+        return enc, tracer, base
+
+    if fault:
+        fi.enable(fault, error_rate=1.0, max_hits=1)
+    try:
+        enc, tracer, base = encode("v", str(n_dev))
+    finally:
+        fi.clear()
+    mapped = 7 if n_dev == 1 else 0
+    assert (enc.stats["dispatches"], enc.stats["mapped_dispatches"]) == \
+        (8, mapped)
+    assert enc.stats["fallbacks"] == (1 if fault else 0)
+    sources = [s.attrs.get("source") for s in tracer.snapshot()
+               if s.name == "pipeline.dispatch"]
+    assert sources.count("map") == mapped and \
+        sources.count("slot") == 8 - mapped
+    want = rs.shard_columns(dat, 0, rs.shard_size(size, 1 << 20, small),
+                            1 << 20, small)
+    assert _shards(base) == [w.tobytes() for w in want]
+    _, _, one = encode("one", "1")
+    with open(base + ".eci", "rb") as f1, open(one + ".eci", "rb") as f2:
+        assert f1.read() == f2.read()
