@@ -1,638 +1,19 @@
-"""Process-based overlap workers for the host EC pipelines.
+"""Asynchronous drains for the streaming EC encode (ec/streaming.py).
 
-The staged pipeline (streaming.py) overlaps host fill/write with codec
-compute.  In-process, that overlap rides a worker THREAD: fine when the
-ctypes codec releases the GIL and a second core exists, but on a 1-core
-host threads just convoy.  This module provides the same overlap through
-a separate PROCESS over shared memory, so the mechanism itself —
-producer fills dispatch d+1 while consumer computes dispatch d — is
-exercised and measurable on any core count (VERDICT r3 asked for the
-claim to be measured, not asserted; bench.py reports worker-on vs
-worker-off throughput from this worker).
-
-Two workers share one lifecycle base:
-
-- ProcessOverlapWorker: dispatch buffers AND parity live in shared
-  memory; the parent copies input rows in (the staged pipeline's model).
-- FileParityWorker: the worker mmaps the SAME input file the parent
-  mmap'd, so only parity crosses shared memory — the zero-copy mmap
-  encode's overlap half.
-
-Protocol: single worker process, FIFO job queue.  Every job carries a
-monotonically-increasing SEQUENCE NUMBER and its ack echoes it back, so
-the parent can tell a replayed result from a stale one.  FIFO submission
-order == completion order, which matches the pipelines' drain order.
-Worker-side job failures ack ("err", seq, detail) instead of dying
-silently, so the parent can fall back to serial compute for that one
-dispatch and keep the worker.
-
-SUPERVISION (the self-healing contract): the parent detects worker death
-or stall through its bounded ack reads and, instead of failing the
-encode, respawns the process with jittered exponential backoff (bounded
-by max_restarts) and REPLAYS the in-flight dispatches.  Replay is safe
-because every job's inputs are still live on the parent side when its
-ack is outstanding: the staged worker's input buffers are shared-memory
-slots the parent does not recycle until fetch, and the file worker
-re-reads the input file itself.  Results that the dead incarnation
-already acked are drained into a dedup buffer first, so a replay never
-produces a double-write.  When the restart budget is exhausted, fetch
-raises WorkerGaveUp and the pipeline degrades to the CPU codec
-mid-stream (streaming.py) — the encode still completes byte-identical.
-
-Fault points (utils/faultinject): `ec.worker.ack` injects a parent-side
-ack failure — the supervisor treats it exactly like worker death (kills
-the real process, respawns, replays), so chaos tests exercise the whole
-recovery path deterministically; `ec.shm` fires in spawn, so arming it
-makes respawns fail and drains the retry budget on demand.
-
-ASYNC DRAIN (PR 7): the pipelines no longer block their critical thread
-in fetch.  AsyncDrainer runs the per-dispatch fetch on a small thread
-pool and hands completed parity to ONE writer thread through a bounded
-FIFO queue, so D2H transfers (and worker acks) overlap the producer's
-fill/dispatch/write work.  The worker protocol grew the per-slot drain
-state that makes this safe: submit() and fetch() may now run on
-DIFFERENT threads (producer submits dispatch d+1 while the drainer is
-blocked fetching dispatch d), serialized around the supervision state
-by an internal lock, and abandon() marks the worker so a drainer
-blocked mid-fetch fails fast with WorkerGaveUp instead of burning the
-restart budget respawning a worker the caller already tore down.
+The encode's producer thread fills and dispatches; it never blocks in a
+device fetch.  AsyncDrainer runs each dispatch's fetch on a small thread
+pool and hands the fetched parity to ONE writer thread through a bounded
+FIFO queue, so D2H transfers overlap the producer's fill, dispatch and
+data writes while the shard append order and the `.eci` crc stream stay
+those of a serial drain.  DrainerGroup is one such drain lane per device
+of a slice: the per-device dispatch-queue plane.
 """
 
 from __future__ import annotations
 
 import concurrent.futures
-import multiprocessing as mp
-import os
 import queue as queue_mod
 import threading
-import time
-from collections import OrderedDict
-from multiprocessing import shared_memory
-
-import numpy as np
-
-from ..observability import get_tracer
-from ..utils import faultinject
-from ..utils.backoff import jittered_backoff
-
-
-def _close_shm_quiet(shm) -> None:
-    """close() tolerating still-exported buffer views (the abandoned-
-    worker fallback keeps using input slots after the process dies):
-    release the fd now and defuse the SharedMemory destructor's retry —
-    the mapping itself is freed when the last numpy view drops (mmap
-    dealloc closes the map), and the caller already unlink()ed the
-    name, so nothing leaks."""
-    try:
-        shm.close()
-    except BufferError:
-        try:
-            if shm._fd >= 0:
-                os.close(shm._fd)
-                shm._fd = -1
-        except OSError:  # pragma: no cover - already closed
-            pass
-        shm._mmap = None
-        shm._buf = None
-
-
-class WorkerJobError(RuntimeError):
-    """One job failed inside a live worker (e.g. its input file vanished):
-    the dispatch needs a CPU recompute, the worker itself is fine."""
-
-
-class WorkerGaveUp(RuntimeError):
-    """The supervisor exhausted its restart budget: the worker path is
-    done for this encode and the caller must degrade to the CPU codec."""
-
-
-def _worker_main(in_name: str, out_name: str, k: int, r: int, b: int,
-                 nbufs: int, mat_bytes: bytes, jobs, acks) -> None:
-    from .. import native
-
-    if native.load() is None:  # pragma: no cover - parent checked first
-        acks.put(("err", -1, "native gf256 unavailable"))
-        return
-    import time as _time
-
-    mat = np.frombuffer(mat_bytes, dtype=np.uint8).reshape(r, k)
-    shm_in = shared_memory.SharedMemory(name=in_name)
-    shm_out = shared_memory.SharedMemory(name=out_name)
-    try:
-        ins = np.frombuffer(shm_in.buf, dtype=np.uint8).reshape(nbufs, k, b)
-        outs = np.frombuffer(shm_out.buf, dtype=np.uint8).reshape(nbufs, r, b)
-        in0 = ins.ctypes.data
-        out0 = outs.ctypes.data
-        acks.put(("ready", os.getpid()))
-        while True:
-            msg = jobs.get()
-            if msg is None:
-                break
-            _, seq, (bi, n) = msg
-            try:
-                # wall-clock compute window rides the ack: the parent's
-                # tracer merges it as a worker.compute span on drain
-                t0 = _time.time()
-                native.gf_matmul_ptrs(
-                    mat,
-                    [in0 + (bi * k + i) * b for i in range(k)],
-                    [out0 + (bi * r + j) * b for j in range(r)], n)
-                acks.put(("done", seq, bi, t0, _time.time()))
-            except Exception as e:  # pragma: no cover - native errors
-                acks.put(("err", seq, f"{type(e).__name__}: {e}"))
-        del ins, outs
-    finally:
-        shm_in.close()
-        shm_out.close()
-
-
-def _file_worker_main(out_name: str, r: int, b: int, nbufs: int,
-                      mat_bytes: bytes, k: int, jobs, acks) -> None:
-    import mmap as mmap_mod
-    import time as _time
-
-    from .. import native
-
-    if native.load() is None:  # pragma: no cover - parent checked first
-        acks.put(("err", -1, "native gf256 unavailable"))
-        return
-    mat = np.frombuffer(mat_bytes, dtype=np.uint8).reshape(r, k)
-    shm_out = shared_memory.SharedMemory(name=out_name)
-    in_map = None
-    in_addr = 0
-    try:
-        outs = np.frombuffer(shm_out.buf, dtype=np.uint8).reshape(nbufs, r, b)
-        out0 = outs.ctypes.data
-        acks.put(("ready", os.getpid()))
-        while True:
-            msg = jobs.get()
-            if msg is None:
-                break
-            try:
-                if msg[0] == "open":
-                    if in_map is not None:
-                        in_map.close()
-                        in_map = None
-                    f = open(msg[1], "rb")
-                    try:
-                        in_map = mmap_mod.mmap(f.fileno(), 0,
-                                               access=mmap_mod.ACCESS_READ)
-                    finally:
-                        f.close()
-                    in_addr = np.frombuffer(in_map,
-                                            dtype=np.uint8).ctypes.data
-                    acks.put(("opened", msg[1]))
-                    continue
-                _, seq, (slot, base, block, n) = msg
-                t0 = _time.time()
-                native.gf_matmul_ptrs(
-                    mat,
-                    [in_addr + base + i * block for i in range(k)],
-                    [out0 + (slot * r + j) * b for j in range(r)], n)
-                acks.put(("done", seq, slot, t0, _time.time()))
-            except Exception as e:
-                # the file vanished under us (compaction/rename) or the
-                # job failed: report, don't die — the parent recomputes
-                # that one dispatch and keeps us
-                if msg[0] == "open":
-                    acks.put(("err", -1, f"{type(e).__name__}: {e}"))
-                else:
-                    acks.put(("err", msg[1], f"{type(e).__name__}: {e}"))
-        del outs  # exported view must drop before the shm closes
-    finally:
-        if in_map is not None:
-            in_map.close()
-        try:
-            shm_out.close()
-        except BufferError:  # pragma: no cover - abnormal exit w/ views
-            pass
-
-
-class _ParityWorkerBase:
-    """Shared lifecycle: parity shm slots, spawn-context process,
-    ready handshake, bounded acks, supervised respawn + replay,
-    close/terminate."""
-
-    kind = "base"  # metrics label; subclasses override
-
-    def __init__(self, k: int, r: int, dispatch_b: int,
-                 matrix: np.ndarray, nbufs: int, target,
-                 ack_timeout: float = 30.0, max_restarts: int = 3,
-                 restart_backoff: float = 0.05,
-                 restart_backoff_cap: float = 2.0):
-        self.k, self.r, self.b = k, r, dispatch_b
-        self.nbufs = nbufs
-        self.ack_timeout = ack_timeout
-        self.max_restarts = max_restarts
-        self.restart_backoff = restart_backoff
-        self.restart_backoff_cap = restart_backoff_cap
-        self.restarts = 0  # guarded-by: _sup_lock
-        self._target = target
-        self._mat = np.ascontiguousarray(matrix, dtype=np.uint8)
-        self._shm_out = shared_memory.SharedMemory(
-            create=True, size=nbufs * r * dispatch_b)
-        self._outs = [
-            np.frombuffer(self._shm_out.buf, dtype=np.uint8,
-                          count=r * dispatch_b,
-                          offset=i * r * dispatch_b).reshape(r, dispatch_b)
-            for i in range(nbufs)
-        ]
-        # ticket/ack sequencing: _seq_submit numbers jobs, _seq_fetch is
-        # the next seq fetch() expects, _inflight maps seq -> replayable
-        # payload, _done buffers acks that arrived ahead of their fetch
-        # (drained from a dead incarnation, or read while waiting on an
-        # "opened" handshake)
-        self._seq_submit = 0  # guarded-by: _sup_lock
-        self._seq_fetch = 0  # guarded-by: _sup_lock
-        self._inflight: OrderedDict[int, tuple] = OrderedDict()  # guarded-by: _sup_lock
-        self._done: dict[int, tuple] = {}  # guarded-by: _sup_lock
-        # file worker: current open file
-        self._path: str | None = None  # guarded-by: _sup_lock
-        self._proc = None
-        self._jobs = None
-        self._acks = None
-        # per-slot drain state is now touched from TWO threads — the
-        # producer submits dispatch d+1 while the async drainer fetches
-        # dispatch d — so seq/inflight mutations and the whole
-        # kill+respawn+replay sequence serialize on this lock (never
-        # held across a blocking ack read: submit must not stall behind
-        # an in-progress fetch)
-        self._sup_lock = threading.RLock()
-        # abandon() raced against a drainer blocked in fetch: the flag
-        # makes recovery fail fast instead of respawning a worker the
-        # caller already tore down
-        self._abandoned = False
-        # wall-clock [t0, t1) of the most recent fetched job — the
-        # serializable span log the parent's tracer merges on drain
-        self.last_job_span: tuple[float, float] | None = None  # guarded-by: _sup_lock
-        self.worker_pid = 0
-        try:
-            self._spawn()
-        except BaseException:
-            self.close()
-            raise
-
-    def _spawn_args(self, mat):  # pragma: no cover - abstract
-        raise NotImplementedError
-
-    def _spawn(self) -> None:  # holds: _sup_lock
-        """Start a (fresh) worker incarnation: new queues — a corpse's
-        queues may hold garbage — then the ready handshake.  Callers:
-        __init__ (before any drain thread exists) and _recover_locked
-        (holding _sup_lock) — never concurrent."""
-        if faultinject._points:
-            faultinject.hit("ec.shm")
-        # spawn, not fork: the parent usually has jax (multithreaded)
-        # loaded, and forking a multithreaded process can deadlock; the
-        # child imports and initializes the native lib itself
-        ctx = mp.get_context("spawn")
-        self._jobs = ctx.Queue()
-        self._acks = ctx.Queue()
-        self._proc = ctx.Process(target=self._target,
-                                 args=self._spawn_args(self._mat),
-                                 daemon=True)
-        self._proc.start()
-        msg = self._ack_raw()
-        if msg[0] != "ready":
-            # fatal init acks are ("err", -1, detail) — surface the
-            # human-readable detail, not the seq sentinel
-            raise RuntimeError(f"parity worker failed: {msg[-1]}")
-        self.worker_pid = msg[1]
-
-    def _ack_raw(self):
-        """Bounded ack read: a dead worker surfaces as RuntimeError
-        within ~0.5s (liveness-polled), a stalled one within ack_timeout
-        — never an eternal hang."""
-        deadline = time.monotonic() + self.ack_timeout
-        while True:
-            try:
-                return self._acks.get(timeout=0.5)
-            except queue_mod.Empty:
-                if not self._proc.is_alive():
-                    raise RuntimeError("parity worker died")
-                if time.monotonic() >= deadline:
-                    raise RuntimeError("parity worker stalled")
-
-    # --- supervision ------------------------------------------------------
-    def _kill(self) -> None:
-        if self._proc is None:
-            return
-        try:
-            if self._proc.is_alive():
-                self._proc.terminate()
-                self._proc.join(timeout=2)
-                if self._proc.is_alive():  # pragma: no cover - stuck
-                    self._proc.kill()
-                    self._proc.join(timeout=2)
-        except Exception:  # pragma: no cover - already-reaped races
-            pass
-
-    def _drain_stale_acks(self) -> None:  # holds: _sup_lock
-        """After killing an incarnation, salvage whatever results it
-        managed to ack: those jobs completed (the output slot was fully
-        written before the ack), so they must NOT be replayed — a replay
-        would recompute into a slot the parent may be reading."""
-        if self._acks is None:
-            return
-        while True:
-            try:
-                msg = self._acks.get(timeout=0.05)
-            except (queue_mod.Empty, OSError, EOFError):
-                return
-            except Exception:  # pragma: no cover - corrupt queue
-                return
-            if msg and msg[0] in ("done", "err") and msg[1] >= self._seq_fetch:
-                self._done.setdefault(msg[1], msg)
-
-    def _recover(self, cause: BaseException) -> None:
-        """Kill + respawn + replay, with jittered exponential backoff;
-        raises WorkerGaveUp when the restart budget is exhausted.
-        Serialized with submit/fetch state mutations via _sup_lock."""
-        with self._sup_lock:
-            self._recover_locked(cause)
-
-    def _recover_locked(self, cause: BaseException) -> None:
-        t_rec0 = time.time()
-        err = cause
-        while True:
-            if self._abandoned:
-                # the caller tore this worker down (mid-encode fallback
-                # or abort): a drainer that was blocked in fetch must
-                # not respawn the corpse
-                raise WorkerGaveUp(
-                    f"parity worker abandoned: {err}") from cause
-            if self.restarts >= self.max_restarts:
-                self._kill()
-                raise WorkerGaveUp(
-                    f"parity worker gave up after {self.restarts} "
-                    f"restarts: {err}") from cause
-            self.restarts += 1
-            from ..observability import events as _events
-            from ..stats import ec_pipeline_metrics
-
-            ec_pipeline_metrics().worker_restarts.inc(self.kind)
-            _events.emit("worker_restart", kind=self.kind,
-                         restarts=self.restarts,
-                         cause=type(cause).__name__)
-            # jittered exponential backoff: a crash loop must not burn a
-            # core respawning, and co-scheduled encoders must not
-            # thundering-herd their respawns in lockstep
-            time.sleep(jittered_backoff(  # weedlint: lock-io recovery is deliberately exclusive: submit/fetch must stall until the respawned worker is consistent, and the backoff is bounded by restart_backoff_cap
-                self.restart_backoff, self.restart_backoff_cap,
-                self.restarts - 1))
-            self._kill()
-            self._drain_stale_acks()
-            try:
-                self._spawn()
-                if self._path is not None:
-                    self._open_in_worker(self._path)
-                replayed = 0
-                for seq, payload in self._inflight.items():
-                    if seq not in self._done and seq >= self._seq_fetch:
-                        self._jobs.put(("job", seq, payload))
-                        replayed += 1
-            except Exception as e:
-                err = e
-                continue
-            get_tracer().add_span(
-                "pipeline.retry", t_rec0, time.time(), kind=self.kind,
-                restart=self.restarts, replayed=replayed,
-                error=f"{type(cause).__name__}: {cause}")
-            return
-
-    # --- job flow ---------------------------------------------------------
-    def _submit_payload(self, payload: tuple) -> int:
-        with self._sup_lock:
-            seq = self._seq_submit
-            self._seq_submit += 1
-            self._inflight[seq] = payload
-            try:
-                self._jobs.put(("job", seq, payload))
-            except Exception as e:
-                # a broken jobs queue is a worker fault like any other:
-                # the respawn replays this job from _inflight
-                self._recover_locked(e)
-            return seq
-
-    def _await_seq(self, seq: int):
-        while True:
-            # the dedup buffer is shared with skip_next()/recovery on
-            # the producer side: every touch rides _sup_lock (never
-            # held across the blocking _ack_raw read below)
-            with self._sup_lock:
-                msg = self._done.pop(seq, None)
-            if msg is not None:
-                return msg
-            try:
-                if faultinject._points:
-                    faultinject.hit("ec.worker.ack")
-                msg = self._ack_raw()
-            except Exception as e:
-                self._recover(e)
-                continue
-            kind = msg[0]
-            if kind not in ("done", "err"):
-                continue  # late ready/opened from a respawn: ignore
-            mseq = msg[1]
-            with self._sup_lock:
-                if mseq < self._seq_fetch or mseq in self._done:
-                    continue  # duplicate of an already-consumed result
-                if mseq != seq:
-                    self._done[mseq] = msg
-                    continue
-            return msg
-
-    def fetch(self, ticket: int) -> np.ndarray:  # thread-entry
-        """Runs on the ASYNC DRAINER's fetch thread while the producer
-        keeps submitting (the weedlint thread-entry annotation above is
-        what makes the lockset checker model that).
-
-        Block until the next FIFO job's parity is ready; returns the
-        [r, b] shared-memory view (valid until the buffer index is
-        reused).  The job's wall-clock compute window lands in
-        last_job_span.  Raises WorkerJobError if the job failed inside a
-        live worker (seq consumed — recompute that dispatch and keep the
-        worker), WorkerGaveUp when supervision exhausted its budget."""
-        with self._sup_lock:
-            seq = self._seq_fetch
-        msg = self._await_seq(seq)
-        with self._sup_lock:
-            self._seq_fetch = seq + 1
-            self._inflight.pop(seq, None)
-            if msg[0] == "err":
-                self.last_job_span = None
-            else:
-                _, _, got, t0, t1 = msg
-                self.last_job_span = (t0, t1)
-        if msg[0] == "err":
-            raise WorkerJobError(msg[2])
-        if got != ticket:
-            raise RuntimeError(f"parity worker protocol: done {got}, "
-                               f"expected ticket {ticket}")
-        return self._outs[ticket]
-
-    def skip_next(self) -> None:  # thread-entry
-        """Runs on the drainer thread too (fault-fallback realignment).
-
-        Abandon the next FIFO result without reading it (the caller
-        recomputed that dispatch itself): consume the seq so later
-        fetches stay aligned; the eventual ack is deduped as stale."""
-        with self._sup_lock:
-            self._inflight.pop(self._seq_fetch, None)
-            self._done.pop(self._seq_fetch, None)
-            self._seq_fetch += 1
-
-    def _open_in_worker(self, path: str) -> None:
-        self._jobs.put(("open", path))
-        while True:
-            msg = self._ack_raw()
-            if msg[0] == "opened":
-                if msg[1] != path:
-                    raise RuntimeError(f"parity worker open: {msg[1]}")
-                return
-            if msg[0] == "err" and msg[1] == -1:
-                # the LIVE worker reports the open itself failed (file
-                # vanished/ENOENT): deterministic — respawning cannot
-                # help, the caller should fall back, not burn restarts
-                raise WorkerJobError(f"open {path}: {msg[-1]}")
-            if msg[0] in ("done", "err"):
-                with self._sup_lock:  # RLock: _recover_locked re-enters
-                    if msg[1] >= self._seq_fetch:
-                        self._done.setdefault(msg[1], msg)
-                # else: stale duplicate of a consumed/skipped result
-                # (e.g. the ack a skip_next() left unread) — drop it,
-                # do NOT treat a healthy worker as desynced
-                continue
-            raise RuntimeError(f"parity worker open: {msg[0]} {msg[1]}")
-
-    # --- teardown ---------------------------------------------------------
-    def abandon(self) -> None:
-        """Kill the worker process but keep the shared-memory slabs (and
-        any parent-side numpy views into them) alive: a mid-encode CPU
-        fallback keeps using the input slots as plain staging buffers;
-        close() runs later, after the views drop.  Also marks the worker
-        abandoned so a drainer thread blocked in fetch fails fast
-        (WorkerGaveUp) instead of respawning the corpse."""
-        # DELIBERATELY lock-free: _recover_locked holds _sup_lock
-        # through its backoff sleeps, and abandon() must not block
-        # behind a recovery in progress — the flag is a monotonic bool
-        # the recovery loop re-reads each iteration
-        self._abandoned = True  # weedlint: disable=W502 lock-free abort flag; _sup_lock is held across recovery backoff sleeps
-        self._kill()
-
-    def _close_extra(self) -> None:
-        pass
-
-    def close(self) -> None:
-        # a closed worker is discarded for good: a drainer thread still
-        # blocked in fetch must fail fast (WorkerGaveUp), not respawn a
-        # process whose shm is about to be unlinked
-        self._abandoned = True  # weedlint: disable=W502 lock-free abort flag (see abandon)
-        try:
-            if self._proc is not None and self._proc.is_alive():
-                self._jobs.put(None)
-                self._proc.join(timeout=10)
-                if self._proc.is_alive():  # pragma: no cover
-                    self._proc.terminate()
-        finally:
-            self._outs = []  # weedlint: disable=W502 teardown: close() runs after the drainer is joined or abandoned (fetch fails fast on _abandoned)
-            self._close_extra()
-            # unlink BEFORE close: close() can hit still-live caller
-            # views (abandoned-worker fallback), but the name must not
-            # leak in /dev/shm — the mapping itself is released when
-            # the views drop
-            try:
-                self._shm_out.unlink()
-            except OSError:  # pragma: no cover
-                pass
-            _close_shm_quiet(self._shm_out)
-
-    def __del__(self):  # pragma: no cover - best-effort cleanup
-        try:
-            self.close()
-        except Exception:
-            pass
-
-
-class ProcessOverlapWorker(_ParityWorkerBase):
-    """Staged-pipeline worker: dispatch buffers live in shared memory;
-    the parent fills buffer bi, submits (bi, n), the worker matmuls in
-    shared memory and acks bi."""
-
-    kind = "staged"
-
-    def __init__(self, k: int, r: int, dispatch_b: int, matrix: np.ndarray,
-                 nbufs: int, **supervise_kw):
-        self._shm_in = shared_memory.SharedMemory(
-            create=True, size=nbufs * k * dispatch_b)
-        self.bufs = [
-            np.frombuffer(self._shm_in.buf, dtype=np.uint8,
-                          count=k * dispatch_b,
-                          offset=i * k * dispatch_b).reshape(k, dispatch_b)
-            for i in range(nbufs)
-        ]
-        super().__init__(k, r, dispatch_b, matrix, nbufs, _worker_main,
-                         **supervise_kw)
-
-    def _spawn_args(self, mat):
-        return (self._shm_in.name, self._shm_out.name, self.k, self.r,
-                self.b, self.nbufs, mat.tobytes(), self._jobs, self._acks)
-
-    def submit(self, bi: int, n: int) -> int:
-        """Queue buffer bi (first n columns valid) for parity compute;
-        the ticket is bi itself (single FIFO worker).  The (bi, n)
-        payload is retained for replay until its result is fetched — the
-        shared-memory input slot stays unrecycled exactly as long."""
-        self._submit_payload((bi, n))
-        return bi
-
-    def _close_extra(self) -> None:
-        self.bufs = []
-        try:
-            self._shm_in.unlink()
-        except OSError:  # pragma: no cover
-            pass
-        _close_shm_quiet(self._shm_in)
-
-
-class FileParityWorker(_ParityWorkerBase):
-    """Compute-side half of the zero-copy mmap encode: the worker mmaps
-    the SAME input file and writes parity for (base, block, n) spans
-    into a small shared-memory slot ring, so the parent overlaps its
-    pwrite syscall time with GF(2^8) compute on multicore hosts."""
-
-    kind = "mmap"
-
-    def __init__(self, k: int, r: int, dispatch_b: int,
-                 matrix: np.ndarray, nbufs: int = 2, **supervise_kw):
-        super().__init__(k, r, dispatch_b, matrix, nbufs,
-                         _file_worker_main, **supervise_kw)
-
-    def _spawn_args(self, mat):
-        return (self._shm_out.name, self.r, self.b, self.nbufs,
-                mat.tobytes(), self.k, self._jobs, self._acks)
-
-    @property
-    def parity(self):
-        return self._outs
-
-    def open(self, path: str) -> None:
-        """Point the worker at its input file; remembered so a respawn
-        re-opens it before replaying in-flight spans.  A worker-reported
-        open failure (WorkerJobError — the file itself is the problem)
-        propagates immediately so the caller falls back without burning
-        the restart budget; only worker death/stall triggers recovery."""
-        with self._sup_lock:  # a respawn re-reads it mid-recovery
-            self._path = path
-        try:
-            self._open_in_worker(path)
-        except (WorkerGaveUp, WorkerJobError):
-            raise
-        except Exception as e:
-            self._recover(e)  # respawn re-opens self._path itself
-
-    def submit(self, slot: int, base: int, block: int, n: int) -> None:
-        self._submit_payload((slot, base, block, n))
 
 
 class AsyncDrainer:
@@ -643,9 +24,8 @@ class AsyncDrainer:
     blocking work happens elsewhere:
 
       - fetch(meta) runs on a small thread pool.  pool_size=1 keeps a
-        strict FIFO fetch order — required by the seq-numbered worker
-        ack protocol — while device-array handles (independent D2H
-        copies) may use more threads to keep several transfers in
+        strict FIFO fetch order, while device-array handles (independent
+        D2H copies) may use more threads to keep several transfers in
         flight on the wire.
       - write(meta, result) runs on ONE dedicated writer thread, fed in
         SUBMISSION order through a bounded queue, so shard append order
@@ -658,9 +38,7 @@ class AsyncDrainer:
     between dispatches — so the pipeline's existing retry-from-
     checkpoint machinery sees the failure exactly where the old inline
     drain would have raised it.  abort() is the abnormal-exit path: it
-    discards queued work and joins the threads; the caller tears down
-    (abandons) any seq-numbered worker FIRST so a fetch blocked on a
-    dead worker unblocks fast instead of respawning it.
+    discards queued work and joins the threads.
     """
 
     def __init__(self, fetch, write, pool_size: int = 1,

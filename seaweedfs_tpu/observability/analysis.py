@@ -11,16 +11,14 @@ that answer:
 
 The input is anything a trace can arrive as: a live Tracer, a list of
 Span objects, a Tracer.to_dict() document, or the Chrome trace-event
-JSON that `bench.py --trace-out` / GET /debug/traces persist — offline
+JSON that Tracer.to_chrome() / GET /debug/traces persist — offline
 analysis of a saved trace produces the same report as the live ring.
 
 Per pipeline run (each pipeline.encode_file / pipeline.rebuild_files
 root span) the report carries:
 
   - stage occupancy: seconds and share-of-wall per pipeline stage
-    (setup/fill/dispatch/compute/drain/write/fallback/close), plus the
-    concurrent worker.compute track kept separate so overlapped compute
-    never reads as serial host time;
+    (setup/fill/dispatch/compute/drain/write/fallback/close);
   - an overlap_efficiency decomposition that ties every second of the
     wall to a named stage (drain = host BLOCKED on results; anything
     not inside a span is "unattributed" — python overhead the sampling
@@ -28,11 +26,11 @@ root span) the report carries:
   - the critical path through the dispatch sequence: the dominant stage
     of each dispatch, compressed into segments, and the overall
     critical_path_stage (the argmax of the wall decomposition);
-  - gap analysis between consecutive worker.compute windows: each idle
-    gap on the worker track is classified by what the host was doing
-    meanwhile — input_starved (fill/dispatch), drain_blocked,
-    link_bound (blocked while the async drainer was actively pulling
-    parity off the wire), writer_blocked, or other;
+  - gap analysis of the concurrent fetch track: each idle gap between
+    fetch windows (no parity coming back from the device) is
+    classified by what the host was doing meanwhile — input_starved
+    (fill/dispatch), writer_blocked (data writes), drain_blocked
+    (waiting on a slot), or other;
   - drain-track awareness (PR 7): pipeline.drain spans recorded on a
     DIFFERENT thread than the run root are the async drainer's
     concurrent fetch track — reported as drain_track_s + a
@@ -166,47 +164,28 @@ def _cover_len(a: float, b: float,
     return total
 
 
-def _gap_analysis(members: list[dict],
-                  drain_track: Optional[list[dict]] = None,
-                  offthread: Optional[list[dict]] = None) -> dict:
-    """Classify idle gaps between consecutive worker.compute windows by
+def _gap_analysis(host: list[dict],
+                  drain_track: list[dict]) -> dict:
+    """Classify the idle gaps of the concurrent fetch track — between
+    merged pipeline.drain windows, while no parity is coming back — by
     what the HOST thread was doing during each gap: filling/dispatching
-    the next input (the worker is input-starved), blocked in drain, or
-    writing shards.  Host drain time that coincides with an ACTIVE
-    fetch on the concurrent drainer track is split out as `link_bound`
-    (the host waits because the wire is still moving parity) vs
-    `drain_blocked` (the host waits on drain machinery that is not
-    actually transferring)."""
-    drain_track = drain_track or []
-    track_ivs = _merged_intervals(drain_track)
-    windows = sorted((s for s in members
-                      if s["name"].startswith("worker.")),
-                     key=lambda s: s["t0"])
-    out = {"worker_windows": len(windows), "worker_busy_s": 0.0,
-           "gap_total_s": 0.0,
+    the next input (the device is input-starved), writing data shards,
+    or blocked on the slot pool with no fetch running."""
+    windows = _merged_intervals(drain_track)
+    out = {"windows": len(windows), "busy_s": 0.0, "gap_total_s": 0.0,
            "classes": {"input_starved": 0.0, "drain_blocked": 0.0,
-                       "link_bound": 0.0,
                        "writer_blocked": 0.0, "other": 0.0}}
     if not windows:
         return out
-    out["worker_busy_s"] = round(
-        sum(s["t1"] - s["t0"] for s in windows), 4)
-    # identity-based exclusion of the concurrent tracks: value equality
-    # would be O(n*m) dict compares on 10^4-span bench traces
-    excl = {id(s) for s in drain_track}
-    excl.update(id(s) for s in (offthread or []))
-    host = [s for s in members if id(s) not in excl]
+    out["busy_s"] = round(sum(b - a for a, b in windows), 4)
     by_class = {
         "input_starved": [s for s in host
                           if _stage_of(s) in ("fill", "dispatch")],
         "writer_blocked": [s for s in host if _stage_of(s) == "write"],
+        "drain_blocked": [s for s in host if _stage_of(s) == "drain"],
     }
-    drain_spans = [s for s in host if _stage_of(s) == "drain"]
-    for prev, nxt in zip(windows, windows[1:]):
-        g0, g1 = prev["t1"], nxt["t0"]
+    for (_, g0), (g1, _) in zip(windows, windows[1:]):
         gap = g1 - g0
-        if gap <= 0:
-            continue
         out["gap_total_s"] += gap
         covered = 0.0
         for cls, stage_spans in by_class.items():
@@ -214,15 +193,6 @@ def _gap_analysis(members: list[dict],
                     for sp in stage_spans)
             out["classes"][cls] += s
             covered += s
-        for sp in drain_spans:
-            a = max(g0, sp["t0"])
-            b = min(g1, sp["t1"])
-            if b <= a:
-                continue
-            lb = _cover_len(a, b, track_ivs)
-            out["classes"]["link_bound"] += lb
-            out["classes"]["drain_blocked"] += (b - a) - lb
-            covered += b - a
         out["classes"]["other"] += max(0.0, gap - covered)
     out["classes"] = {k: round(v, 4) for k, v in out["classes"].items()}
     # the classes decompose gap_total_s: independent rounding could push
@@ -237,7 +207,7 @@ def _analyze_run(root: dict, members: list[dict],
     wall = max(root["t1"] - root["t0"], _EPS)
     # the async drain (PR 7) fetches on a DIFFERENT thread than the
     # pipeline root: those pipeline.drain spans are a concurrent track
-    # (like worker.compute) — counting them as host time would let the
+    # — counting them as host time would let the
     # wall decomposition exceed 1.0 and misread an overlapped link
     # transfer as a stall.  Old (inline-drain) traces record drain on
     # the root's thread and keep the host-blocked semantics.
@@ -247,9 +217,9 @@ def _analyze_run(root: dict, members: list[dict],
     per_dispatch: dict[int, dict[str, float]] = {}
     fallback_reasons: dict[str, int] = {}
     drain_track: list[dict] = []
-    offthread: list[dict] = []           # writer/fallback threads
-    offthread_s: dict[str, float] = {}
+    offthread_s: dict[str, float] = {}   # writer/fallback threads
     drain_host_spans: list[dict] = []
+    host_spans: list[dict] = []
     retries = 0
     for sp in members:
         stage = _stage_of(sp)
@@ -273,12 +243,12 @@ def _analyze_run(root: dict, members: list[dict],
             if sp["name"] == "pipeline.drain":
                 drain_track.append(sp)
             else:
-                offthread.append(sp)
                 offthread_s[stage] = offthread_s.get(stage, 0.0) + dur
             if d is not None:
                 row = per_dispatch.setdefault(int(d), {})
                 row[stage] = row.get(stage, 0.0) + dur
             continue
+        host_spans.append(sp)
         if stage == "drain":
             drain_host_spans.append(sp)
         stage_s[stage] = stage_s.get(stage, 0.0) + dur
@@ -375,8 +345,6 @@ def _analyze_run(root: dict, members: list[dict],
 
     degraded = bool(retries or fallback_reasons
                     or int(root["attrs"].get("resume_entry") or 0) > 0)
-    worker_s = sum(s["t1"] - s["t0"] for s in members
-                   if s["name"].startswith("worker."))
     report = {
         "name": root["name"],
         "mode": root["attrs"].get("mode"),
@@ -385,7 +353,6 @@ def _analyze_run(root: dict, members: list[dict],
         "wall_s": round(wall, 4),
         "dispatches": len(per_dispatch),
         "stage_s": {k: round(v, 4) for k, v in sorted(stage_s.items())},
-        "worker_compute_s": round(worker_s, 4),  # concurrent track
         "drain_track_s": round(track_s, 4),      # concurrent fetch track
         # writer/fallback work on the drainer's threads, per stage
         "concurrent_stage_s": {k: round(v, 4)
@@ -396,7 +363,7 @@ def _analyze_run(root: dict, members: list[dict],
         "attribution": attribution,
         "critical_path_stage": critical_path_stage,
         "critical_path": segments,
-        "gap_analysis": _gap_analysis(members, drain_track, offthread),
+        "gap_analysis": _gap_analysis(host_spans, drain_track),
         "degraded": degraded,
         "retries": retries,
         "fallbacks": sum(fallback_reasons.values()),
@@ -733,8 +700,7 @@ def render_cluster_report(report: dict) -> str:
 
 
 def attribution_summary(report: dict) -> dict:
-    """The compact block bench.py embeds as e2e_pipeline_*.attribution:
-    per-stage seconds, the critical-path stage, and the degraded flag
+    """The compact attribution block of a report: per-stage seconds, the critical-path stage, and the degraded flag
     for the report's LAST run (the measured rep)."""
     if not report.get("runs"):
         return {"degraded": report.get("degraded", False)}
@@ -789,11 +755,11 @@ def render_report(report: dict) -> str:
             lines.append(f"  {stage:<{width}} {row['s']:>9.3f}s "
                          f"{100 * row['share']:5.1f}% {bar}")
         ga = run["gap_analysis"]
-        if ga["worker_windows"]:
+        if ga["windows"]:
             cls = ", ".join(f"{k}={v}s" for k, v in ga["classes"].items()
                             if v > 0)
-            lines.append(f"  worker gaps: {ga['gap_total_s']}s over "
-                         f"{ga['worker_windows']} windows ({cls or 'none'})")
+            lines.append(f"  fetch gaps: {ga['gap_total_s']}s over "
+                         f"{ga['windows']} windows ({cls or 'none'})")
         if run["critical_path"]:
             path = " -> ".join(
                 f"{seg['stage']}[d{seg['dispatches'][0]}"

@@ -9,7 +9,7 @@ that answer: a bounded, thread-safe ring of TYPED events emitted at the
 exact chokepoints that already bump the degraded-path counters:
 
     from seaweedfs_tpu.observability import events as _events
-    _events.emit("worker_restart", kind="staged", restarts=2)
+    _events.emit("engine_fallback", reason="drain_fault", engine="device")
 
 Each event carries a type from EVENT_TYPES (with a default severity), a
 wall timestamp, the emitting server (from the request thread-local when
@@ -51,7 +51,7 @@ SEVERITY_RANK = {s: i for i, s in enumerate(SEVERITIES)}
 # others fails tier-1 instead of drifting silently.
 EVENT_TYPES = {
     # degraded-path chokepoints (each shadows a /metrics counter)
-    "worker_restart": "warning",        # ec/overlap.py supervisor respawn
+    "worker_restart": "warning",        # no emitter: parity workers are gone
     "engine_fallback": "warning",       # ec/streaming.py + ec/codec.py
     "shard_corrupt": "error",           # ec/integrity.py note_corruption
     "scrub_repair": "warning",          # scrubber quarantine+rebuild ok

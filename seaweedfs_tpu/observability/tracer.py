@@ -17,8 +17,8 @@ Design constraints, in order:
   - mergeable across processes: span ids are namespaced (pid-derived by
     default) and timestamps are wall-anchored monotonic clocks, so a
     worker process's serializable span log (export_log/ingest_log, or
-    the overlap workers' timed acks fed through add_span) merges into
-    the parent's timeline without id collisions;
+    externally timed windows fed through add_span) merges into the
+    parent's timeline without id collisions;
   - on the device trace's clock: once a device engine has set up
     (ops/device.py installs `annotation_hook`), every live span opened
     through span() also enters a profiler annotation named after it,
@@ -245,7 +245,7 @@ class Tracer:
                  thread: str = "", tid: Optional[int] = None,
                  **attrs) -> Optional[str]:
         """Record an externally timed span (wall-clock seconds — e.g.
-        the overlap worker's compute window shipped back in its ack).
+        a compile's duration reported after the fact).
         `tid` places the span on its own thread track (defaults to the
         calling thread)."""
         if not self.enabled or _trace_context.is_not_sampled():

@@ -1,7 +1,7 @@
 """Production-shaped scenario harness (ROADMAP open item 4).
 
-bench.py's cluster sections are uniform-random RPS loops — nothing like
-millions of users.  This package drives a spawned cluster with the
+Uniform-random RPS loops are nothing like millions of users.  This
+package drives a spawned cluster with the
 shapes production actually has — Zipfian object popularity over a hot
 set, a mixed size distribution, delete churn, and MID-LOAD fault
 injection from the W701-checked FAULT_POINTS registry — with the
@@ -15,9 +15,8 @@ spec's expectations.
     for spec in default_scenarios():
         result = run_scenario(spec)
 
-The bench `scenarios` section runs the three canonical specs (Zipfian
-hot-set read storm, mixed-size write+churn, failure-under-load) and
-stamps each verdict into the bench JSON.
+default_scenarios() holds the three canonical specs (Zipfian hot-set
+read storm, mixed-size write+churn, failure-under-load).
 
 Two more entry points close the loop with the observability stack:
 
@@ -28,9 +27,7 @@ Two more entry points close the loop with the observability stack:
     scaled) rate, with replay_fidelity machine-checking the
     reproduction;
   - capacity.find_capacity / probe_cluster binary-search the max
-    sustainable rps per route class under a declared SLO — the bench
-    `capacity` section's numbers and the dataplane refactor's
-    acceptance baseline.
+    sustainable rps per route class under a declared SLO.
 """
 
 from .capacity import CapacitySLO, find_capacity, measure_rate

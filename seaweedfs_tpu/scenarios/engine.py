@@ -15,8 +15,7 @@ p50/p90/p99), per-phase throughput + accepted-p99 (healthy / fault /
 recovery), the request-plane counter deltas (shed, deadline_exceeded,
 retry_budget_exhausted), the fault + alert timelines, one stitched
 sampled trace, and a `checks` list scoring the spec's expectations —
-`verdict` is "pass" only when every check holds.  bench.py's
-`scenarios` section embeds these documents verbatim.
+`verdict` is "pass" only when every check holds.
 """
 
 from __future__ import annotations

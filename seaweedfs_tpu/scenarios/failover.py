@@ -26,8 +26,8 @@ restriction then guarantees the winner has every one of them.  Events
 ingested in the kill window itself are post-kill by definition.
 
 The result document mirrors the scenario engine's shape (routes,
-checks, verdict) so bench.py's `master_failover` section and
-tools/bench_diff.py floor it like any other scenario.
+checks, verdict) so tools/bench_diff.py floors it like any other
+scenario.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ trace.analyze turns a span ring into the attribution report
 gap classification, and the clean-vs-degraded verdict — the answer the
 next perf PR needs, without eyeballing raw span dumps.  -file accepts
 either a Tracer.to_dict() document or the Chrome trace JSON written by
-`bench.py --trace-out` / GET /debug/traces.
+Tracer.to_chrome() / GET /debug/traces.
 """
 
 from __future__ import annotations

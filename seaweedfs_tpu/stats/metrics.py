@@ -433,16 +433,18 @@ class S3Metrics(_ServerMetrics):
 
 
 class ECPipelineMetrics:
-    """Self-healing EC pipeline counters: worker restarts by the
-    supervisor (ec/overlap.py) and per-dispatch engine fallbacks to the
-    CPU codec (ec/streaming.py, ec/codec.py).  Separate from the
-    per-role bundles because the pipeline runs inside whatever process
-    invoked the encode — volume server, shell tool, or bench."""
+    """Self-healing EC pipeline counters: per-dispatch engine fallbacks
+    to the CPU codec (ec/streaming.py, ec/codec.py) and the dispatches
+    by input source.  worker_restarts stays registered at 0 so /status
+    and the alert plane keep their shape: no parity worker process is
+    left to restart.  Separate from the per-role bundles because the
+    pipeline runs inside whatever process invoked the encode — volume
+    server or shell tool."""
 
     def __init__(self, registry: Registry = REGISTRY):
         self.worker_restarts = registry.counter(
             "SeaweedFS_ec_worker_restarts_total",
-            "Parity worker processes respawned by the pipeline supervisor.",
+            "Parity worker processes respawned (none exist: always 0).",
             labels=("kind",))
         self.engine_fallbacks = registry.counter(
             "SeaweedFS_ec_engine_fallbacks_total",

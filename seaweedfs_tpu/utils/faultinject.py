@@ -16,17 +16,6 @@ Instrumented sites (grep for fi.hit to find them all):
     disk.read / disk.write / disk.sync   — DiskFile positional IO
     shard.read                           — EC shard pread
     net.request                          — pooled HTTP client sends
-    ec.worker.ack                        — parity worker ack read (parent
-                                           side); an injected error is
-                                           treated as worker death: the
-                                           supervisor SIGKILLs and
-                                           respawns the real process,
-                                           replaying in-flight dispatches
-    ec.shm                               — parity worker spawn/shm attach;
-                                           arming it makes respawns fail,
-                                           deterministically exhausting
-                                           the retry budget (CPU fallback
-                                           drills)
     ec.dispatch / ec.drain               — streaming pipeline submit and
                                            drain; an injected error forces
                                            a per-dispatch CPU fallback
@@ -39,11 +28,6 @@ Instrumented sites (grep for fi.hit to find them all):
                                            back flipped — the bit-rot
                                            drill behind the sidecar
                                            verify-on-use paths
-
-The ec.* points fire in the ENCODING PARENT only: overlap workers are
-spawned processes with their own (empty) fault registry, so arming a
-point never corrupts worker-side compute — it exercises the parent's
-recovery paths deterministically.
 """
 
 from __future__ import annotations
@@ -70,12 +54,6 @@ FAULT_POINTS: dict[str, str] = {
     "disk.sync": "DiskFile fsync (storage/backend.py)",
     "shard.read": "EC shard pread (ec/ec_volume.py)",
     "net.request": "pooled HTTP client send (utils/httpd.py)",
-    "ec.worker.ack": "parity-worker ack read, parent side — injected "
-                     "error is treated as worker death: SIGKILL + "
-                     "respawn + in-flight replay (ec/overlap.py)",
-    "ec.shm": "parity-worker spawn / shm attach — arming makes "
-              "respawns fail, draining the restart budget for CPU-"
-              "fallback drills (ec/overlap.py)",
     "ec.dispatch": "streaming pipeline submit — injected error forces "
                    "a per-dispatch CPU fallback (ec/streaming.py)",
     "ec.drain": "streaming pipeline drain — injected error forces a "

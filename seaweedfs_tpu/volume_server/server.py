@@ -1157,9 +1157,9 @@ class VolumeServer:
                 "Version": "seaweedfs-tpu 0.1",
                 "Volumes": volumes,
                 "EcVolumes": sorted(list(self.store.ec_volumes)),
-                # self-healing pipeline health: nonzero restarts mean the
-                # supervisor respawned parity workers, nonzero fallbacks
-                # mean dispatches degraded to the CPU codec — encodes
+                # self-healing pipeline health: nonzero fallbacks mean
+                # dispatches degraded to the CPU codec (worker_restarts
+                # stays 0: no parity worker process exists) — encodes
                 # still completed byte-identical, but perf numbers from
                 # this server may reflect degraded runs
                 "EcPipeline": ec_pipeline_metrics().totals(),

@@ -40,14 +40,6 @@ def _encode_file_staged(self):
     drainer = AsyncDrainer(drain_fetch_core, lambda m, p: None)
     drainer.finish()
 
-def _encode_file_mmap(self):
-    def drain_fetch(meta):
-        with tr.span("pipeline.drain", dispatch=0):
-            parity = worker.fetch(meta)[:, :4]
-        return parity
-    drainer = AsyncDrainer(drain_fetch, lambda m, p: None)
-    drainer.finish()
-
 def _encode_file_mesh(self):
     def drain_fetch_dev(meta):
         with tr.span("pipeline.drain", device=0):
@@ -68,8 +60,8 @@ class TestPlantedViolations:
     def test_blocking_fetch_in_hot_loop_rejected(self):
         # the pre-PR-7 shape: _fetch called straight from the loop body
         src = CLEAN.replace(
-            "    drainer.finish()\n\ndef _encode_file_mmap",
-            "    parity = self._fetch(handle)\n\ndef _encode_file_mmap")
+            "    drainer.finish()\n\ndef _encode_file_mesh",
+            "    parity = self._fetch(handle)\n\ndef _encode_file_mesh")
         problems = CHECK.check_streaming_source(src, "x.py")
         assert problems and "_fetch" in problems[0] \
             and "drain" in problems[0]
@@ -83,15 +75,15 @@ class TestPlantedViolations:
 
     def test_missing_async_drainer_rejected(self):
         src = CLEAN.replace(
-            "    drainer = AsyncDrainer(drain_fetch, lambda m, p: None)\n"
+            "    drainer = AsyncDrainer(drain_fetch_core, lambda m, p: None)\n"
             "    drainer.finish()", "    pass")
         problems = CHECK.check_streaming_source(src, "x.py")
-        assert any("AsyncDrainer" in p and "_encode_file_mmap" in p
+        assert any("AsyncDrainer" in p and "_encode_file_staged" in p
                    for p in problems)
 
     def test_missing_hot_func_rejected(self):
         problems = CHECK.check_streaming_source("x = 1\n", "x.py")
-        assert len(problems) == 3
+        assert len(problems) == 2
         assert all("not found" in p for p in problems)
 
     def test_mesh_without_any_drainer_rejected(self):

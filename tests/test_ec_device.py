@@ -48,9 +48,13 @@ def test_volume_store_refuses_to_start_without_tpu(unpinned, tmp_path,
 
 
 def test_auto_engine_picks_the_host_codec_off_tpu():
+    """No engine is picked for the caller: off (and on) the TPU the
+    host codec is the CPU path, ec/encoder.write_ec_files, which the
+    streaming encoder names when asked for "auto"."""
     from seaweedfs_tpu.ec.streaming import StreamingEncoder
 
-    assert StreamingEncoder(engine="auto").engine == "host"
+    with pytest.raises(ValueError, match="ec/encoder.write_ec_files"):
+        StreamingEncoder(engine="auto")
 
 
 @pytest.fixture

@@ -292,26 +292,27 @@ def test_reconstruct_interval_skips_oserror_shard(tmp_path, monkeypatch):
 # --- streaming encode/rebuild --------------------------------------------
 
 def test_streaming_rebuild_demotes_corrupt_survivor(tmp_path):
-    """The StreamingEncoder rebuild (staged and, where the native
-    toolchain exists, mmap) detects survivor rot via the sidecar and
-    regenerates both the missing and the rotted shard byte-identical."""
+    """The StreamingEncoder rebuild (after an encode on one device, the
+    staged plane, and on every device, the queue plane) detects
+    survivor rot via the sidecar and regenerates both the missing and
+    the rotted shard byte-identical."""
     from seaweedfs_tpu.ec.streaming import StreamingEncoder
 
     base = str(tmp_path / "v")
     open(base + ".dat", "wb").write(
         rng.integers(0, 256, 1_200_000, dtype=np.uint8).tobytes())
-    for zero_copy in (True, False):
-        out = str(tmp_path / f"o{int(zero_copy)}")
-        enc = StreamingEncoder(10, 4, engine="host", zero_copy=zero_copy,
-                               overlap="none", dispatch_mb=1)
+    for devices in ("1", None):
+        out = str(tmp_path / f"o{devices}")
+        enc = StreamingEncoder(10, 4, engine="device", devices=devices,
+                               dispatch_mb=1)
         enc.encode_file(base + ".dat", out, 1_000_000, 10_000)
         assert os.path.exists(out + ".eci")
         ref = _shards(out)
         os.remove(out + to_ext(7))
         _flip(out + to_ext(2), 55_555, bit=3)
         generated = enc.rebuild_files(out)
-        assert sorted(generated) == [2, 7], (zero_copy, generated)
-        assert _shards(out) == ref, zero_copy
+        assert sorted(generated) == [2, 7], (devices, generated)
+        assert _shards(out) == ref, devices
         assert enc.stats["verify_s"] >= 0.0
 
 
@@ -325,22 +326,28 @@ def test_sidecar_survives_checkpoint_resume(tmp_path, monkeypatch):
     base = str(tmp_path / "v")
     open(base + ".dat", "wb").write(
         rng.integers(0, 256, 2_000_000, dtype=np.uint8).tobytes())
+    enc = StreamingEncoder(10, 4, engine="device", devices="1",
+                           dispatch_mb=1, depth=1)
+    enc.dispatch_b = 65536
     real = streaming_mod.preadv_into
     calls = {"n": 0}
 
     def flaky(f, views, off):
         calls["n"] += 1
         if calls["n"] == 15:
+            # fault once the writer has retired a dispatch: the retry
+            # resumes past byte 0
+            deadline = time.monotonic() + 30
+            while enc._ckpt[1] == 0 and time.monotonic() < deadline:
+                time.sleep(0.002)
             raise OSError("injected fill IO error")
         return real(f, views, off)
 
     monkeypatch.setattr(streaming_mod, "preadv_into", flaky)
-    enc = StreamingEncoder(10, 4, engine="host", zero_copy=False,
-                           overlap="none", dispatch_mb=1, depth=1)
-    enc.dispatch_b = 65536
     out = str(tmp_path / "o")
     enc.encode_file(base + ".dat", out, 1_000_000, 10_000)
     assert enc.stats["retries"] == 1  # the drill actually resumed
+    assert enc._ckpt[1] > 0
     resumed = EciSidecar.load(out)
     assert resumed is not None
     clean = backfill_sidecar(out)  # recompute from the finished shards

@@ -8,6 +8,8 @@ tails (ec_encoder.go:172-231 semantics).
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import os
 import time
 
@@ -20,16 +22,46 @@ from seaweedfs_tpu.ec.layout import to_ext
 from seaweedfs_tpu.ec.streaming import (StreamingEncoder, _plan_entries,
                                         default_drain_pool)
 from seaweedfs_tpu.observability import Tracer
+from seaweedfs_tpu.utils import faultinject as fi
 
 RNG = np.random.default_rng(0x5EA)
 
+ENGINES = ["pallas", "fallback", "device"]
+
+
+def _interpret_pallas(enc):
+    """The chip's word kernel in interpret mode, one tile a block."""
+    from seaweedfs_tpu.ops.gf_matmul import (INTERPRET_TILE_B,
+                                             gf_matmul_pallas_packed)
+
+    enc._kernel = functools.partial(gf_matmul_pallas_packed,
+                                    tile_b=INTERPRET_TILE_B, interpret=True)
+    enc._row_q = INTERPRET_TILE_B
+    return enc
+
 
 def make_enc(k, r, engine, **kw):
-    """ "host" = zero-copy mmap path, "host-pipeline" = staged host
-    pipeline (zero_copy off), "device" = jax path."""
-    if engine == "host-pipeline":
-        return StreamingEncoder(k, r, engine="host", zero_copy=False, **kw)
-    return StreamingEncoder(k, r, engine=engine, **kw)
+    """"device" = the XLA kernel on every device of the slice (the
+    queue plane on the tests' 8 CPU devices); "pallas" = the word
+    kernel in interpret mode on one device; "fallback" = one device,
+    every dispatch's parity from the CPU codec (armed by _faults)."""
+    if engine == "device":
+        return StreamingEncoder(k, r, engine="device", **kw)
+    enc = StreamingEncoder(k, r, engine="device", devices="1", **kw)
+    return _interpret_pallas(enc) if engine == "pallas" else enc
+
+
+@contextlib.contextmanager
+def _faults(engine):
+    """"fallback" arms ec.dispatch at rate 1.0 for the block."""
+    if engine != "fallback":
+        yield
+        return
+    fi.enable("ec.dispatch", error_rate=1.0)
+    try:
+        yield
+    finally:
+        fi.clear()
 
 
 def _write_dat(tmp_path, size, name="v"):
@@ -56,7 +88,7 @@ def npchunk(small):
     return max(64, small // 3 * 2)
 
 
-@pytest.mark.parametrize("engine", ["host", "host-pipeline", "device"])
+@pytest.mark.parametrize("engine", ENGINES)
 @pytest.mark.parametrize("size,large,small", [
     (0, 10_000, 100),              # empty volume
     (999, 10_000, 100),            # sub-single-row tail
@@ -70,9 +102,12 @@ def test_streaming_encode_byte_identical(tmp_path, size, large, small, engine):
     ref = _cpu_reference(tmp_path, base, large, small)
     enc = make_enc(10, 4, engine, dispatch_mb=1)
     enc.dispatch_b = 4096  # force multi-dispatch packing paths
-    enc.encode_file(base + ".dat", base,
-                    large_block_size=large, small_block_size=small)
+    with _faults(engine):
+        enc.encode_file(base + ".dat", base,
+                        large_block_size=large, small_block_size=small)
     assert _shards(base, 14) == _shards(ref, 14)
+    if engine == "fallback":
+        assert enc.stats["fallbacks"] == enc.stats["dispatches"]
 
 
 def test_streaming_encode_default_geometry_small_dispatch(tmp_path):
@@ -87,13 +122,16 @@ def test_streaming_encode_default_geometry_small_dispatch(tmp_path):
     assert _shards(base, 14) == _shards(ref, 14)
 
 
-@pytest.mark.parametrize("engine", ["host", "host-pipeline", "device"])
+@pytest.mark.parametrize("engine", ENGINES)
 @pytest.mark.parametrize("kill", [
     [0],            # one data shard
     [11],           # one parity shard
     [0, 3, 11, 13],  # worst case: 4 erasures mixed data+parity
 ])
 def test_streaming_rebuild_byte_identical(tmp_path, kill, engine):
+    """A rebuild has no per-dispatch fault points: armed as for an
+    encode, "fallback" still rebuilds on the device, and nothing
+    fires."""
     large, small = 10_000, 100
     base = _write_dat(tmp_path, 123_457)
     encoder.write_ec_files(base, ReedSolomon(10, 4),
@@ -103,9 +141,12 @@ def test_streaming_rebuild_byte_identical(tmp_path, kill, engine):
         os.unlink(base + to_ext(i))
     enc = make_enc(10, 4, engine)
     enc.dispatch_b = 4096
-    got_ids = enc.rebuild_files(base)
+    with _faults(engine):
+        got_ids = enc.rebuild_files(base)
+        assert fi.fired("ec.dispatch") == 0
     assert got_ids == sorted(kill)
     assert _shards(base, 14) == want
+    assert enc.stats["fallbacks"] == 0
 
 
 def test_streaming_rebuild_unrepairable(tmp_path):
@@ -118,7 +159,7 @@ def test_streaming_rebuild_unrepairable(tmp_path):
         StreamingEncoder(10, 4).rebuild_files(base)
 
 
-@pytest.mark.parametrize("engine", ["host", "host-pipeline", "device"])
+@pytest.mark.parametrize("engine", ENGINES)
 def test_streaming_alt_geometries(tmp_path, engine):
     for k, r in ((6, 3), (12, 4)):
         base = _write_dat(tmp_path, 77_777, name=f"g{k}{r}{engine[0]}")
@@ -129,31 +170,10 @@ def test_streaming_alt_geometries(tmp_path, engine):
                                small_block_size=100, chunk=512)
         enc = make_enc(k, r, engine)
         enc.dispatch_b = 2048
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
+        with _faults(engine):
+            enc.encode_file(base + ".dat", base,
+                            large_block_size=10_000, small_block_size=100)
         assert _shards(base, k + r) == _shards(ref, k + r)
-
-
-def test_process_overlap_worker_byte_identical(tmp_path):
-    """overlap="process" runs the codec in a separate process over
-    shared memory (ec/overlap.py) — same shards, worker reused across
-    encodes, clean shutdown."""
-    base = _write_dat(tmp_path, 123_457, name="ov")
-    ref = _cpu_reference(tmp_path, base, 10_000, 100)
-    enc = StreamingEncoder(10, 4, engine="host", overlap="process")
-    enc.dispatch_b = 4096
-    try:
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-        # worker survives a second encode (buffer pool reuse)
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-        assert enc._proc_worker is not None
-    finally:
-        if enc._proc_worker is not None:
-            enc._proc_worker.close()
 
 
 class _SlowHandle:
@@ -172,12 +192,11 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
     CPU: every fetch blocks LINK_S (injected copy latency) while the
     host floor per dispatch is HOST_S (injected via the ec.dispatch
     delay fault).  With N=3 buffers the async drain must move that wire
-    time onto the drainer thread — overlap_efficiency >= 0.6 where the
-    inline drain measures ~HOST/(HOST+LINK) — while fetch order stays
-    FIFO and the output (shards AND the write-order-crc `.eci`
+    time onto the drainer thread — overlap_efficiency >= 0.6 where an
+    inline drain would measure ~HOST/(HOST+LINK) — while fetch order
+    stays FIFO and the output (shards AND the write-order-crc `.eci`
     sidecar) stays byte-identical to the CPU reference."""
     from seaweedfs_tpu.ec.integrity import sidecar_path
-    from seaweedfs_tpu.utils import faultinject as fi
 
     HOST_S, LINK_S = 0.03, 0.03
     large, small = 100 << 20, 1 << 18
@@ -187,46 +206,43 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
     encoder.write_ec_files(ref, ReedSolomon(10, 4),
                            large_block_size=large, small_block_size=small)
 
-    def run(async_drain):
-        tracer = Tracer(capacity=1 << 14)
-        enc = StreamingEncoder(10, 4, engine="host", zero_copy=False,
-                               overlap="none", depth=2,  # N = 3 buffers
-                               async_drain=async_drain, tracer=tracer)
-        enc.dispatch_b = 1 << 18
-        order: list[int] = []
-        real_dispatch = enc._dispatch
-        seq = {"n": 0}
+    tracer = Tracer(capacity=1 << 14)
+    # one fetch thread: the fetch order itself is FIFO
+    enc = StreamingEncoder(10, 4, engine="device", devices="1", depth=2,
+                           drain_pool=1, tracer=tracer)  # N = 3 buffers
+    enc.dispatch_b = 1 << 18
+    order: list[int] = []
+    seq = {"n": 0}
 
-        def slow_dispatch(planes, buf):
-            h = _SlowHandle(real_dispatch(planes, buf), seq["n"])
-            seq["n"] += 1
-            return h
+    def slow_dispatch(planes, buf):
+        # the fake device: parity from the CPU codec, finished on submit
+        data = buf if buf.ndim == 2 else \
+            buf.transpose(1, 0, 2).reshape(buf.shape[1], -1)
+        h = _SlowHandle(enc._cpu_parity(data), seq["n"])
+        seq["n"] += 1
+        return h
 
-        def slow_fetch(h):
-            time.sleep(LINK_S)  # the wire, paid by the fetching thread
-            order.append(h.seq)
-            return h.parity
+    def slow_fetch(h):
+        time.sleep(LINK_S)  # the wire, paid by the fetching thread
+        order.append(h.seq)
+        return h.parity
 
-        enc._dispatch = slow_dispatch
-        enc._fetch = slow_fetch
-        out = str(tmp_path / ("slow_async" if async_drain else "slow_ser"))
-        fi.enable("ec.dispatch", delay=HOST_S)
-        try:
-            enc.encode_file(base + ".dat", out,
-                            large_block_size=large, small_block_size=small)
-        finally:
-            fi.clear()
-        # host-blocked time: the producer thread's drain stalls, and
-        # in the inline serial drain its own fetches
-        spans = tracer.snapshot()
-        producer = next(s.tid for s in spans
-                        if s.name == "pipeline.encode_file")
-        blocked = sum(s.duration for s in spans if s.tid == producer
-                      and s.name in ("pipeline.drain_wait", "pipeline.drain"))
-        eff = 1.0 - blocked / enc.stats["wall_s"]
-        return enc, out, order, eff
-
-    enc, out, order, eff = run(async_drain=True)
+    enc._dispatch = slow_dispatch
+    enc._fetch = slow_fetch
+    out = str(tmp_path / "slow_async")
+    fi.enable("ec.dispatch", delay=HOST_S)
+    try:
+        enc.encode_file(base + ".dat", out,
+                        large_block_size=large, small_block_size=small)
+    finally:
+        fi.clear()
+    # host-blocked time: the producer thread's drain stalls
+    spans = tracer.snapshot()
+    producer = next(s.tid for s in spans
+                    if s.name == "pipeline.encode_file")
+    blocked = sum(s.duration for s in spans if s.tid == producer
+                  and s.name in ("pipeline.drain_wait", "pipeline.drain"))
+    eff = 1.0 - blocked / enc.stats["wall_s"]
     n = enc.stats["dispatches"]
     assert n >= 16  # enough dispatches for the pipeline to fill
     # FIFO: the drainer fetches dispatches strictly in submission order
@@ -238,7 +254,7 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
     # RESIDUAL wait: the part that already elapsed under host work is
     # exactly the latency the async drain hid)
     assert enc.stats["drain_s"] >= LINK_S
-    assert enc.stats["drain_pool"] >= 1
+    assert enc.stats["drain_pool"] == 1
     # parity-only drain: exactly r/k of bytes_in crossed back
     assert enc.stats["parity_bytes_drained"] == \
         enc.stats["bytes_in"] * 4 // 10
@@ -246,11 +262,6 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
     assert _shards(out, 14) == _shards(ref, 14)
     assert open(sidecar_path(out), "rb").read() == \
         open(sidecar_path(ref), "rb").read()
-    # the inline drain on the same workload eats the wire serially
-    # (~HOST/(HOST+LINK) efficiency): the async drain is what hides it
-    _, _, order_s, eff_serial = run(async_drain=False)
-    assert order_s == list(range(n))
-    assert eff_serial <= eff - 0.1
 
 
 def test_default_drain_pool_bounds():
@@ -266,7 +277,7 @@ def test_async_drain_device_engine_byte_identical(tmp_path):
     writer appends FIFO — bytes must not care."""
     base = _write_dat(tmp_path, 123_457, name="adev")
     ref = _cpu_reference(tmp_path, base, 10_000, 100)
-    enc = StreamingEncoder(10, 4, engine="device", async_drain=True)
+    enc = StreamingEncoder(10, 4, engine="device")
     enc.dispatch_b = 4096
     enc.encode_file(base + ".dat", base,
                     large_block_size=10_000, small_block_size=100)
@@ -333,82 +344,6 @@ def test_failed_rebuild_leaves_no_empty_shards(tmp_path, make):
     assert not os.path.exists(base + to_ext(2))  # no empty ghost shard
 
 
-def test_file_parity_worker_byte_identical(tmp_path):
-    """overlap="mmap-process" keeps the zero-copy mmap read path but
-    computes parity in a separate process that mmaps the same file
-    (ec/overlap.py FileParityWorker) — byte-identical shards, worker
-    reused across two different files, tail entries still handled by
-    the parent."""
-    base = _write_dat(tmp_path, 123_457, name="fw")
-    ref = _cpu_reference(tmp_path, base, 10_000, 100)
-    enc = StreamingEncoder(10, 4, engine="host", overlap="mmap-process")
-    enc.dispatch_b = 4096
-    try:
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-        assert enc._file_worker  # actually engaged
-        # a SECOND file reuses the worker (re-opened in the child)
-        base2 = _write_dat(tmp_path, 3 * 10 * 10_000 + 7, name="fw2")
-        ref2 = str(tmp_path / "ref2")
-        os.link(base2 + ".dat", ref2 + ".dat")
-        encoder.write_ec_files(ref2, ReedSolomon(10, 4),
-                               large_block_size=10_000,
-                               small_block_size=100, chunk=npchunk(100))
-        enc.encode_file(base2 + ".dat", base2,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base2, 14) == _shards(ref2, 14)
-    finally:
-        if enc._file_worker:
-            enc._file_worker.close()
-
-
-def test_file_parity_worker_respawns_on_dispatch_change(tmp_path):
-    """dispatch_b is baked into the worker's shm slot ring: changing it
-    must respawn the worker, not silently truncate parity columns."""
-    base = _write_dat(tmp_path, 123_457, name="fwb")
-    ref = _cpu_reference(tmp_path, base, 10_000, 100)
-    enc = StreamingEncoder(10, 4, engine="host", overlap="mmap-process")
-    enc.dispatch_b = 2048
-    try:
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-        first = enc._file_worker
-        enc.dispatch_b = 8192  # grow: stale worker would truncate at 2048
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-        assert enc._file_worker is not first  # respawned
-    finally:
-        enc._drop_file_worker()
-
-
-def test_file_parity_worker_death_falls_back_serial(tmp_path):
-    """A dead worker must not hang or corrupt: the encode falls back to
-    serial compute and a later encode respawns a fresh worker."""
-    base = _write_dat(tmp_path, 123_457, name="fwd")
-    ref = _cpu_reference(tmp_path, base, 10_000, 100)
-    enc = StreamingEncoder(10, 4, engine="host", overlap="mmap-process")
-    enc.dispatch_b = 4096
-    try:
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-        # kill the worker out from under the encoder
-        enc._file_worker._proc.terminate()
-        enc._file_worker._proc.join(timeout=10)
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)  # still correct
-        # the corpse was dropped; the NEXT encode spawns fresh and works
-        enc.encode_file(base + ".dat", base,
-                        large_block_size=10_000, small_block_size=100)
-        assert _shards(base, 14) == _shards(ref, 14)
-    finally:
-        enc._drop_file_worker()
-
-
 # --- whole groups of small-block rows, dispatched from the mapped .dat ---
 # 1 KiB blocks and 4 KiB dispatches: a group is 4 rows, 40 KiB of .dat
 MAP_LARGE, MAP_SMALL, MAP_B = 1 << 20, 1024, 4096
@@ -419,19 +354,9 @@ ROW = 10 * MAP_SMALL
 def _device_enc(kernel="xla", **kw):
     """The staged plane (one device) at the mapped geometry; "pallas"
     runs the chip's word kernel in interpret mode, one tile a block."""
-    import functools
-
-    from seaweedfs_tpu.ops.gf_matmul import (INTERPRET_TILE_B,
-                                             gf_matmul_pallas_packed)
-
     enc = StreamingEncoder(10, 4, engine="device", devices="1", **kw)
     enc.dispatch_b = MAP_B
-    if kernel == "pallas":
-        enc._kernel = functools.partial(gf_matmul_pallas_packed,
-                                        tile_b=INTERPRET_TILE_B,
-                                        interpret=True)
-        enc._row_q = INTERPRET_TILE_B
-    return enc
+    return _interpret_pallas(enc) if kernel == "pallas" else enc
 
 
 def _files(base, total=14):
@@ -443,24 +368,24 @@ def _dat_mapped(path) -> bool:
         return path in f.read()
 
 
-@pytest.mark.parametrize("kernel,async_drain", [
-    ("xla", None), ("pallas", None), ("xla", False)],
-    ids=["xla", "pallas", "xla-serial-drain"])
+@pytest.mark.parametrize("kernel,depth", [
+    ("xla", 3), ("pallas", 3), ("xla", 1)],
+    ids=["xla", "pallas", "xla-depth1"])
 @pytest.mark.parametrize("size,want", [
     (3 * GROUP, (3, 3)),                  # an exact multiple of a group
     (3 * GROUP + 3 * ROW + 77, (4, 3)),   # last group ends past EOF
     (GROUP - 1, (1, 0)),                  # smaller than one group
 ], ids=["groups", "partial-row", "under-a-group"])
-def test_mapped_rows_byte_identical(tmp_path, size, want, kernel,
-                                    async_drain):
+def test_mapped_rows_byte_identical(tmp_path, size, want, kernel, depth):
     """Whole groups go to the kernel as [rows, K, W] views of the mapped
     .dat, the rest through a slot: shards and the .eci sidecar equal the
     CPU encoder's, and each dispatch says where its input came from,
-    with the async drain and the serial one."""
+    at the default depth and with one dispatch in flight behind the
+    one being filled."""
     base = _write_dat(tmp_path, size)
     ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
     tracer = Tracer(capacity=1 << 12)
-    enc = _device_enc(kernel, tracer=tracer, async_drain=async_drain)
+    enc = _device_enc(kernel, tracer=tracer, depth=depth)
     enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
     assert _files(base) == _files(ref)
     assert (enc.stats["dispatches"], enc.stats["mapped_dispatches"]) == want
@@ -504,31 +429,69 @@ def test_large_block_rows_take_slots(tmp_path):
     assert _files(base) == _files(ref)
 
 
-@pytest.mark.parametrize("point", ["ec.dispatch", "ec.drain"])
-def test_mapped_dispatch_fault_recomputes_from_view(tmp_path, point):
-    """A dispatch or drain fault on a mapped dispatch falls back to the
-    CPU codec, which reads the dispatch's rows from the map: the bytes
-    stay the CPU encoder's and the fallback is counted."""
-    from seaweedfs_tpu.stats import ec_pipeline_metrics
-    from seaweedfs_tpu.utils import faultinject as fi
+PLANES = ["slot", "map", "queue2", "queue4", "rebuild"]
 
+
+@pytest.mark.parametrize("plane", PLANES)
+@pytest.mark.parametrize("point", ["ec.dispatch", "ec.drain"])
+def test_fault_falls_back_per_dispatch(tmp_path, point, plane):
+    """One dispatch or drain fault falls back to the CPU codec for that
+    dispatch alone, on every plane: slots and mapped rows on one device,
+    per-device queues on two and four.  The shards and the .eci equal
+    the CPU encoder's; the fallback is counted once in the call's
+    stats, once in engine_fallbacks{reason} and once in the event
+    journal.  A rebuild has no fault point of its own: "rebuild"
+    repairs the shards such an encode wrote, on the device, with no
+    fallback."""
+    from seaweedfs_tpu.observability.events import get_journal
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+
+    mapped = plane in ("map", "rebuild")
+    large, small = (MAP_LARGE, MAP_SMALL) if mapped else (10_000, 100)
     base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
-    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    ref = _cpu_reference(tmp_path, base, large, small)
     tracer = Tracer(capacity=1 << 12)
-    enc = _device_enc(tracer=tracer)
-    f0 = ec_pipeline_metrics().totals()["engine_fallbacks"]
-    fi.enable(point, error_rate=1.0, max_hits=1)  # the first: mapped
+    devices = {"queue2": "2", "queue4": "4"}.get(plane, "1")
+    enc = StreamingEncoder(10, 4, engine="device", devices=devices,
+                           tracer=tracer)
+    enc.dispatch_b = MAP_B
+    reason = point.split(".")[1] + "_fault"
+    m = ec_pipeline_metrics()
+    f0 = m.engine_fallbacks.value(reason)
+    seen = get_journal().query(limit=1)
+    seq0 = seen[-1]["seq"] if seen else 0
+    fi.enable(point, error_rate=1.0, max_hits=1)
     try:
-        enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+        enc.encode_file(base + ".dat", base, large, small)
+        hits = fi.fired(point)
     finally:
         fi.clear()
     assert _files(base) == _files(ref)
-    assert enc.stats["fallbacks"] == 1
-    assert ec_pipeline_metrics().totals()["engine_fallbacks"] - f0 == 1
-    reason = point.split(".")[1] + "_fault"
+    assert hits == 1
+    assert enc.stats["fallbacks"] == hits
+    assert m.engine_fallbacks.value(reason) - f0 == hits
+    events = get_journal().query(type_="engine_fallback", since_seq=seq0,
+                                 limit=0)
+    assert [(e["details"]["reason"], e["details"]["engine"])
+            for e in events] == [(reason, "device")]
     assert any(s.attrs.get("reason") == reason and s.attrs["dispatch"] == 0
                for s in tracer.snapshot() if s.name == "pipeline.fallback")
-    assert enc.stats["mapped_dispatches"] == 3
+    if plane == "map":
+        assert enc.stats["mapped_dispatches"] == 3
+    if plane == "rebuild":
+        for i in (0, 3, 11, 13):
+            os.unlink(base + to_ext(i))
+        assert enc.rebuild_files(base) == [0, 3, 11, 13]
+        assert enc.stats["fallbacks"] == 0
+        assert _files(base) == _files(ref)
+
+
+@pytest.mark.parametrize("engine", ["host", "auto", "cpu"])
+def test_removed_engine_values_rejected(engine):
+    """The streaming encoder is the device encoder alone: any other
+    engine is refused at construction, naming the CPU path."""
+    with pytest.raises(ValueError, match="ec/encoder.write_ec_files"):
+        StreamingEncoder(10, 4, engine=engine)
 
 
 @pytest.mark.parametrize("lag_s", [0.0, 0.05], ids=["even", "data-lags"])

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import os
 
-import numpy as np
 import pytest
 
 from seaweedfs_tpu.storage.needle import Needle
@@ -103,22 +102,6 @@ def test_net_latency_injection():
     finally:
         fi.clear()
         m.stop()
-
-
-def test_ec_shm_fault_fails_worker_spawn():
-    """Arming `ec.shm` makes parity-worker (re)spawns fail
-    deterministically — the lever the CPU-fallback chaos drills pull.
-    The hit fires in _spawn BEFORE the process starts, so this drill
-    needs no native toolchain: construction surfaces the injected
-    fault (after cleaning up its shared memory) instead of hanging on
-    a worker that never comes up."""
-    from seaweedfs_tpu.ec.overlap import ProcessOverlapWorker
-
-    matrix = np.ones((4, 8), dtype=np.uint8)
-    fi.enable("ec.shm", error_rate=1.0, max_hits=1)
-    with pytest.raises(OSError):
-        ProcessOverlapWorker(8, 4, 1 << 12, matrix, nbufs=2)
-    assert fi.fired("ec.shm") == 1
 
 
 def test_coord_exec_fault_fails_plan_step():

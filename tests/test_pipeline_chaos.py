@@ -1,16 +1,12 @@
 """Chaos drills for the self-healing EC pipeline AND the cluster-level
 rebuild/rebalance coordinator.
 
-Process-level contract (ec/overlap.py supervision + ec/streaming.py
-per-dispatch retry/fallback): a parity worker dying, stalling, or
-faulting mid-encode must NEVER surface as a caller-visible error — the
-supervisor respawns the worker and replays in-flight dispatches, and
-when the restart budget is exhausted the encode degrades per-dispatch to
-the CPU codec and still completes with byte-identical parity.  Faults
-are driven two ways: deterministically through the ec.* fault points
-(utils/faultinject), and with a real SIGKILL of the worker process.
-These drills need the native gf256 engine (overlap workers) and skip
-without it.
+Process-level contract (ec/streaming.py retry-from-checkpoint): a fault
+mid-encode must NEVER surface as a caller-visible error — the encode
+retries, resuming from the last drained-and-written dispatch, and the
+output stays byte-identical.  Per-dispatch fallbacks to the CPU codec
+are drilled in tests/test_ec_streaming.py (test_fault_falls_back_per_
+dispatch).
 
 Cluster-level contract (ops/coordinator.py, TestCoordinatorChaos): with
 the coordinator enabled and NO manual intervention, corrupting shards
@@ -21,61 +17,32 @@ journaled repair events must prove the coordinator reacted to the fired
 alert (alert id + causing trace id on every action), not to a test
 back-channel.  These drills run on the CPU codec everywhere.
 
-Health is observable: SeaweedFS_ec_worker_restarts_total and
-SeaweedFS_ec_engine_fallbacks_total counters, pipeline.retry /
-pipeline.fallback spans, and per-call stats (retries / fallbacks /
-worker_restarts).
+Health is observable: the SeaweedFS_ec_engine_fallbacks_total counter,
+pipeline.retry / pipeline.fallback spans, and per-call stats (retries /
+fallbacks).
 """
 
 from __future__ import annotations
 
 import os
 import signal
-import threading
 import time
 
 import numpy as np
 import pytest
 
 from seaweedfs_tpu.ec import encoder
-from seaweedfs_tpu.ec.codec import CpuEngine, ReedSolomon, best_cpu_engine
+from seaweedfs_tpu.ec.codec import CpuEngine, ReedSolomon
 from seaweedfs_tpu.ec.layout import to_ext
 from seaweedfs_tpu.ec.streaming import StreamingEncoder
 from seaweedfs_tpu.observability import disable_tracing, enable_tracing
-from seaweedfs_tpu.stats import ec_pipeline_metrics
 from seaweedfs_tpu.utils import faultinject as fi
 
-from seaweedfs_tpu import native
-
-# the worker drills need the native engine; the coordinator cluster
-# drills below run everywhere (CPU codec)
-needs_native = pytest.mark.skipif(
-    native.load() is None,
-    reason="native gf256 engine unavailable: no overlap workers")
-
 K, R, TOTAL = 10, 4, 14
-LARGE, SMALL = 100 << 20, 1 << 20  # default small rows for a 64MB volume
-SIZE = 64 << 20  # acceptance floor: streaming encode of >= 64MB
 
 
 def _shards(base: str) -> list[bytes]:
     return [open(base + to_ext(i), "rb").read() for i in range(TOTAL)]
-
-
-@pytest.fixture(scope="module")
-def volume(tmp_path_factory):
-    """One 64MB volume + its single-threaded CPU-codec reference shards,
-    shared by every drill (the encodes under test write elsewhere)."""
-    td = tmp_path_factory.mktemp("chaos")
-    base = str(td / "v")
-    rng = np.random.default_rng(0xC4A05)
-    with open(base + ".dat", "wb") as f:
-        for _ in range(SIZE // (8 << 20)):
-            f.write(rng.integers(0, 256, 8 << 20, dtype=np.uint8).tobytes())
-    encoder.write_ec_files(
-        base, ReedSolomon(K, R, engine=best_cpu_engine()),
-        large_block_size=LARGE, small_block_size=SMALL)
-    return td, base, _shards(base)
 
 
 @pytest.fixture()
@@ -90,213 +57,13 @@ def tracer():
 
 
 def _staged_encoder(**kw) -> StreamingEncoder:
-    enc = StreamingEncoder(K, R, engine="host", overlap="process",
+    """The staged plane: the device engine on one device."""
+    enc = StreamingEncoder(K, R, engine="device", devices="1",
                            dispatch_mb=1, **kw)
+    enc.dispatch_b = 65536
     return enc
 
 
-def _close(enc: StreamingEncoder) -> None:
-    if enc._proc_worker is not None:
-        enc._proc_worker.close()
-        enc._proc_worker = None
-
-
-@needs_native
-def test_ack_fault_respawns_worker_byte_identical(volume, tracer):
-    """ec.worker.ack armed: the supervisor SIGKILLs and respawns the
-    real worker process, replays in-flight dispatches, and the encode
-    completes without caller-visible error, byte-identical."""
-    td, base, ref = volume
-    m = ec_pipeline_metrics()
-    r0 = m.worker_restarts.value("staged")
-    enc = _staged_encoder()
-    out = str(td / "ack")
-    fi.enable("ec.worker.ack", error_rate=1.0, max_hits=2)
-    try:
-        enc.encode_file(base + ".dat", out,
-                        large_block_size=LARGE, small_block_size=SMALL)
-    finally:
-        fi.clear()
-        _close(enc)
-    assert _shards(out) == ref
-    delta = m.worker_restarts.value("staged") - r0
-    assert delta >= 1  # SeaweedFS_ec_worker_restarts_total > 0
-    assert enc.stats["worker_restarts"] >= 1
-    # supervision is visible as pipeline.retry spans, not drain-wait
-    retries = [s for s in tracer.snapshot() if s.name == "pipeline.retry"]
-    assert retries and retries[0].attrs["kind"] == "staged"
-    # and on the Prometheus exposition under the contract name
-    from seaweedfs_tpu.stats import REGISTRY
-
-    assert "SeaweedFS_ec_worker_restarts_total" in REGISTRY.expose()
-
-
-@needs_native
-def test_sigkill_worker_mid_encode_completes(volume):
-    """A real os.kill(SIGKILL) of the parity worker mid-encode: the
-    bounded ack read detects the death, the supervisor respawns and
-    replays, the encode completes byte-identical."""
-    td, base, ref = volume
-    m = ec_pipeline_metrics()
-    r0 = m.worker_restarts.value("staged")
-    enc = _staged_encoder()
-    out = str(td / "kill")
-    err: list = []
-    done = threading.Event()
-
-    def run():
-        try:
-            # drain delay stretches the encode so the kill lands inside:
-            # 7 dispatches at 0.05 s outlast the 0.1 s before the kill
-            # on an idle host too (at 0.01 s an unloaded encode could
-            # finish first, and no restart was ever seen)
-            fi.enable("ec.drain", delay=0.05)
-            enc.encode_file(base + ".dat", out,
-                            large_block_size=LARGE, small_block_size=SMALL)
-        except Exception as e:  # pragma: no cover - the drill's failure
-            err.append(e)
-        finally:
-            fi.clear()
-            done.set()
-
-    t = threading.Thread(target=run)
-    t.start()
-    try:
-        deadline = time.monotonic() + 30
-        pid = 0
-        while time.monotonic() < deadline and not pid:
-            w = enc._proc_worker
-            pid = getattr(w, "worker_pid", 0) if w is not None else 0
-            time.sleep(0.005)
-        assert pid, "worker never came up"
-        time.sleep(0.1)  # let some dispatches get in flight
-        try:
-            os.kill(pid, signal.SIGKILL)
-        except ProcessLookupError:  # pragma: no cover - already respawned
-            pass
-        t.join(180)
-    finally:
-        fi.clear()
-        _close(enc)
-    assert done.is_set() and not err, err
-    assert _shards(out) == ref
-    assert m.worker_restarts.value("staged") - r0 >= 1
-
-
-@needs_native
-def test_budget_exhausted_finishes_via_cpu_fallback(volume, tracer):
-    """Restart budget 0 + one injected ack fault: the worker path gives
-    up immediately and the encode finishes mid-stream on the CPU codec —
-    byte-identical, with SeaweedFS_ec_engine_fallbacks_total > 0."""
-    td, base, ref = volume
-    m = ec_pipeline_metrics()
-    f0 = sum(m.engine_fallbacks.snapshot().values())
-    enc = _staged_encoder(max_worker_restarts=0)
-    out = str(td / "gaveup")
-    fi.enable("ec.worker.ack", error_rate=1.0, max_hits=1)
-    try:
-        enc.encode_file(base + ".dat", out,
-                        large_block_size=LARGE, small_block_size=SMALL)
-    finally:
-        fi.clear()
-        _close(enc)
-    assert _shards(out) == ref
-    assert sum(m.engine_fallbacks.snapshot().values()) - f0 > 0
-    assert enc.stats["fallbacks"] > 0
-    names = {s.name for s in tracer.snapshot()}
-    assert "pipeline.fallback" in names
-    from seaweedfs_tpu.stats import REGISTRY
-
-    assert "SeaweedFS_ec_engine_fallbacks_total" in REGISTRY.expose()
-
-
-@needs_native
-def test_dispatch_and_drain_faults_fall_back_per_dispatch(tmp_path):
-    """One-shot ec.dispatch / ec.drain faults degrade exactly the hit
-    dispatches to the CPU codec; the worker stays alive and keeps the
-    rest of the encode."""
-    base = str(tmp_path / "v")
-    rng = np.random.default_rng(7)
-    open(base + ".dat", "wb").write(
-        rng.integers(0, 256, 3_200_000, dtype=np.uint8).tobytes())
-    encoder.write_ec_files(base, ReedSolomon(K, R, engine=CpuEngine()),
-                           large_block_size=100_000, small_block_size=10_000)
-    ref = _shards(base)
-    enc = _staged_encoder()
-    enc.dispatch_b = 65536
-    out = str(tmp_path / "o")
-    fi.enable("ec.dispatch", error_rate=1.0, max_hits=1)
-    fi.enable("ec.drain", error_rate=1.0, max_hits=1)
-    try:
-        enc.encode_file(base + ".dat", out,
-                        large_block_size=100_000, small_block_size=10_000)
-        alive = enc._proc_worker is not None
-    finally:
-        fi.clear()
-        _close(enc)
-    assert _shards(out) == ref
-    assert enc.stats["fallbacks"] == 2
-    assert alive  # per-dispatch fallback, not whole-pipeline degradation
-
-
-@needs_native
-def test_mmap_worker_sigkill_respawns_and_replays(tmp_path):
-    """The zero-copy mmap path's FileParityWorker: a real SIGKILL mid-
-    encode respawns the worker (which re-opens the input file) and the
-    shards stay byte-identical."""
-    base = str(tmp_path / "v")
-    rng = np.random.default_rng(8)
-    open(base + ".dat", "wb").write(
-        rng.integers(0, 256, 8_000_000, dtype=np.uint8).tobytes())
-    encoder.write_ec_files(base, ReedSolomon(K, R, engine=CpuEngine()),
-                           large_block_size=200_000, small_block_size=20_000)
-    ref = _shards(base)
-    m = ec_pipeline_metrics()
-    r0 = m.worker_restarts.value("mmap")
-    enc = StreamingEncoder(K, R, engine="host", overlap="mmap-process",
-                           dispatch_mb=1, max_worker_restarts=5)
-    enc.dispatch_b = 65536
-    out = str(tmp_path / "o")
-    err: list = []
-    done = threading.Event()
-
-    def run():
-        try:
-            fi.enable("ec.drain", delay=0.01)
-            enc.encode_file(base + ".dat", out,
-                            large_block_size=200_000,
-                            small_block_size=20_000)
-        except Exception as e:  # pragma: no cover
-            err.append(e)
-        finally:
-            fi.clear()
-            done.set()
-
-    t = threading.Thread(target=run)
-    t.start()
-    try:
-        deadline = time.monotonic() + 30
-        pid = 0
-        while time.monotonic() < deadline and not pid:
-            w = enc._file_worker
-            pid = getattr(w, "worker_pid", 0) if w else 0
-            time.sleep(0.005)
-        assert pid, "file worker never came up"
-        time.sleep(0.1)
-        try:
-            os.kill(pid, signal.SIGKILL)
-        except ProcessLookupError:  # pragma: no cover
-            pass
-        t.join(180)
-    finally:
-        fi.clear()
-        enc._drop_file_worker()
-    assert done.is_set() and not err, err
-    assert _shards(out) == ref
-    assert m.worker_restarts.value("mmap") - r0 >= 1
-
-
-@needs_native
 def test_mid_encode_failure_resumes_from_checkpoint(tmp_path, tracer,
                                                     monkeypatch):
     """A fill-phase IO error mid-encode retries the call, RESUMING from
@@ -312,22 +79,24 @@ def test_mid_encode_failure_resumes_from_checkpoint(tmp_path, tracer,
                            large_block_size=1_000_000,
                            small_block_size=10_000)
     ref = _shards(base)
+    # large=1MB keeps every row a small 10_000-byte block (uniform
+    # entries), depth=1 keeps two dispatches in flight
+    enc = _staged_encoder(depth=1)
     real = streaming_mod.preadv_into
     calls = {"n": 0}
 
     def flaky(f, views, off):
         calls["n"] += 1
         if calls["n"] == 15:
+            # the 15th fill (dispatch 2) faults once the writer thread
+            # has retired a dispatch, so the checkpoint is past byte 0
+            deadline = time.monotonic() + 30
+            while enc._ckpt[1] == 0 and time.monotonic() < deadline:
+                time.sleep(0.002)
             raise OSError("injected fill IO error")
         return real(f, views, off)
 
     monkeypatch.setattr(streaming_mod, "preadv_into", flaky)
-    # large=1MB keeps every row a small 10_000-byte block (uniform
-    # entries), depth=1 drains early so the checkpoint has advanced
-    # past byte 0 when the 15th fill (dispatch 2) faults
-    enc = StreamingEncoder(K, R, engine="host", zero_copy=False,
-                           overlap="none", dispatch_mb=1, depth=1)
-    enc.dispatch_b = 65536
     out = str(tmp_path / "o")
     enc.encode_file(base + ".dat", out,
                     large_block_size=1_000_000, small_block_size=10_000)
@@ -339,7 +108,6 @@ def test_mid_encode_failure_resumes_from_checkpoint(tmp_path, tracer,
     assert retries and retries[0].attrs["resume_byte"] > 0
 
 
-@needs_native
 def test_staged_resume_entrypoint_is_byte_exact(tmp_path):
     """The resume machinery itself: corrupt every shard past a dispatch
     boundary, re-enter _encode_file_staged at that checkpoint, and the
@@ -349,9 +117,7 @@ def test_staged_resume_entrypoint_is_byte_exact(tmp_path):
     rng = np.random.default_rng(10)
     open(base + ".dat", "wb").write(
         rng.integers(0, 256, 1_500_000, dtype=np.uint8).tobytes())
-    enc = StreamingEncoder(K, R, engine="host", zero_copy=False,
-                           overlap="none", dispatch_mb=1)
-    enc.dispatch_b = 65536
+    enc = _staged_encoder()
     out = str(tmp_path / "o")
     # large=1MB keeps every plan entry a whole small block: entry e is
     # exactly shard bytes [e*10_000, (e+1)*10_000)
@@ -373,12 +139,11 @@ def test_staged_resume_entrypoint_is_byte_exact(tmp_path):
     assert _shards(out) == ref
 
 
-@needs_native
 def test_async_drain_deep_buffers_byte_identical(tmp_path):
-    """The async multi-buffered drain at depth=4 (5 slots in flight),
-    staged-process AND mmap-process: FIFO writer order must keep shards
-    and the write-order-crc `.eci` sidecar byte-identical to the CPU
-    reference while fetch/write run off the critical thread."""
+    """The async multi-buffered drain at depth=4 (5 slots in flight):
+    FIFO writer order must keep shards and the write-order-crc `.eci`
+    sidecar byte-identical to the CPU reference while fetch/write run
+    off the critical thread."""
     from seaweedfs_tpu.ec.integrity import sidecar_path
 
     base = str(tmp_path / "v")
@@ -389,108 +154,15 @@ def test_async_drain_deep_buffers_byte_identical(tmp_path):
                            large_block_size=200_000, small_block_size=20_000)
     ref = _shards(base)
     ref_eci = open(sidecar_path(base), "rb").read()
-    for name, overlap in (("st", "process"), ("mm", "mmap-process")):
-        enc = StreamingEncoder(K, R, engine="host", overlap=overlap,
-                               dispatch_mb=1, depth=4)
-        enc.dispatch_b = 65536
-        out = str(tmp_path / name)
-        try:
-            enc.encode_file(base + ".dat", out,
-                            large_block_size=200_000,
-                            small_block_size=20_000)
-        finally:
-            _close(enc)
-            enc._drop_file_worker()
-        assert _shards(out) == ref, overlap
-        assert open(sidecar_path(out), "rb").read() == ref_eci, overlap
-        assert enc.stats["drain_pool"] >= 1, overlap
-        assert enc.stats["parity_bytes_drained"] > 0, overlap
-        assert enc.stats["fallbacks"] == 0, overlap
-
-
-@needs_native
-def test_worker_kill_while_drain_queue_full(volume):
-    """SIGKILL the parity worker while the async drain queue is FULL
-    (slow drainer via ec.drain delay keeps every slot in flight): the
-    drainer-side supervisor respawns, replays the whole in-flight
-    window, and the FIFO writer keeps the output byte-identical."""
-    td, base, ref = volume
-    m = ec_pipeline_metrics()
-    r0 = m.worker_restarts.value("staged")
-    enc = _staged_encoder(depth=3, max_worker_restarts=5)
-    out = str(td / "killfull")
-    err: list = []
-    done = threading.Event()
-
-    def run():
-        try:
-            # the throttled drainer keeps every slot in flight, so the
-            # producer is still being paced by slot backpressure (jobs
-            # still outstanding past the worker) when the kill lands
-            fi.enable("ec.drain", delay=0.08)
-            enc.encode_file(base + ".dat", out,
-                            large_block_size=LARGE, small_block_size=SMALL)
-        except Exception as e:  # pragma: no cover - the drill's failure
-            err.append(e)
-        finally:
-            fi.clear()
-            done.set()
-
-    t = threading.Thread(target=run)
-    t.start()
-    try:
-        deadline = time.monotonic() + 30
-        pid = 0
-        while time.monotonic() < deadline and not pid:
-            w = enc._proc_worker
-            pid = getattr(w, "worker_pid", 0) if w is not None else 0
-            time.sleep(0.005)
-        assert pid, "worker never came up"
-        time.sleep(0.12)  # queue full, later submissions still pending
-        try:
-            os.kill(pid, signal.SIGKILL)
-        except ProcessLookupError:  # pragma: no cover - already respawned
-            pass
-        t.join(180)
-    finally:
-        fi.clear()
-        _close(enc)
-    assert done.is_set() and not err, err
+    enc = _staged_encoder(depth=4)
+    out = str(tmp_path / "st")
+    enc.encode_file(base + ".dat", out,
+                    large_block_size=200_000, small_block_size=20_000)
     assert _shards(out) == ref
-    assert m.worker_restarts.value("staged") - r0 >= 1
-
-
-@needs_native
-def test_worker_err_ack_recomputes_without_killing_worker(tmp_path):
-    """A job that fails INSIDE a live worker is acked ("err", seq) and
-    surfaces as WorkerJobError: that dispatch recomputes serially, the
-    worker survives, no respawn is burned."""
-    from seaweedfs_tpu.ec.overlap import FileParityWorker, WorkerJobError
-
-    rs = ReedSolomon(K, R)
-    w = FileParityWorker(K, R, 4096, rs.matrix[K:], nbufs=2,
-                         restart_backoff=0.01)
-    try:
-        p = str(tmp_path / "in.bin")
-        rng = np.random.default_rng(11)
-        open(p, "wb").write(
-            rng.integers(0, 256, K * 4096, dtype=np.uint8).tobytes())
-        w.open(p)
-        # a poisoned job payload: the worker's slot arithmetic raises a
-        # Python-level error -> job-level err ack, not process death
-        w.submit("poison", 0, 4096, 4096)
-        with pytest.raises(WorkerJobError):
-            w.fetch(0)
-        # the SAME worker incarnation keeps serving
-        pid = w.worker_pid
-        w.submit(1, 0, 4096, 4096)
-        parity = w.fetch(1)
-        data = np.fromfile(p, dtype=np.uint8).reshape(K, 4096)
-        want = CpuEngine().matmul(np.ascontiguousarray(rs.matrix[K:]), data)
-        assert np.array_equal(parity, want)
-        assert w.worker_pid == pid and w.restarts == 0
-    finally:
-        w.close()
+    assert open(sidecar_path(out), "rb").read() == ref_eci
+    assert enc.stats["drain_pool"] >= 1
+    assert enc.stats["parity_bytes_drained"] > 0
+    assert enc.stats["fallbacks"] == 0
 
 
 # --- cluster-level coordinator chaos drills --------------------------------

@@ -1,9 +1,10 @@
-"""End-to-end tracing: EC pipeline spans, server endpoints, bench hook.
+"""End-to-end tracing: EC pipeline spans and server endpoints.
 
-Pins the PR's acceptance bar: a CPU-only traced streaming encode yields
-per-dispatch fill/dispatch/write/drain spans whose sum explains the
-pipeline's wall clock, the same latencies are scrapeable from /metrics,
-and /debug/traces serves the ring as Chrome trace JSON on a live server.
+Pins the acceptance bar: a CPU-only traced streaming encode yields
+per-dispatch fill/dispatch/write/drain spans, the producer thread's
+spans explain the pipeline's wall clock, the same latencies are
+scrapeable from /metrics, and /debug/traces serves the ring as Chrome
+trace JSON on a live server.
 """
 
 from __future__ import annotations
@@ -30,10 +31,9 @@ def _write_dat(tmp_path, size, name="v"):
 
 
 def _staged_encoder(tracer, dispatch_b=65536):
-    """Serial staged host pipeline: stages never overlap, so their span
-    sum must reproduce the wall clock."""
-    enc = StreamingEncoder(10, 4, engine="host", zero_copy=False,
-                           overlap="none", tracer=tracer)
+    """The staged plane, the device engine on one device."""
+    enc = StreamingEncoder(10, 4, engine="device", devices="1",
+                           tracer=tracer)
     enc.dispatch_b = dispatch_b
     return enc
 
@@ -67,17 +67,18 @@ class TestPipelineSpans:
             assert fill.attrs["bytes"] > 0
 
     def test_span_sum_explains_wall_within_10pct(self, tmp_path):
-        """Acceptance: per-dispatch fill/dispatch/write/drain spans sum
-        to within 10% of the pipeline's reported wall_s on a CPU-only
-        serial run (stages are disjoint, so the sum IS the wall minus
-        setup/teardown).
+        """Acceptance: the producer thread's spans — per-dispatch fill,
+        dispatch and data write, and the drain waits — sum to within
+        10% of the pipeline's reported wall_s on a CPU-only run (they
+        are disjoint on that thread, so the sum IS the wall minus
+        setup/teardown); the drain and parity write spans land on the
+        drainer's threads.
 
         Measured in a FRESH SUBPROCESS on tmpfs: late in a full suite
         run this pytest process carries dozens of lingering daemon
         threads (servers, heartbeat loops) whose GIL contention lands
         wall time BETWEEN spans and un-attributes time that has nothing
-        to do with the tracer — the same isolation bench.py uses for
-        its own measurements."""
+        to do with the tracer."""
         import subprocess
         import sys
 
@@ -95,8 +96,8 @@ try:
     dat.write_bytes(np.random.default_rng(5).integers(
         0, 256, size, dtype=np.uint8).tobytes())
     tracer = Tracer(capacity=1 << 15)
-    enc = StreamingEncoder(10, 4, engine="host", zero_copy=False,
-                           overlap="none", tracer=tracer)
+    enc = StreamingEncoder(10, 4, engine="device", devices="1",
+                           tracer=tracer)
     enc.dispatch_b = 2 << 20
     enc.encode_file(str(dat), str(workdir / "warm"))  # warm cache
     best = None
@@ -105,16 +106,23 @@ try:
         enc.encode_file(str(dat), str(workdir / ("cold%d" % i)))
         wall = enc.stats["wall_s"]
         spans = tracer.snapshot()
+        producer = next(sp.tid for sp in spans
+                        if sp.name == "pipeline.encode_file")
         by_stage = {}
+        on_producer = 0.0
         for sp in spans:
             if sp.name.startswith("pipeline.") \
                     and sp.attrs.get("dispatch") is not None:
                 st = sp.name.split(".", 1)[1]
                 by_stage[st] = by_stage.get(st, 0.0) + sp.duration
-        counted = sum(by_stage.values()) + sum(
+                if sp.tid == producer:
+                    on_producer += sp.duration
+            elif sp.name == "pipeline.drain_wait":
+                on_producer += sp.duration
+        counted = on_producer + sum(
             sp.duration for sp in spans
             if sp.name in ("pipeline.setup", "pipeline.close"))
-        res = {"ratio": sum(by_stage.values()) / wall,
+        res = {"ratio": on_producer / wall,
                "counted_ratio": counted / wall,
                "by_stage": by_stage,
                "stages": sorted(by_stage),
@@ -167,50 +175,6 @@ finally:
         per_span = (time.perf_counter() - t0) / 20_000
         assert n_spans * per_span < 0.02 * wall, \
             f"{n_spans} spans x {per_span * 1e6:.2f}us vs wall {wall:.4f}s"
-
-    def test_mmap_path_emits_compute_spans(self, tmp_path):
-        from seaweedfs_tpu import native
-
-        if native.load() is None:
-            pytest.skip("no native toolchain")
-        tracer = Tracer(capacity=8192)
-        base = _write_dat(tmp_path, 1 << 20, name="mm")
-        enc = StreamingEncoder(10, 4, engine="host", overlap="none",
-                               tracer=tracer)
-        enc.dispatch_b = 65536
-        enc.encode_file(base + ".dat", base)
-        names = {s.name for s in tracer.snapshot()}
-        assert "pipeline.encode_file" in names
-        assert "pipeline.compute" in names
-        assert "pipeline.write" in names
-
-    def test_worker_process_spans_merge_on_drain(self, tmp_path):
-        """overlap="process": the worker's compute windows ride its acks
-        and land as worker.compute spans parented under the pipeline
-        root — the cross-process half of the timeline."""
-        from seaweedfs_tpu import native
-
-        if native.load() is None:
-            pytest.skip("no native toolchain")
-        tracer = Tracer(capacity=8192)
-        base = _write_dat(tmp_path, 300_000, name="pw")
-        enc = StreamingEncoder(10, 4, engine="host", overlap="process",
-                               tracer=tracer)
-        enc.dispatch_b = 8192
-        try:
-            enc.encode_file(base + ".dat", base,
-                            large_block_size=10_000, small_block_size=100)
-        finally:
-            if enc._proc_worker is not None:
-                enc._proc_worker.close()
-        spans = tracer.snapshot()
-        workers = [s for s in spans if s.name == "worker.compute"]
-        assert len(workers) == enc.stats["dispatches"]
-        root = next(s for s in spans if s.name == "pipeline.encode_file")
-        assert all(w.parent_id == root.span_id for w in workers)
-        assert all(w.attrs["worker_pid"] for w in workers)
-        dispatches = sorted(w.attrs["dispatch"] for w in workers)
-        assert dispatches == list(range(enc.stats["dispatches"]))
 
     def test_rebuild_spans(self, tmp_path):
         from seaweedfs_tpu.ec.layout import to_ext
@@ -315,26 +279,3 @@ class TestServerEndpoints:
         for stage in STAGES:
             assert f'SeaweedFS_trace_span_seconds_count{{' \
                    f'name="pipeline.{stage}"}}' in text
-
-
-class TestBenchHook:
-    def test_trace_smoke_writes_chrome_trace_and_summary(self, tmp_path):
-        """bench.py --trace-out in miniature: a tiny CPU traced encode
-        produces the Chrome file and the per-dispatch summary that rides
-        BENCH_*.json."""
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-
-        out = str(tmp_path / "trace.json")
-        mbps, pipe = bench.trace_smoke(trace_out=out, size_mb=2,
-                                       base_dir=str(tmp_path))
-        assert mbps > 0
-        spans = pipe["spans"]
-        assert spans["dispatches"] == pipe["dispatches"]
-        assert set(spans["stage_totals_s"]) >= {"fill", "dispatch", "write"}
-        assert spans["per_dispatch_s"][0]["d"] == 0
-        doc = json.loads(open(out).read())
-        assert [e for e in doc["traceEvents"] if e.get("ph") == "X"]

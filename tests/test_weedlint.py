@@ -50,8 +50,6 @@ from tools.weedlint.rules_resources import \
     check_module_source as check_resources  # noqa: E402
 from tools.weedlint.rules_routes import \
     check_module_source as check_routes  # noqa: E402
-from tools.weedlint.rules_bench import \
-    check_source as check_bench_caps  # noqa: E402
 from tools.weedlint.rules_eventloop import check_eventloop  # noqa: E402
 from tools.weedlint.rules_leader import \
     check_source as check_leader_gated  # noqa: E402
@@ -223,18 +221,6 @@ W902_BAD = (
     "        self.raft.append('event', {'events': []})\n"
     "        self.alert_engine.import_state({})\n")
 
-W1001_CLEAN = (
-    "SECTION_CAPS = {'alpha': 60, 'beta': 120}\n"
-    "def run():\n"
-    "    section('alpha', lambda: None)\n"
-    "    cap = SECTION_CAPS.get('beta', 300)\n")
-W1001_BAD = (
-    "SECTION_CAPS = {'alpha': 60}\n"
-    "def run():\n"
-    "    section('alpha', lambda: None)\n"
-    "    section('gamma', lambda: None)\n"
-    "    cap = SECTION_CAPS.get('delta', 300)\n")
-
 CASES = [
     ("W101", "x = 1\n", "import tomllib\n",
      lambda src: rules_py310.check_source(src, "t.py")),
@@ -260,8 +246,6 @@ CASES = [
      lambda src: check_timeouts(src, "t.py")),
     ("W902", W902_CLEAN, W902_BAD,
      lambda src: check_leader_gated(src, "seaweedfs_tpu/master/t.py")),
-    ("W1001", W1001_CLEAN, W1001_BAD,
-     lambda src: check_bench_caps(src, "bench.py")),
 ]
 
 

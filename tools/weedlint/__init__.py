@@ -24,7 +24,6 @@ Rules (see README "Static analysis" for the full table):
     W701  fault-point registry consistency + test cover   [new]
     W801  ec/ resource acquire without release-on-all-paths [new]
     W901  outbound calls must carry an explicit timeout
-    W1001 bench.py sections must have SECTION_CAPS entries
 
 Waive a finding inline with a reason:
 

@@ -269,8 +269,8 @@ def _load_builtin_rules() -> None:
     if _loaded:
         return
     _loaded = True
-    from . import (rules_async_drain, rules_bench,  # noqa: F401
-                   rules_blocking, rules_eventloop, rules_faults,
+    from . import (rules_async_drain, rules_blocking,  # noqa: F401
+                   rules_eventloop, rules_faults,
                    rules_health_keys, rules_leader, rules_ledger,
                    rules_lockorder, rules_lockset, rules_py310,
                    rules_resources, rules_routes, rules_timeouts,

@@ -5,9 +5,9 @@ buffered drain only pays off while nothing reintroduces a blocking
 full-block fetch on the critical thread — a regression that stays
 byte-correct and therefore invisible to every differential test:
 
-  1. `_encode_file_staged`, `_encode_file_mmap` and `_encode_file_mesh`
-     must each construct the AsyncDrainer (directly, or as per-device
-     lanes through a DrainerGroup).
+  1. `_encode_file_staged` and `_encode_file_mesh` must each construct
+     the AsyncDrainer (directly, or as per-device lanes through a
+     DrainerGroup).
   2. Inside them, blocking-fetch calls (`_fetch`, `fetch`, `asarray`,
      `device_get`, `block_until_ready`) may appear ONLY within nested
      drain helpers (functions named `drain*`) — including the
@@ -26,8 +26,7 @@ from .engine import Finding, Repo, Rule, register
 
 PACKAGE = "seaweedfs_tpu"
 STREAMING_REL = os.path.join(PACKAGE, "ec", "streaming.py")
-HOT_FUNCS = ("_encode_file_staged", "_encode_file_mmap",
-             "_encode_file_mesh")
+HOT_FUNCS = ("_encode_file_staged", "_encode_file_mesh")
 DRAINER_CTORS = {"AsyncDrainer", "DrainerGroup"}
 BLOCKING_CALLS = {"_fetch", "fetch", "asarray", "device_get",
                   "block_until_ready"}
