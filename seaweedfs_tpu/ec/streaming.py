@@ -14,6 +14,8 @@ runs a depth-N asynchronous pipeline over fixed-shape dispatches:
     rows needs no fill: it goes up as a [rows, K, block] view of the
     read-only mapped `.dat`, which the kernel reads in file order
     (ops/gf_matmul.py) and whose parity comes back as a slot's would;
+    a rebuild's whole chunk goes up the same way, as each survivor
+    shard's own span of its map, stacked to [K, W] on the device;
   - every dispatch has the SAME width, so XLA compiles one kernel per
     input layout (tail dispatches are zero-padded, and parity-of-zeros
     is zeros, which is simply not written out);
@@ -50,6 +52,7 @@ import queue as queue_mod
 import sys
 import threading
 import time
+import traceback
 from collections import OrderedDict, deque
 from typing import Iterator, Optional
 
@@ -107,10 +110,12 @@ def _plan_entries(file_size: int, k: int, large: int, small: int,
         start += small * k
 
 
-class _DatMap:
-    """The `.dat` mapped read-only for one device encode attempt, so a
-    dispatch of whole small-block rows goes to the device as a view of
-    the file's own bytes, [rows, K, block] in file order, with no fill.
+class _FileMap:
+    """A file mapped read-only for one device encode or rebuild attempt,
+    so a dispatch goes to the device as a view of the file's own bytes
+    with no fill: the `.dat`'s whole small-block rows, [rows, K, block]
+    in file order (rows()), or one survivor shard's span of a rebuild
+    chunk (span()).
 
     The device transfer may alias a view (zero-copy host buffers, always
     on the CPU backend), so the map closes only once every dispatch that
@@ -143,6 +148,17 @@ class _DatMap:
         except (OSError, ValueError):
             pass
         return self.arr[start:start + n].reshape(g, k, block)
+
+    def span(self, offset: int, n: int) -> np.ndarray:
+        """Bytes [offset, offset + n) as [n] u8, pre-faulted as rows()
+        pre-faults its rows (from the page the span starts in)."""
+        lo = offset - offset % self._mod.PAGESIZE
+        try:
+            self._mm.madvise(getattr(self._mod, "MADV_POPULATE_READ", 22),
+                             lo, offset + n - lo)
+        except (OSError, ValueError):
+            pass
+        return self.arr[offset:offset + n]
 
     def close(self) -> bool:
         """Unmap unless a view still lives; True once unmapped."""
@@ -320,8 +336,21 @@ class StreamingEncoder:
         out.copy_to_host_async()
         return out
 
+    def _dispatch_survivors(self, planes, views: list):
+        """Async: a rebuild's whole chunk as the K chosen survivors' own
+        [dispatch_b] u8 spans of their maps, put on the slice's first
+        device as K [dispatch_b//4] u32 rows, which the jitted kernel
+        stacks on the device into the [K, dispatch_b//4] words a slot
+        would give (ops/gf_matmul.py); comes back as _dispatch's
+        does."""
+        rows = self._jax.device_put([v.view(np.uint32) for v in views],
+                                    self._devs[0])
+        out = self._kernel(planes, tuple(rows))
+        out.copy_to_host_async()
+        return out
+
     def _map_dat(self, dat_path: str, file_size: int, large: int,
-                 small: int) -> Optional[_DatMap]:
+                 small: int) -> Optional[_FileMap]:
         """The `.dat` mapped for row-group dispatches, or None where no
         dispatch can be one: a volume shorter than one group, or blocks
         that do not divide the dispatch."""
@@ -330,10 +359,10 @@ class StreamingEncoder:
                 blk and b % blk == 0 and blk % self._row_q == 0
                 for blk in (large, small)):
             return None
-        return _DatMap(dat_path, file_size)
+        return _FileMap(dat_path, file_size)
 
     def _row_group(self, fills: list, used: int,
-                   dat_map: Optional[_DatMap]) -> Optional[np.ndarray]:
+                   dat_map: Optional[_FileMap]) -> Optional[np.ndarray]:
         """A packed dispatch as one [rows, K, block] view of the mapped
         `.dat`, where its entries are dispatch_b // block consecutive
         whole rows of one block size, all inside the file; else None,
@@ -374,9 +403,9 @@ class StreamingEncoder:
                 buf[:, used:] = 0
 
     def _count_dispatch(self, st: dict, mapped: bool) -> None:
-        """One dispatch of the staged or queue plane, in the call's
-        stats and the process counters /status reports (EcPipeline
-        `dispatches`, `mapped_dispatches`)."""
+        """One dispatch of an encode (either plane) or a rebuild, in the
+        call's stats and the process counters /status reports
+        (EcPipeline `dispatches`, `mapped_dispatches`)."""
         from ..stats import ec_pipeline_metrics
 
         st["dispatches"] += 1
@@ -425,7 +454,7 @@ class StreamingEncoder:
         with self._st_lock:
             self._slot_bufs = slots
 
-    def _close_map(self, dat_map: Optional[_DatMap]) -> None:
+    def _close_map(self, dat_map: Optional[_FileMap]) -> None:
         """Close an attempt's map, or leave it to _reap_stale_maps
         while a view lives (a transfer not yet released, a fault's
         traceback)."""
@@ -451,8 +480,9 @@ class StreamingEncoder:
         # alias under _st_lock, never rebind the attribute
         self.stats = {  # weedlint: disable=W502 rebound before the drain threads exist
                       "dispatches": 0, "wall_s": 0.0, "bytes_in": 0,
-                      # dispatches sent as views of the mapped .dat
-                      # (_row_group), no fill
+                      # dispatches sent as views of a mapped file (the
+                      # .dat's rows, _row_group; a rebuild's survivor
+                      # spans), no fill
                       "mapped_dispatches": 0,
                       "retries": 0, "fallbacks": 0, "worker_restarts": 0,
                       # integrity accounting: sidecar_s = crc build time
@@ -507,7 +537,7 @@ class StreamingEncoder:
         # the encode's flush/drain closures form reference cycles that
         # keep views of the map alive past the call's return; collect
         # them now so close() can actually release it (rare path: only
-        # runs after a failed attempt)
+        # runs after a failed attempt or a view outliving its fetch)
         import gc
 
         gc.collect()
@@ -1239,14 +1269,25 @@ class StreamingEncoder:
         fewer than data_shards clean shards."""
         sidecar = EciSidecar.load(base_file_name)
         demoted: set[int] = set()
-        while True:
-            try:
-                return self._rebuild_files_once(base_file_name, sidecar,
-                                                demoted)
-            except CorruptSurvivor as e:
-                demoted.add(e.shard_id)
-                note_corruption("rebuild", e.shard_id, base_file_name,
-                                block=e.block, tracer=self._tracer())
+        try:
+            while True:
+                try:
+                    return self._rebuild_files_once(base_file_name, sidecar,
+                                                    demoted)
+                except CorruptSurvivor as e:
+                    demoted.add(e.shard_id)
+                    note_corruption("rebuild", e.shard_id, base_file_name,
+                                    block=e.block, tracer=self._tracer())
+                self._reap_stale_maps()  # the attempt's views unwound
+        finally:
+            tb = sys.exc_info()[2]
+            if tb is not None:
+                # the traceback pins the failed attempt's frames, and the
+                # views of its maps they hold (on the CPU backend a
+                # dispatch's inputs alias them): drop their locals, so
+                # the maps close before the call unwinds
+                traceback.clear_frames(tb)
+            self._reap_stale_maps()
 
     def _rebuild_files_once(self, base_file_name: str,
                             sidecar: Optional[EciSidecar],
@@ -1326,9 +1367,10 @@ class StreamingEncoder:
             sidecar = None  # stale sidecar: unverifiable, not rot
         outputs = {m: open(base_file_name + to_ext(m), "wb")
                    for m in missing}
-        bufs = [np.zeros((k, b), dtype=np.uint8)
-                for _ in range(self.depth + 1)]
-        free: deque[int] = deque(range(len(bufs)))
+        # a whole chunk goes up as each survivor's own span of its map,
+        # no fill; the tail, shorter than a dispatch, is filled into one
+        # zero-padded slot, the kernel's one 2-D shape
+        maps: dict[int, _FileMap] = {}
         pending: deque[tuple[object, int, int]] = deque()
 
         st = self._reset_stats()
@@ -1341,37 +1383,41 @@ class StreamingEncoder:
         root.__enter__()
 
         def drain_one():
-            out_dev, n, bi, d_idx = pending.popleft()
+            out_dev, n, d_idx = pending.popleft()
             with tr.span("pipeline.drain", dispatch=d_idx):
                 out = self._fetch(out_dev)
             with tr.span("pipeline.write", dispatch=d_idx, kind="rebuilt"):
                 for row_i, m in enumerate(missing):
                     outputs[m].write(out[row_i, :n])
-            free.append(bi)
 
         ok = False
         try:
+            if shard_size >= b:
+                for s in survivors:
+                    maps[s] = _FileMap(base_file_name + to_ext(s),
+                                       shard_size)
             for offset in range(0, shard_size, b):
                 n = min(b, shard_size - offset)
-                if not free:
-                    drain_one()
-                bi = free.popleft()
-                buf = bufs[bi]
+                mapped = n == b
                 d_idx = st["dispatches"]
-                with tr.span("pipeline.fill", dispatch=d_idx,
-                             bytes=len(survivors) * n):
-                    for row_i, s in enumerate(survivors):
-                        preadv_into(inputs[s], [buf[row_i, :n]], offset)
-                    if n < b:
-                        buf[:, n:] = 0
+                if mapped:
+                    views = [maps[s].span(offset, b) for s in survivors]
+                else:
+                    slot = np.zeros((k, b), dtype=np.uint8)
+                    with tr.span("pipeline.fill", dispatch=d_idx,
+                                 bytes=k * n):
+                        for row_i, s in enumerate(survivors):
+                            preadv_into(inputs[s], [slot[row_i, :n]], offset)
+                    views = list(slot)
                 if sidecar is not None:
-                    # verify before dispatch: corrupt bytes must never
-                    # reach the reconstruction matmul (the raised
-                    # CorruptSurvivor aborts the attempt; the caller
-                    # demotes and retries with an alternate survivor)
+                    # verify before dispatch, on the very bytes that go
+                    # up: corrupt bytes must never reach the
+                    # reconstruction matmul (the raised CorruptSurvivor
+                    # aborts the attempt; the caller demotes and retries
+                    # with an alternate survivor)
                     t0 = clock()
-                    for row_i, s in enumerate(survivors):
-                        raw = buf[row_i, :n]
+                    for s, raw in zip(survivors, views):
+                        raw = raw[:n]
                         if faultinject._points:
                             raw = faultinject.corrupt_block(
                                 "ec.shard.corrupt", s, raw, offset)
@@ -1380,17 +1426,24 @@ class StreamingEncoder:
                             raise CorruptSurvivor(s, bad)
                     st["verify_s"] += clock() - t0
                 with tr.span("pipeline.dispatch", dispatch=d_idx,
-                             bytes=len(survivors) * n):
-                    pending.append((self._dispatch(planes, buf), n, bi,
-                                    d_idx))
-                st["dispatches"] += 1
-                st["bytes_in"] += len(survivors) * n
+                             bytes=k * n, source="map" if mapped else "slot"):
+                    pending.append((
+                        self._dispatch_survivors(planes, views) if mapped
+                        else self._dispatch(planes, slot), n, d_idx))
+                self._count_dispatch(st, mapped)
+                st["bytes_in"] += k * n
                 if len(pending) > self.depth:
                     drain_one()
             while pending:
                 drain_one()
             ok = True
         finally:
+            # drop every view this frame holds, so a map whose dispatches
+            # were all fetched closes now (_close_map keeps the others)
+            pending.clear()
+            views = raw = slot = None
+            for m in maps.values():
+                self._close_map(m)
             for f in inputs.values():
                 f.close()
             for f in outputs.values():

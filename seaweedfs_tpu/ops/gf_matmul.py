@@ -26,7 +26,9 @@ reconstructs of any width (TpuEngine).  Words (`*_packed`): data
 [K, B//4] u32 -> [R, B//4] u32, the streaming pipeline's dispatches; or
 [G, K, W] u32 -> [R, G*W] u32, G whole rows of a `.dat` as the file lays
 them out (row g's K blocks of W words back to back), whose parity comes
-back in the 2-D layout of the same columns packed row after row.
+back in the 2-D layout of the same columns packed row after row; or a
+tuple of K [B//4] u32 rows, one a rebuild survivor, stacked to [K, B//4]
+inside the jitted dispatch.
 Byte q of word j is column 4j + q, as a little-endian host's
 ``.view(np.uint32)`` / ``.view(np.uint8)`` sees it, and the matmul is
 independent per column, so the word path runs the bit-plane matmul once
@@ -122,7 +124,10 @@ def gf_matmul_xla(a_planes: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
 def gf_matmul_xla_packed(a_planes: jnp.ndarray,
                          words: jnp.ndarray) -> jnp.ndarray:
     """gf_matmul_xla in the word layout: words [K, B//4] u32 -> [R, B//4]
-    u32, or [G, K, W] u32 rows -> [R, G*W] u32."""
+    u32, or [G, K, W] u32 rows -> [R, G*W] u32, or a tuple of K [B//4]
+    u32 rows, stacked here -> [R, B//4] u32."""
+    if isinstance(words, tuple):
+        words = jnp.stack(words)
     if words.ndim == 3:
         words = words.transpose(1, 0, 2).reshape(words.shape[1], -1)
     return _gf_words(a_planes, words)
@@ -201,7 +206,11 @@ def gf_matmul_pallas_packed(a_planes: jnp.ndarray, words: jnp.ndarray,
                             interpret: bool = False) -> jnp.ndarray:
     """gf_matmul_pallas in the word layout: words [K, B//4] u32 ->
     [R, B//4] u32, or [G, K, W] u32 rows -> [R, G*W] u32, the whole
-    dispatch one kernel."""
+    dispatch one kernel; or a tuple of K [B//4] u32 rows (a rebuild's
+    survivors, each put from its own map), stacked on the device into
+    the [K, B//4] words the kernel reads."""
+    if isinstance(words, tuple):
+        words = jnp.stack(words)
     return gf_matmul_pallas(a_planes, words, tile_b=tile_b,
                             interpret=interpret, words=True)
 

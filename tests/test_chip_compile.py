@@ -100,6 +100,55 @@ def test_pallas_packed_takes_mapped_rows(one_chip):
     assert "gather" not in text
 
 
+def test_pallas_packed_takes_survivor_rows(one_chip):
+    """A rebuild's whole chunk: K [8 MiB/4] u32 rows, each put from its
+    survivor shard's map, as a tuple: the module keeps the name the
+    benchmark finds it by, stacks the rows with one concatenate into
+    the [K, W] words a slot gives, and its ROOT is the one Pallas
+    kernel, named as ever, with no gather."""
+    from seaweedfs_tpu.ops.gf_matmul import gf_matmul_pallas_packed
+
+    b = (8 << 20) // TILE * TILE
+    rows = tuple(_words((b // 4,), one_chip) for _ in range(K))
+    text = gf_matmul_pallas_packed.lower(
+        _spec((8, 8 * K), one_chip), rows, tile_b=TILE).compile().as_text()
+    assert re.search(r"^HloModule jit_gf_matmul_pallas_packed\b", text,
+                     re.M)
+    cats = [ln for ln in text.splitlines() if " concatenate(" in ln]
+    assert len(cats) == 1 and f"u32[{K},{b // 4}]" in cats[0], cats
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*ROOT %gf_matmul_pallas(\.\d+)? = "
+                    rf"u32\[1,{b // 4}\]", calls[0]), calls[0]
+    assert "gather" not in text
+
+
+# sha256 of the lowered 2-D word dispatch at the streaming width, the
+# Mosaic kernel's serialized body masked (it carries source paths and
+# lines), as it was before the survivors' tuple came in: slot
+# dispatches and the queue plane lower exactly as they did
+_LOWERED_2D = {
+    4: "f17882fa3b7f5323670cc9dd40d8c5b1d3a4a0df959f22a944005fa1dcb5e44b",
+    1: "42fc002d98b553354c21c86a7aab049eb7f29ff328fe83b7bc3cdf0dd32ef76d",
+}
+
+
+@pytest.mark.parametrize("rows", sorted(_LOWERED_2D))
+def test_pallas_packed_2d_lowering_unchanged(one_chip, rows):
+    import hashlib
+
+    from seaweedfs_tpu.ops.gf_matmul import gf_matmul_pallas_packed
+
+    b = (8 << 20) // TILE * TILE
+    text = gf_matmul_pallas_packed.lower(
+        _spec((8 * rows, 8 * K), one_chip), _words((K, b // 4), one_chip),
+        tile_b=TILE).as_text()
+    masked, n = re.subn(r'\\22body\\22: \\22[A-Za-z0-9+/=]*\\22',
+                        "BODY", text)
+    assert n == 1
+    assert hashlib.sha256(masked.encode()).hexdigest() == _LOWERED_2D[rows]
+
+
 @pytest.mark.parametrize("rows", [4, 1])
 def test_pallas_reconstruct_rows(one_chip, rows):
     """TpuEngine's kernel for a 4-row (parity / 4-shard rebuild) and a

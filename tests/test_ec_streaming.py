@@ -543,3 +543,142 @@ def test_volume_geometry_maps_twelve_of_thirteen(tmp_path):
     assert (t1["dispatches"] - t0["dispatches"],
             t1["mapped_dispatches"] - t0["mapped_dispatches"]) == (13, 12)
 
+
+
+# --- rebuilds dispatched from the survivors' maps ---
+# 1 KiB rows on a 4 KiB dispatch: a shard grows 1 KiB for every 10 KiB
+# of .dat; (dat bytes, dispatches, of them mapped)
+REBUILD_SIZES = {
+    "whole": (12 * ROW - 77, 3, 3),       # 12 KiB shards: whole chunks
+    "tail": (13 * ROW - 5, 4, 3),         # 13 KiB: and a 1 KiB tail
+    "under": (2 * ROW - 3, 1, 0),         # 2 KiB: the tail alone
+}
+
+
+def _encoded(tmp_path, size, name="r"):
+    """A volume encoded by the CPU path, its .eci at 1 KiB blocks, so
+    every 4 KiB rebuild chunk is verified inline."""
+    base = _write_dat(tmp_path, size, name=name)
+    encoder.write_ec_files(base, ReedSolomon(10, 4), MAP_LARGE, MAP_SMALL,
+                           sidecar_block_size=MAP_SMALL)
+    return base
+
+
+def _assert_released(enc, base):
+    """No survivor map, no descriptor of a shard, and no map left for
+    the encoder to reap."""
+    assert enc._stale_maps == []
+    shards = {os.path.realpath(base + to_ext(i)) for i in range(14)}
+    with open("/proc/self/maps") as f:
+        maps = f.read()
+    assert not [p for p in shards if p in maps]
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            held.add(os.readlink(f"/proc/self/fd/{fd}"))
+        except OSError:
+            pass
+    assert not shards & held
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("kill", [[3], [0, 3, 11, 13]], ids=["one", "four"])
+@pytest.mark.parametrize("case", sorted(REBUILD_SIZES))
+def test_mapped_rebuild_byte_identical(tmp_path, case, kill, kernel):
+    """Whole chunks go up as each survivor's span of its map, a tail
+    through the one slot: the rebuilt shards equal the original ones
+    and the CPU path's rebuild, each dispatch says where its input came
+    from and is counted where /status reads EcPipeline, and the inline
+    verify ran on the mapped bytes."""
+    import shutil
+
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+
+    size, dispatches, mapped = REBUILD_SIZES[case]
+    base = _encoded(tmp_path, size)
+    want = _files(base)
+    assert len(want[0]) == (size + ROW - 1) // ROW * MAP_SMALL
+    ref = str(tmp_path / "ref")
+    for ext in [to_ext(i) for i in range(14)] + [".eci"]:
+        shutil.copy(base + ext, ref + ext)
+    for i in kill:
+        os.unlink(base + to_ext(i))
+        os.unlink(ref + to_ext(i))
+    encoder.rebuild_ec_files(ref, ReedSolomon(10, 4), chunk=MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = _device_enc(kernel, tracer=tracer)
+    t0 = ec_pipeline_metrics().totals()
+    assert enc.rebuild_files(base) == kill
+    t1 = ec_pipeline_metrics().totals()
+    assert _files(base) == want == _files(ref)
+    assert (enc.stats["dispatches"], enc.stats["mapped_dispatches"]) == \
+        (dispatches, mapped)
+    assert (t1["dispatches"] - t0["dispatches"],
+            t1["mapped_dispatches"] - t0["mapped_dispatches"]) == \
+        (dispatches, mapped)
+    sources = [s.attrs["source"] for s in tracer.snapshot()
+               if s.name == "pipeline.dispatch"]
+    assert sources == ["map"] * mapped + ["slot"] * (dispatches - mapped)
+    fills = [s for s in tracer.snapshot() if s.name == "pipeline.fill"]
+    assert len(fills) == dispatches - mapped
+    assert enc.stats["verify_s"] > 0
+    _assert_released(enc, base)
+
+
+def test_mapped_rebuild_demotes_corrupt_survivor(tmp_path):
+    """A survivor whose mapped chunk fails its crc is demoted before
+    that chunk is dispatched (the first attempt sends chunks 0 and 1
+    only), and the retry regenerates it and the lost shard byte for
+    byte, with every map closed after either attempt."""
+    base = _encoded(tmp_path, REBUILD_SIZES["tail"][0])
+    want = _files(base)
+    os.unlink(base + to_ext(3))
+    tracer = Tracer(capacity=1 << 12)
+    enc = _device_enc(tracer=tracer)
+    fi.enable("ec.shard.corrupt",
+              params={"shard": 5, "offset": 2 * MAP_B + 100, "bit": 3},
+              max_hits=1)
+    try:
+        got = enc.rebuild_files(base)
+        hits = fi.fired("ec.shard.corrupt")
+    finally:
+        fi.clear()
+    assert hits == 1
+    assert got == [3, 5]
+    assert _files(base) == want
+    assert [s.attrs["dispatch"] for s in tracer.snapshot()
+            if s.name == "pipeline.dispatch"] == [0, 1, 0, 1, 2, 3]
+    assert enc.stats["mapped_dispatches"] == 3
+    _assert_released(enc, base)
+
+
+@pytest.mark.parametrize("at", [1, 3], ids=["mapped", "tail-slot"])
+def test_failed_mapped_rebuild_leaves_nothing(tmp_path, at):
+    """A dispatch that raises, a mapped one with one in flight before
+    it or the tail's with three: no partial output survives, every map
+    and descriptor is released as the call unwinds, and the next
+    rebuild is byte-identical."""
+    base = _encoded(tmp_path, REBUILD_SIZES["tail"][0])
+    want = _files(base)
+    for i in (3, 12):
+        os.unlink(base + to_ext(i))
+    enc = _device_enc()
+    real, calls = enc._kernel, []
+
+    def failing(planes, words):
+        calls.append(type(words))
+        if len(calls) == at + 1:
+            raise RuntimeError("injected dispatch failure")
+        return real(planes, words)
+
+    enc._kernel = failing
+    with pytest.raises(RuntimeError, match="injected dispatch failure"):
+        enc.rebuild_files(base)
+    assert (calls[-1] is tuple) == (at < 3)  # the tail goes as a slot
+    assert not os.path.exists(base + to_ext(3))
+    assert not os.path.exists(base + to_ext(12))
+    _assert_released(enc, base)
+    enc._kernel = real
+    assert enc.rebuild_files(base) == [3, 12]
+    assert _files(base) == want
+    _assert_released(enc, base)

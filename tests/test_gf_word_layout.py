@@ -101,6 +101,23 @@ def test_row_groups_equal_per_row_results(impl, groups, tiles):
     assert np.array_equal(got, want)
 
 
+@pytest.mark.parametrize("tiles", [1, 3])
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_row_tuple_equals_stacked_words(impl, tiles):
+    """A rebuild's K survivor rows, each [W] u32 and put on its own, as
+    a tuple: the same words as the stacked [K, W] array gives, and the
+    CPU codec's bytes."""
+    m = np.ascontiguousarray(MATRIX[K:K + 1])
+    planes = expand_matrix_bitplanes(m)
+    width = tiles * INTERPRET_TILE_B
+    words = _data("ramp", width, seed=70 + tiles).view(np.uint32)
+    got = np.asarray(_word_matmul(impl, planes, tuple(words)))
+    assert got.dtype == np.uint32 and got.shape == (1, width // 4)
+    assert np.array_equal(got, np.asarray(_word_matmul(impl, planes, words)))
+    assert np.array_equal(unpack_u32_host(got, width),
+                          CpuEngine().matmul(m, words.view(np.uint8)))
+
+
 # sha256 of the 2-D word programs' jaxprs before the row axis came in:
 # rebuilds, the slot dispatches and the queue plane run exactly these
 _PROGRAMS_2D = {
