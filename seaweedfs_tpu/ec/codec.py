@@ -25,6 +25,7 @@ import numpy as np
 from ..observability import get_tracer
 from .gf256 import (MUL_TABLE, build_cauchy_matrix, build_encoding_matrix,
                     mat_invert, mat_mul)
+from .layout import SMALL_BLOCK_SIZE
 
 
 class GfMatmulEngine(Protocol):
@@ -135,10 +136,11 @@ class MeshEngine:
         import jax
 
         from ..ops.device import device_backend, ensure_compile_cache
-        from ..ops.gf_matmul import expand_matrix_bitplanes
+        from ..ops.gf_matmul import (DEFAULT_TILE_B, INTERPRET_TILE_B,
+                                     WidthClasses, expand_matrix_bitplanes)
         from ..parallel.mesh import (factor_mesh, make_mesh,
                                      parse_device_spec, sharded_encode_fn)
-        device_backend()
+        on_tpu = device_backend() == "tpu"
         ensure_compile_cache()
         self._jax = jax
         if mesh is None:
@@ -152,6 +154,33 @@ class MeshEngine:
         self._encode = sharded_encode_fn(mesh)
         self._expand = expand_matrix_bitplanes
         self._plane_cache: dict[bytes, object] = {}
+        # TpuEngine's width classes, each a multiple of the dp x sp grid
+        self.classes = WidthClasses(
+            DEFAULT_TILE_B if on_tpu else INTERPRET_TILE_B,
+            SMALL_BLOCK_SIZE, doubling=not on_tpu)
+
+    def width_class(self, b: int) -> int:
+        q = self.dims[0] * self.dims[1]
+        return -(-self.classes.of(b) // q) * q
+
+    def compile_classes(self, rows: int, k: int) -> None:
+        """As TpuEngine.compile_classes, for the sharded matmul: the
+        planes and the [K, dp, B/dp] data placed as matmul places them."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+        spec = self._jax.ShapeDtypeStruct
+        dp = self.dims[0]
+        planes = spec((8 * rows, 8 * k), np.uint8,
+                      sharding=NamedSharding(self.mesh, P(None, "tp")))
+        grid = NamedSharding(self.mesh, P(None, "dp", "sp"))
+
+        def at(w: int) -> int:
+            w = self.width_class(w)
+            self._encode.lower(planes, spec((k, dp, w // dp), np.uint8,
+                                            sharding=grid)).compile()
+            return w
+
+        self.classes.compile(rows, k, at)
 
     def _planes(self, m: np.ndarray):
         """Bit-plane matrix, device_put replicated-over-(dp,sp) and
@@ -170,7 +199,7 @@ class MeshEngine:
         return planes
 
     def matmul(self, m: np.ndarray, shards: np.ndarray) -> np.ndarray:
-        dp, sp, tp = self.dims
+        dp, _, tp = self.dims
         m = np.ascontiguousarray(m, dtype=np.uint8)
         if (8 * m.shape[1]) % tp != 0:  # contraction must split over tp
             raise ValueError(f"8*K={8 * m.shape[1]} not divisible by "
@@ -178,8 +207,7 @@ class MeshEngine:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
         k, width = shards.shape
-        q = dp * sp
-        pad = (-width) % q
+        pad = self.width_class(width) - width
         if pad:
             data = np.zeros((k, width + pad), dtype=np.uint8)
             data[:, :width] = shards
@@ -351,10 +379,14 @@ class ReedSolomon:
         upto = self.data_shards if data_only else self.total_shards
         missing = [i for i in range(upto) if shards[i] is None]
         if missing:
+            # `columns` is the useful width, `padded` the width the
+            # engine runs it at (a device engine's width class)
+            pad_to = getattr(self.engine, "width_class", None)
             with get_tracer().span(
                     "ec.reconstruct", k=self.data_shards,
                     r=self.parity_shards, missing=len(missing),
-                    bytes=size * self.data_shards,
+                    bytes=size * self.data_shards, columns=size,
+                    padded=pad_to(size) if pad_to else size,
                     backend=self.engine.name):
                 sub = [list(int(v) for v in self.matrix[i])
                        for i in sub_rows]

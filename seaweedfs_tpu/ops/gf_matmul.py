@@ -22,8 +22,9 @@ engine (differential-tested):
   - `gf_matmul_pallas`: fused Pallas kernel tiled over B
 
 Each comes in two layouts.  Bytes: data [K, B] u8 -> [R, B] u8, for
-reconstructs of any width (TpuEngine).  Words (`*_packed`): data
-[K, B//4] u32 -> [R, B//4] u32, the streaming pipeline's dispatches; or
+reconstructs (TpuEngine: any width, run at one of a fixed set of width
+classes).  Words (`*_packed`): data [K, B//4] u32 -> [R, B//4] u32, the
+streaming pipeline's dispatches; or
 [G, K, W] u32 -> [R, G*W] u32, G whole rows of a `.dat` as the file lays
 them out (row g's K blocks of W words back to back), whose parity comes
 back in the 2-D layout of the same columns packed row after row; or a
@@ -39,7 +40,9 @@ moves on the device (strided lane slices lower to gathers on a TPU).
 
 from __future__ import annotations
 
+import bisect
 import functools
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -48,8 +51,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..ec.gf256 import expand_matrix_to_bits
+from ..ec.layout import SMALL_BLOCK_SIZE
+from ..observability import get_tracer
 
-LANE = 128
 # picked by a round-1 tile sweep (8K..128K columns) on a v5e; that sweep
 # predates this code and has not been repeated on it
 DEFAULT_TILE_B = 65536
@@ -224,6 +228,50 @@ def unpack_u32_host(words: np.ndarray, width: int) -> np.ndarray:
     return arr.view(np.uint8).reshape(arr.shape[0], width)
 
 
+class WidthClasses:
+    """The fixed widths a device engine runs its codec matmul at.
+
+    A call of width b runs at the least class >= b and its valid
+    columns are cut on the host, so no device program depends on b: a
+    fixed set of compiles per matrix shape, whatever widths the reads
+    ask for.  Up to `block` (the 1 MiB small block: every reconstructed
+    interval of a volume under 10 GiB) the classes are the multiples of
+    `step`, or with `doubling` its doublings (the CPU backend's few
+    compiles for the tests); a wider call (a large-block row) runs at a
+    multiple of `block`."""
+
+    def __init__(self, step: int, block: int, doubling: bool = False):
+        if block % step:
+            raise ValueError(f"block {block} is not a multiple of {step}")
+        self.block = block
+        widths, w = [], step
+        while w <= block:
+            widths.append(w)
+            w = 2 * w if doubling else w + step
+        self.widths = tuple(widths)
+        # the widths compiled so far, and the matrix shapes they serve
+        self.compiled: set[int] = set()
+        self._shapes: set[tuple[int, int]] = set()
+
+    def of(self, b: int) -> int:
+        if b > self.block:
+            return -(-b // self.block) * self.block
+        return self.widths[bisect.bisect_left(self.widths, b)]
+
+    def compile(self, rows: int, k: int, at) -> None:
+        """Compile every class for an [rows, k] matrix, once: `at(w)`
+        compiles the engine's program for class w and returns the width
+        it runs at.  One thread a class: a compile, or a load from the
+        persistent cache, runs outside the interpreter lock."""
+        if (rows, k) in self._shapes:
+            return
+        with get_tracer().span("ec.compile_classes", rows=rows, k=k,
+                               classes=len(self.widths)), \
+                ThreadPoolExecutor(len(self.widths)) as ex:
+            self.compiled.update(ex.map(at, self.widths))
+        self._shapes.add((rows, k))
+
+
 class TpuEngine:
     """GfMatmulEngine backed by the bit-plane kernels.
 
@@ -231,7 +279,11 @@ class TpuEngine:
     CpuEngine.  `mode` is "xla" | "pallas" | "auto" (pallas on a TPU,
     xla on a CPU-pinned process — pallas-on-CPU uses the interpreter,
     which is only for tests).  Without a TPU the process must be pinned
-    to the CPU (ops/device.py), or construction raises NoTpuError."""
+    to the CPU (ops/device.py), or construction raises NoTpuError.
+
+    Every call runs at its width class (WidthClasses over the tile: 16
+    on a TPU); compile_classes compiles them for a matrix shape ahead
+    of the calls."""
 
     def __init__(self, mode: str = "auto", tile_b: int = 0):
         from .device import device_backend, ensure_compile_cache
@@ -246,6 +298,8 @@ class TpuEngine:
             mode = "pallas" if self.on_tpu else "xla"
         self.mode = mode
         self.name = f"tpu-{mode}"
+        self.classes = WidthClasses(self.tile_b, SMALL_BLOCK_SIZE,
+                                    doubling=not self.on_tpu)
         self._plane_cache: dict[bytes, jnp.ndarray] = {}
 
     def _planes(self, m: np.ndarray) -> jnp.ndarray:
@@ -256,22 +310,39 @@ class TpuEngine:
             self._plane_cache[key] = p
         return p
 
+    def _kernel(self) -> tuple:
+        """The jitted matmul of this mode, and its static arguments."""
+        if self.mode == "pallas":
+            return gf_matmul_pallas, {"tile_b": self.tile_b,
+                                      "interpret": not self.on_tpu}
+        return gf_matmul_xla, {}
+
+    def width_class(self, b: int) -> int:
+        return self.classes.of(b)
+
+    def compile_classes(self, rows: int, k: int) -> None:
+        """Compile the kernel for an [rows, k] matrix at every class up
+        to the small block, so that no call of that shape compiles."""
+        fn, static = self._kernel()
+        a = jax.ShapeDtypeStruct((8 * rows, 8 * k), jnp.uint8)
+
+        def at(w: int) -> int:
+            # an ahead-of-time compile fills jit's own cache
+            fn.lower(a, jax.ShapeDtypeStruct((k, w), jnp.uint8),
+                     **static).compile()
+            return w
+
+        self.classes.compile(rows, k, at)
+
     def matmul(self, m: np.ndarray, shards: np.ndarray) -> np.ndarray:
         a = self._planes(np.asarray(m, dtype=np.uint8))
         b = shards.shape[1]
+        w = self.classes.of(b)
         self.calls += 1
-        if self.mode == "pallas":
-            pad = (-b) % self.tile_b
-            padded = np.pad(shards, ((0, 0), (0, pad))) if pad else shards
-            out = gf_matmul_pallas(a, jnp.asarray(padded), tile_b=self.tile_b,
-                                   interpret=not self.on_tpu)
-        else:
-            pad = (-b) % LANE
-            padded = np.pad(shards, ((0, 0), (0, pad))) if pad else shards
-            out = gf_matmul_xla(a, jnp.asarray(padded))
-        if pad:
-            # device-side slice BEFORE the fetch: only the b valid
-            # parity columns are copied back — the tile padding never
-            # leaves the device
-            out = jax.lax.slice(out, (0, 0), (out.shape[0], b))
-        return np.asarray(jax.device_get(out))
+        fn, static = self._kernel()
+        padded = np.pad(shards, ((0, 0), (0, w - b))) if w > b else shards
+        out = np.asarray(jax.device_get(fn(a, jnp.asarray(padded),
+                                           **static)))
+        # the valid columns, cut on the host as a view: the class's
+        # padding is fetched too, and no device op depends on b
+        return out[:, :b] if w > b else out

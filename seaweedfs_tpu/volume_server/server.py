@@ -1173,7 +1173,11 @@ class VolumeServer:
                 doc["EcDevice"] = {**device_doc(),
                                    "codec_engine": rs.engine.name,
                                    "codec_matmuls": getattr(
-                                       rs.engine, "calls", 0)}
+                                       rs.engine, "calls", 0),
+                                   # the width classes compiled so far
+                                   "reconstruct_classes": sorted(getattr(
+                                       getattr(rs.engine, "classes", None),
+                                       "compiled", ()))}
             from ..stats import ec_integrity_metrics
 
             # bit-rot defense: nonzero corrupt_shards means sidecar

@@ -256,6 +256,17 @@ class Store:
     def _open_ec_volume(self, directory: str, collection: str, vid: int) -> EcVolume:
         base = volume_file_prefix(directory, collection, vid)
         ev = EcVolume(base, vid)
+        if self.ec_engine_name in ("tpu", "mesh"):
+            # before any GET can reach the volume: a degraded read then
+            # runs a program compiled here, never one compiled on a
+            # user's request.  reconstruct_interval reads data_shards
+            # survivors and ReedSolomon.reconstruct makes every other
+            # shard, so its matrix has total - data rows
+            compile_classes = getattr(self.rs().engine, "compile_classes",
+                                      None)
+            if compile_classes is not None:
+                compile_classes(ev.total_shards - ev.data_shards,
+                                ev.data_shards)
         self.ec_volumes[vid] = ev
         self.ec_collections[vid] = collection
         self.note_ec_change(vid)

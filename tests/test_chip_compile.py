@@ -161,6 +161,28 @@ def test_pallas_reconstruct_rows(one_chip, rows):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("width", [TILE, 1 << 20])
+def test_pallas_reconstruct_at_width_classes(one_chip, width):
+    """TpuEngine's smallest and largest width class on a TPU (one tile
+    and the 1 MiB small block), for the reconstruct's 4 x 10 matrix: the
+    module and its one custom call are named as benchmark/kernels.py
+    finds them, and the output is the whole class (the valid columns
+    are cut on the host)."""
+    from seaweedfs_tpu.ops.gf_matmul import WidthClasses, gf_matmul_pallas
+
+    classes = WidthClasses(TILE, 1 << 20)
+    assert width in (classes.widths[0], classes.widths[-1])
+    text = gf_matmul_pallas.lower(
+        _spec((8 * R, 8 * K), one_chip), _spec((K, width), one_chip),
+        tile_b=TILE).compile().as_text()
+    assert re.search(r"^HloModule jit_gf_matmul_pallas\b", text, re.M)
+    calls = [ln for ln in text.splitlines() if "custom-call(" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(rf"\s*ROOT %gf_matmul_pallas(\.\d+)? = u8\[{R},{width}\]",
+                    calls[0]), calls[0]
+    assert "tpu_custom_call" in calls[0]
+
+
 def test_device_encode_fn_on_tpu(one_chip):
     """The kernel each chip of a slice runs in the per-device queues
     (`-ec.engine=tpu|mesh` on several chips), as the benchmark finds it
