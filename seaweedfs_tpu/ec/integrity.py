@@ -38,8 +38,10 @@ Sidecar format (`<base>.eci`, big-endian):
             bytes when shard_size % block_size != 0
 
 CRCs use the same masked crc32c as needle checksums (storage/crc.py:
-rotr15 + 0xa282ead8), hardware-accelerated via google_crc32c where
-available.  Rebuild NEVER rewrites sidecar rows: regenerated shards are
+rotr15 + 0xa282ead8).  The encode's crc stream (SidecarBuilder) takes
+storage/crc.py's block_crcs: one native call for every block a buffer
+closes, with no copy and the GIL released (crc32c() where the library
+is missing).  Rebuild NEVER rewrites sidecar rows: regenerated shards are
 byte-identical to the originals by the codec contract, so the row
 written at encode time stays authoritative — corruption that happened
 after encode can never launder itself into the baseline.
@@ -54,7 +56,7 @@ from typing import Optional
 import numpy as np
 
 from ..observability import get_tracer
-from ..storage.crc import crc32c, masked_value
+from ..storage.crc import block_crcs, crc32c, load_block_crc, masked_value
 from .layout import TOTAL_SHARDS_COUNT, to_ext
 
 ECI_EXT = ".eci"
@@ -239,6 +241,7 @@ class SidecarBuilder:
         self._fill = [0] * total_shards   # bytes in the open block
         self._size = [0] * total_shards
         self._touched = [False] * total_shards
+        load_block_crc()
 
     def reset_shard(self, shard_id: int) -> None:
         self._crcs[shard_id] = []
@@ -248,12 +251,27 @@ class SidecarBuilder:
         self._touched[shard_id] = False
 
     def update(self, shard_id: int, data) -> None:
+        """Feed the shard's next bytes.  Contiguous input is checksummed
+        in place by one native call for all its blocks, which holds no
+        GIL, so the encode's data lanes and parity writer update their
+        own shards in parallel; one shard is fed by one thread at a
+        time."""
         mv = memoryview(data)
         if mv.ndim != 1 or mv.itemsize != 1:  # ndarray rows arrive as u8
             mv = mv.cast("B")
         bs = self.block_size
         self._touched[shard_id] = True
-        pos, n = 0, len(mv)
+        n = len(mv)
+        fill = self._fill[shard_id]
+        got = (block_crcs(mv, self._run[shard_id], fill, bs)
+               if n and mv.c_contiguous else None)
+        if got is not None:
+            closed, self._run[shard_id] = got
+            self._crcs[shard_id].extend(closed)
+            self._fill[shard_id] = (fill + n) % bs
+            self._size[shard_id] += n
+            return
+        pos = 0
         while pos < n:
             take = min(bs - self._fill[shard_id], n - pos)
             self._run[shard_id] = crc32c(mv[pos:pos + take],

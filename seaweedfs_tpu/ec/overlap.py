@@ -6,7 +6,9 @@ pool and hands the fetched parity to ONE writer thread through a bounded
 FIFO queue, so D2H transfers overlap the producer's fill, dispatch and
 data writes while the shard append order and the `.eci` crc stream stay
 those of a serial drain.  DrainerGroup is one such drain lane per device
-of a slice: the per-device dispatch-queue plane.
+of a slice: the per-device dispatch-queue plane.  DataLanes takes the
+data shards' pass-through writes off the producer the same way, each
+shard on the one thread that owns it.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ from __future__ import annotations
 import concurrent.futures
 import queue as queue_mod
 import threading
+import time
 
 
 class AsyncDrainer:
@@ -213,3 +216,114 @@ class DrainerGroup:
     def abort(self) -> None:
         for d in self.drainers:
             d.abort()
+
+
+class DataLanes:
+    """The data shards' pass-through writes, on shard-owned threads.
+
+    Lane j of n owns the shards i with i % n == j, so each shard's bytes
+    and its `.eci` crc state are touched by one thread, in file order.
+    The producer submit()s each dispatch once, in dispatch order, and
+    goes straight on; every lane runs write(j, meta) for it, in that
+    order.  What must not run ahead of the data, a slot's release or
+    the resume checkpoint, asks written(d) or wait(d): the first d
+    dispatches are written in every lane.
+
+    Errors as AsyncDrainer's: the first write exception is kept, later
+    dispatches are discarded unwritten, and it is re-raised from
+    submit(), wait() and finish().  abort() discards queued work and
+    joins the threads."""
+
+    def __init__(self, lanes: int, write, name: str = "ec-data"):
+        self.n = max(1, int(lanes))
+        self._write_fn = write
+        self._cv = threading.Condition()
+        self._done = [0] * self.n  # guarded-by: _cv
+        self._error: BaseException | None = None  # guarded-by: _cv
+        # lock-free monotonic flag, as AsyncDrainer.aborting
+        self.aborting = False
+        self._qs = [queue_mod.SimpleQueue() for _ in range(self.n)]
+        self._threads = [
+            threading.Thread(target=self._lane_loop, args=(j,),
+                             daemon=True, name=f"{name}-{j}")
+            for j in range(self.n)]
+        for t in self._threads:
+            t.start()
+
+    @property
+    def error(self):
+        with self._cv:
+            return self._error
+
+    def submit(self, meta) -> None:
+        err = self.error
+        if err is not None:
+            raise err
+        for q in self._qs:
+            q.put(meta)
+
+    def _lane_loop(self, j: int) -> None:
+        q = self._qs[j]
+        while True:
+            meta = q.get()
+            if meta is None:
+                return
+            if self.aborting or self.error is not None:
+                continue
+            try:
+                self._write_fn(j, meta)
+            except (KeyboardInterrupt, SystemExit):  # pragma: no cover
+                raise
+            except BaseException as e:
+                with self._cv:
+                    if self._error is None:
+                        self._error = e
+                    self._cv.notify_all()
+                continue
+            finally:
+                meta = None  # the source view goes with the write
+            with self._cv:
+                self._done[j] += 1
+                self._cv.notify_all()
+
+    def written(self, d: int) -> bool:
+        """The first d dispatches are written in every lane."""
+        with self._cv:
+            return min(self._done) >= d
+
+    def wait(self, d: int, timeout_s: float) -> None:
+        """Block until written(d); raises a lane's error, or
+        RuntimeError once aborted or after timeout_s."""
+        deadline = time.monotonic() + timeout_s
+        with self._cv:
+            while min(self._done) < d:
+                if self._error is not None:
+                    raise self._error
+                if self.aborting:
+                    raise RuntimeError("data lanes aborted")
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    raise RuntimeError("data lanes stalled")
+                self._cv.wait(min(left, 0.2))
+
+    def finish(self) -> None:
+        """Write every submitted dispatch, join the lanes, then
+        re-raise the first lane error (if any)."""
+        for q in self._qs:
+            q.put(None)
+        for t in self._threads:
+            t.join()
+        err = self.error
+        if err is not None:
+            raise err
+
+    def abort(self) -> None:
+        """Abnormal-exit teardown: discard queued work, wake waiters,
+        join the lanes.  Never raises."""
+        self.aborting = True  # weedlint: disable=W502 lock-free abort flag, as AsyncDrainer.abort
+        with self._cv:
+            self._cv.notify_all()
+        for q in self._qs:
+            q.put(None)
+        for t in self._threads:
+            t.join(timeout=30)

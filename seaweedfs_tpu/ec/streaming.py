@@ -23,6 +23,10 @@ runs a depth-N asynchronous pipeline over fixed-shape dispatches:
     a host-side pass-through) overlap the device compute of dispatch d:
     `device_put` + the jitted kernel return immediately, and the parity
     fetch lags `depth` dispatches behind;
+  - the data shards' writes and their `.eci` crc leave the producer for
+    shard-owned write lanes (overlap.DataLanes), whose native crc holds
+    no GIL; a slot goes back to its pool, and the resume checkpoint
+    passes a dispatch, only once the lanes have written it;
   - the DRAIN is asynchronous and multi-buffered (overlap.AsyncDrainer):
     only the parity rows ever cross back over the link (r/k of the
     input — the data shards are already host bytes), the blocking fetch
@@ -59,6 +63,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from ..observability import get_tracer
+from ..storage.crc import block_crc_path, load_block_crc
 from ..utils import faultinject
 from ..utils.ioutil import pread_padded, preadv_into
 from .gf256 import mat_invert, mat_mul
@@ -71,7 +76,7 @@ from .integrity import (
     sidecar_path,
     verify_shard_file,
 )
-from .overlap import AsyncDrainer, DrainerGroup
+from .overlap import AsyncDrainer, DataLanes, DrainerGroup
 from .layout import (
     DATA_SHARDS_COUNT,
     LARGE_BLOCK_SIZE,
@@ -88,6 +93,15 @@ def default_drain_pool(cores: Optional[int] = None) -> int:
     without oversubscribing the host."""
     n = cores if cores is not None else (os.cpu_count() or 1)
     return max(1, min(4, n - 1))
+
+
+def default_data_lanes(cores: Optional[int] = None) -> int:
+    """Data-write lanes of an encode: one thread per spare core, bounded
+    to [1, 5], two of RS(10,4)'s data shards a lane at the most.  A
+    lane's write and crc drop the GIL, so the lanes run in parallel
+    with each other and with the producer."""
+    n = cores if cores is not None else (os.cpu_count() or 1)
+    return max(1, min(5, n - 1))
 
 
 def _plan_entries(file_size: int, k: int, large: int, small: int,
@@ -252,6 +266,9 @@ class StreamingEncoder:
         self.max_encode_retries = max_encode_retries
         self._drain_pool = (max(1, int(drain_pool)) if drain_pool
                             else default_drain_pool())
+        self._data_lanes = default_data_lanes()
+        # the .eci block crc's path, chosen (and built) before any encode
+        load_block_crc()
         # stats counters are bumped from the drainer/writer threads
         # too — and _st_lock also serializes the stale-map list, the
         # kept slots and the lazy fallback-engine init
@@ -414,21 +431,62 @@ class StreamingEncoder:
 
     def _write_data(self, outputs: list, sb, src: np.ndarray,
                     used: int) -> float:
-        """Data shards pass through from the dispatch's input: shard i
-        takes a slot's row i, or block i of each row of a group in
-        order; the `.eci` crc stream takes each slice right after its
-        write, while the slice is still in the CPU's cache (one vectored
-        write a shard, crc after, ran slower on a v5e host).  Returns
-        the crc time."""
+        """One lane's data shards of a dispatch, passed through from its
+        input: shard i, where outputs[i] is not None, takes a slot's
+        row i, or block i of each row of a group in order, and its
+        `.eci` crc stream takes each slice right after its write, while
+        the slice is still in the CPU's cache.  The other lanes write
+        the shards that are None here, at the same time: the write and
+        the native crc both drop the GIL.  Returns the crc time."""
         crc_s = 0.0
-        for i in range(self.k):
+        for i, f in enumerate(outputs):
+            if f is None:
+                continue
             for p in (src[:, i] if src.ndim == 3 else (src[i, :used],)):
-                outputs[i].write(memoryview(p))
+                f.write(memoryview(p))
                 if sb is not None:
                     t0 = time.perf_counter()
                     sb.update(i, p)
                     crc_s += time.perf_counter() - t0
         return crc_s
+
+    def _open_lanes(self, tr, outputs: list, sb, ds: dict,
+                    ds_lock) -> DataLanes:
+        """An encode attempt's data lanes: lane j of L writes the data
+        shards i with i % L == j of every dispatch submitted to it, as
+        (d_idx, src, used), under a `pipeline.write kind=data` span
+        that says its `lane`, and counts their bytes (/status EcPipeline
+        `lane_data_bytes`)."""
+        from ..stats import ec_pipeline_metrics
+
+        k = self.k
+        n = max(1, min(self._data_lanes, k))
+        own = [[f if i % n == j else None for i, f in enumerate(outputs[:k])]
+               for j in range(n)]
+        counter = ec_pipeline_metrics().lane_data_bytes
+
+        def write(j, meta):  # thread-entry
+            d_idx, src, used = meta
+            with tr.span("pipeline.write", dispatch=d_idx, kind="data",
+                         lane=j):
+                crc_s = self._write_data(own[j], sb, src, used)
+            counter.inc(amount=used * len(range(j, k, n)))
+            with ds_lock:
+                ds["sidecar_s"] += crc_s
+
+        return DataLanes(n, write)
+
+    def _wait_data(self, tr, lanes: DataLanes, d: int, **attrs) -> None:
+        """Block until the lanes have written the first d dispatches:
+        before a slot they read goes back to its pool, before the
+        checkpoint passes them.  A wait that blocks is a
+        `pipeline.data_wait` span whose `wait` says where: `slot` on a
+        fetch thread, `checkpoint` on the parity writer (`final`, the
+        producer's wait at the encode's end, is opened by the caller)."""
+        if lanes.written(d):
+            return
+        with tr.span("pipeline.data_wait", **attrs):
+            lanes.wait(d, max(4 * self.drain_timeout_s, 120.0))
 
     def _take_slots(self, nd: int, per_dev: int) -> list:
         """[nd][per_dev] slot buffers: the last clean encode's where the
@@ -631,7 +689,8 @@ class StreamingEncoder:
         tr = self._tracer()
         root = tr.span("pipeline.encode_file", path=dat_path,
                        bytes=file_size, mode="staged", engine=self.engine,
-                       devices=st["devices"], resume_entry=start_entry)
+                       devices=st["devices"], resume_entry=start_entry,
+                       crc=block_crc_path())
         root.__enter__()
         # setup covers output opens (O_TRUNC over existing shards frees
         # their page cache — real, attributable time), the slots and the
@@ -729,6 +788,9 @@ class StreamingEncoder:
             with ds_lock:
                 ds["drain_s"] += fetch_s
                 ds["parity_bytes"] += nbytes
+            if rows is None:
+                # the data lanes read the slot too
+                self._wait_data(tr, lanes, d_idx + 1, wait="slot")
             # the fetched parity is an independent host array and fetch
             # completion proves the kernel consumed the input slot —
             # recycle NOW so the producer refills while this parity
@@ -756,6 +818,7 @@ class StreamingEncoder:
                         sb.update(k + j, parity[j, :u])
                     with ds_lock:
                         ds["sidecar_s"] += clock() - t1
+            self._wait_data(tr, lanes, d_idx + 1, wait="checkpoint")
             # dispatch d_idx is fully drained AND written on every shard:
             # advance the resume checkpoint past its entries/bytes
             ck_e, ck_b = self._ckpt
@@ -792,6 +855,8 @@ class StreamingEncoder:
         for i in range(len(bufs)):
             slot_q.put(i)
         st["drain_pool"] = drainer.pool_size
+        lanes = self._open_lanes(tr, outputs, sb, ds, ds_lock)
+        st["data_lanes"] = lanes.n
 
         try:
             with open(dat_path, "rb") as dat:
@@ -854,20 +919,16 @@ class StreamingEncoder:
                                     _shard_major(src, used))
                     self._count_dispatch(st, rows is not None)
                     st["bytes_in"] += k * used
-                    # data shards pass through from the host input while
-                    # the device computes parity; crc time rides the
-                    # write stage (see the parity-side note), sidecar_s
-                    # sub-counts it
-                    with tr.span("pipeline.write", dispatch=d_idx,
-                                 kind="data"):
-                        st["sidecar_s"] += self._write_data(
-                            outputs, sb, src, used)
+                    # data shards pass through from the host input on the
+                    # lanes while the device computes parity
+                    lanes.submit((d_idx, src, used))
                     # hand the dispatch to the drainer and move straight
-                    # on to filling the next slot — the fetch + parity
-                    # write overlap everything below.  Backpressure is
-                    # normally the slot pool, but the bounded writer
-                    # queue can also push back (fast fetch over a slow
-                    # shard disk recycles slots before the write)
+                    # on to filling the next slot — the data writes, the
+                    # fetch and the parity write overlap everything
+                    # below.  Backpressure is normally the slot pool, but
+                    # the bounded writer queue can also push back (fast
+                    # fetch over a slow shard disk recycles slots before
+                    # the write)
                     drainer.submit((parity_dev, used, bi, d_idx, len(fills),
                                     rows))
                     fills, used = [], 0
@@ -885,8 +946,10 @@ class StreamingEncoder:
                     fills.append((used, n, row_start, block, off))
                     used += n
                 flush()
-                # tail stall: the last in-flight dispatches finish
-                # fetching + writing
+                # tail stall: the lanes write their last data, then the
+                # last in-flight dispatches finish fetching + writing
+                with tr.span("pipeline.data_wait", wait="final"):
+                    lanes.finish()
                 with tr.span("pipeline.drain_wait", final=True):
                     drainer.finish()
             if sb is not None:
@@ -904,9 +967,10 @@ class StreamingEncoder:
             if setup is not None:  # failed before the loop started
                 setup.__exit__(*exc)
             if not ok:
+                lanes.abort()
                 drainer.abort()
-            # fold the concurrent drain/writer accounting into the call
-            # stats now that the threads have joined
+            # fold the concurrent lane/drain/writer accounting into the
+            # call stats now that the threads have joined
             st["drain_s"] += ds["drain_s"]
             st["sidecar_s"] += ds["sidecar_s"]
             st["parity_bytes_drained"] += ds["parity_bytes"]
@@ -937,7 +1001,7 @@ class StreamingEncoder:
         transfers amortize one wire turnaround when the link is the
         ceiling.
 
-        Output discipline: data shards append on the producer thread in
+        Output discipline: data shards append on the data lanes in
         dispatch order (exactly the staged pipeline); parity rows are
         PWRITTEN at their known shard offsets by whichever lane finishes
         first (order-free), while the `.eci` crc stream and the resume
@@ -964,7 +1028,8 @@ class StreamingEncoder:
         tr = self._tracer()
         root = tr.span("pipeline.encode_file", path=dat_path,
                        bytes=file_size, mode="mesh", engine=self.engine,
-                       devices=nd, resume_entry=start_entry)
+                       devices=nd, resume_entry=start_entry,
+                       crc=block_crc_path())
         root.__enter__()
         setup = tr.span("pipeline.setup")
         setup.__enter__()
@@ -1075,6 +1140,9 @@ class StreamingEncoder:
                     tr.event("pipeline.fallback", dispatch=d_idx,
                              device=dev_i, reason=reasons[ji])
                 parities[ji] = parities[ji][:, :u]
+                # the data lanes read the slot too
+                self._wait_data(tr, lanes, d_idx + 1, wait="slot",
+                                device=dev_i)
                 slot_qs[dev_i].put(slot)
             with ds_lock:
                 ds["drain_s"] += fetch_s
@@ -1086,6 +1154,9 @@ class StreamingEncoder:
             """This lane's writer thread: parity rows pwrite at their
             known shard offsets (cross-lane order-free), then the
             ordered tracker retires crc + checkpoint."""
+            # the fetch released the jobs' slots only once the data
+            # lanes had written them, so the checkpoint below never
+            # passes unwritten data
             dev_i, jobs = meta
             with tr.span("pipeline.write", device=dev_i,
                          dispatch=jobs[0][3], kind="parity"):
@@ -1101,6 +1172,8 @@ class StreamingEncoder:
         drainers = DrainerGroup(nd, drain_fetch_dev, drain_write_dev,
                                 queue_depth=slots_per_dev + 2)
         st["drain_pool"] = nd
+        lanes = self._open_lanes(tr, outputs, sb, ds, ds_lock)
+        st["data_lanes"] = lanes.n
         batches: list[list] = [[] for _ in range(nd)]
 
         def submit_batch(dev_i: int) -> None:
@@ -1189,10 +1262,7 @@ class StreamingEncoder:
                     self._count_dispatch(st, False)
                     st["bytes_in"] += k * used
                     dev_dispatches[dev_i] += 1
-                    with tr.span("pipeline.write", dispatch=d_idx,
-                                 kind="data"):
-                        st["sidecar_s"] += self._write_data(
-                            outputs, sb, buf, used)
+                    lanes.submit((d_idx, buf, used))
                     batches[dev_i].append((handle, used, slot, d_idx,
                                            len(fills), out_off))
                     out_off += used
@@ -1214,8 +1284,10 @@ class StreamingEncoder:
                 flush()
                 for dev_i in range(nd):
                     submit_batch(dev_i)
-                # tail stall: every lane's in-flight dispatches finish
-                # fetching + writing
+                # tail stall: the data lanes, then every drain lane's
+                # in-flight dispatches finish fetching + writing
+                with tr.span("pipeline.data_wait", wait="final"):
+                    lanes.finish()
                 with tr.span("pipeline.drain_wait", final=True):
                     drainers.finish()
                 if nxt[0] != st["dispatches"]:
@@ -1239,6 +1311,7 @@ class StreamingEncoder:
             if ok:
                 self._keep_slots(dev_bufs)
             else:
+                lanes.abort()
                 drainers.abort()
             st["drain_s"] += ds["drain_s"]
             st["sidecar_s"] += ds["sidecar_s"]

@@ -1,7 +1,9 @@
-"""ctypes loader for the native GF(2^8) SIMD library.
+"""ctypes loaders for the native libraries: the GF(2^8) SIMD library,
+the LSM KV and the volume server's data plane (whose crc32c the EC
+sidecar uses too).
 
-Builds lazily with make on first import (cached as libgf256.so); callers
-fall back to the numpy engine when no C++ toolchain is available.
+Each builds lazily with make on first load (cached as lib*.so); callers
+fall back to their Python paths when no C++ toolchain is available.
 """
 
 from __future__ import annotations
@@ -9,6 +11,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import threading
 
 import numpy as np
 
@@ -70,6 +73,34 @@ def has_avx2() -> bool:
 def has_gfni() -> bool:
     lib = load()
     return bool(lib and lib.gf_has_gfni())
+
+
+# --- data plane (dataplane.cpp) --------------------------------------------
+
+_DP_LIB_PATH = os.path.join(_DIR, "libdataplane.so")
+_dp_lib = None
+_dp_lock = threading.Lock()
+
+
+def load_dataplane_lib():
+    """The data plane library, built where it is missing or older than
+    its source, or None where it cannot be built or loaded.  One load a
+    process, under a lock that serializes concurrent builders; callers
+    declare the argtypes of the functions they call."""
+    global _dp_lib
+    with _dp_lock:
+        if _dp_lib is None:
+            src = os.path.join(_DIR, "dataplane.cpp")
+            if not os.path.exists(_DP_LIB_PATH) or (
+                    os.path.exists(src)
+                    and os.path.getmtime(src) > os.path.getmtime(_DP_LIB_PATH)):
+                if not _build():  # weedlint: lock-io one-time native build at first load; the lock exists precisely to serialize concurrent builders, and the make is timeout-bounded
+                    return None
+            try:
+                _dp_lib = ctypes.CDLL(_DP_LIB_PATH)
+            except OSError:
+                return None
+        return _dp_lib
 
 
 # --- native LSM KV (lsmkv.cpp) ----------------------------------------------

@@ -93,11 +93,25 @@ static uint32_t crc32c_sw(uint32_t crc, const uint8_t* p, size_t n) {
 }
 
 static bool g_hw_crc = false;
-static uint32_t crc32c(const uint8_t* p, size_t n) {
+static void crc_setup() {
+    static std::once_flag once;
+    std::call_once(once, [] {
+        crc_init();
 #if defined(__x86_64__)
-    if (g_hw_crc) return crc32c_hw(0, p, n);
+        g_hw_crc = has_sse42();
 #endif
-    return crc32c_sw(0, p, n);
+    });
+}
+// crc32c of n bytes at p continuing from crc (0 starts one): the
+// contract of google_crc32c.extend
+static uint32_t crc32c_extend(uint32_t crc, const uint8_t* p, size_t n) {
+#if defined(__x86_64__)
+    if (g_hw_crc) return crc32c_hw(crc, p, n);
+#endif
+    return crc32c_sw(crc, p, n);
+}
+static uint32_t crc32c(const uint8_t* p, size_t n) {
+    return crc32c_extend(0, p, n);
 }
 
 static uint32_t masked_crc(uint32_t c) {
@@ -611,14 +625,35 @@ static void accept_loop(Server* s) {
 // ================================================================ C API
 extern "C" {
 
+// A shard's next n bytes into its `.eci` block crcs: *run is the crc of
+// the open block, which holds fill of its bs bytes; each block closed
+// here goes to out as its masked crc, and their count is returned.
+// One call for all the blocks of a slice, with no copy, so a lane
+// gives up the GIL (ctypes drops it for the call) once a slice and not
+// once a block.
+size_t crc32c_blocks(uint32_t* run, size_t fill, size_t bs,
+                     const uint8_t* p, size_t n, uint32_t* out) {
+    crc_setup();
+    size_t closed = 0;
+    uint32_t crc = *run;
+    while (n) {
+        size_t take = std::min(bs - fill, n);
+        crc = crc32c_extend(crc, p, take);
+        p += take;
+        n -= take;
+        fill += take;
+        if (fill == bs) {
+            out[closed++] = masked_crc(crc);
+            crc = 0;
+            fill = 0;
+        }
+    }
+    *run = crc;
+    return closed;
+}
+
 void* dp_start(const char* host, int port) {
-    static std::once_flag once;
-    std::call_once(once, [] {
-        crc_init();
-#if defined(__x86_64__)
-        g_hw_crc = has_sse42();
-#endif
-    });
+    crc_setup();
     if (port < 0) {
         // engine-only mode: no TCP listener at all (whitelist-guarded
         // servers — the plane has no whitelist slot, and the Python TCP

@@ -135,6 +135,10 @@ def _stage_of(span: dict) -> Optional[str]:
         # pool / final join — host-blocked time, the same bucket the
         # old inline fetch landed in
         return "drain"
+    if stage == "data_wait":
+        # the producer blocked on the data lanes (ec/overlap.py
+        # DataLanes): its share of the data writes
+        return "write"
     return stage if stage in HOST_STAGES else None
 
 

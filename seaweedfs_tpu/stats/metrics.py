@@ -16,6 +16,8 @@ import time
 from bisect import bisect_left
 from typing import Optional
 
+from ..storage.crc import block_crc_path
+
 DEFAULT_BUCKETS = (0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03,
                    0.1, 0.3, 1.0, 3.0, 10.0)
 
@@ -434,12 +436,13 @@ class S3Metrics(_ServerMetrics):
 
 class ECPipelineMetrics:
     """Self-healing EC pipeline counters: per-dispatch engine fallbacks
-    to the CPU codec (ec/streaming.py, ec/codec.py) and the dispatches
-    by input source.  worker_restarts stays registered at 0 so /status
-    and the alert plane keep their shape: no parity worker process is
-    left to restart.  Separate from the per-role bundles because the
-    pipeline runs inside whatever process invoked the encode — volume
-    server or shell tool."""
+    to the CPU codec (ec/streaming.py, ec/codec.py), the dispatches by
+    input source and the data-shard bytes the write lanes wrote; with
+    them the path the sidecar's block crcs take.
+    worker_restarts stays registered at 0 so /status and the alert plane
+    keep their shape: no parity worker process is left to restart.
+    Separate from the per-role bundles because the pipeline runs inside
+    whatever process invoked the encode — volume server or shell tool."""
 
     def __init__(self, registry: Registry = REGISTRY):
         self.worker_restarts = registry.counter(
@@ -456,6 +459,10 @@ class ECPipelineMetrics:
             "their input came from: map (rows viewed in the mapped .dat) "
             "or slot (filled into a staging buffer).",
             labels=("source",))
+        self.lane_data_bytes = registry.counter(
+            "SeaweedFS_ec_lane_data_bytes_total",
+            "Data-shard bytes EC encodes wrote on their shard-owned "
+            "write lanes.")
         self.degraded_binds = registry.counter(
             "SeaweedFS_server_degraded_binds_total",
             "Servers that came up without their framed-TCP plane "
@@ -474,6 +481,10 @@ class ECPipelineMetrics:
                 int(sum(self.degraded_binds.snapshot().values())),
             "dispatches": int(sum(self.dispatches.snapshot().values())),
             "mapped_dispatches": int(self.dispatches.value("map")),
+            "lane_data_bytes": int(self.lane_data_bytes.value()),
+            # the path of the .eci block crcs: native (no copy, no GIL)
+            # or the fallback's name
+            "block_crc": block_crc_path(),
         }
 
 

@@ -12,17 +12,13 @@ operation, and re-attaches.
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 from typing import Optional
 
+from ..native import load_dataplane_lib
 from ..observability import get_tracer
 from ..observability.tracer import NOOP_SPAN
 
-_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                    "native")
-_LIB_PATH = os.path.join(_DIR, "libdataplane.so")
 
 # error codes (dataplane.cpp enum)
 DP_OK = 0
@@ -42,22 +38,12 @@ _lib_lock = threading.Lock()
 def load_dataplane():
     """Build (if stale) + load the library; None when unavailable."""
     global _lib
+    lib = load_dataplane_lib()  # builds under its own lock
+    if lib is None:
+        return None
     with _lib_lock:
         if _lib is not None:
             return _lib
-        src = os.path.join(_DIR, "dataplane.cpp")
-        if not os.path.exists(_LIB_PATH) or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(_LIB_PATH)):
-            try:
-                subprocess.run(["make", "-s", "-C", _DIR], check=True,  # weedlint: lock-io one-time native build at first load; the lock exists precisely to serialize concurrent builders, and the make is timeout-bounded
-                               capture_output=True, timeout=120)
-            except Exception:
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            return None
         u8p = ctypes.POINTER(ctypes.c_ubyte)
         lib.dp_start.restype = ctypes.c_void_p
         lib.dp_start.argtypes = [ctypes.c_char_p, ctypes.c_int]

@@ -696,3 +696,85 @@ def test_targeted_rescrub_clears_stale_unrepairable_verdict(tmp_path):
         shutil.rmtree(str(tmp_path), ignore_errors=True)
     finally:
         store.close()
+
+
+# --- the native block crc -------------------------------------------------
+
+def test_native_block_crcs_match_google_crc32c():
+    """block_crcs over a buffer's own address equals google_crc32c over
+    copies of the same bytes, block by block: assorted lengths (0, under
+    a word, under, at and across a block), unaligned offsets, and open
+    blocks carried in from a previous call (a chained crc)."""
+    google_crc32c = pytest.importorskip("google_crc32c")
+    from seaweedfs_tpu.storage import crc
+
+    if crc.load_block_crc() != "native":
+        pytest.skip("native dataplane library unavailable")
+    buf = np.random.default_rng(31).integers(0, 256, 1 << 20,
+                                             dtype=np.uint8)
+
+    def want(data, run, fill, bs):
+        closed, pos = [], 0
+        while pos < len(data):
+            take = min(bs - fill, len(data) - pos)
+            run = google_crc32c.extend(run, data[pos:pos + take])
+            fill += take
+            pos += take
+            if fill == bs:
+                closed.append(crc.masked_value(run))
+                run, fill = 0, 0
+        return closed, run
+
+    lengths = [0, 1, 7, 8, 9, 255, 4095, 4096, 4097, 100_003, 256 * 1024,
+               900_001]
+    for bs in (4096, 256 * 1024):
+        for n in lengths:
+            for off in (0, 1, 3, 7, 13):
+                for run, fill in ((0, 0), (0x1234ABCD, 5), (0xFFFFFFFF,
+                                                            bs - 1)):
+                    data = memoryview(buf[off:off + n])
+                    got = crc.block_crcs(data, run, fill, bs)
+                    assert got == want(data.tobytes(), run, fill, bs), \
+                        (bs, n, off, run, fill)
+    # chaining: a buffer fed in parts closes the blocks of the whole
+    whole = want(buf.tobytes(), 0, 0, 4096)
+    closed, run, fill = [], 0, 0
+    for a, b in [(0, 5), (5, 70_001), (70_001, 1 << 20)]:
+        got, run = crc.block_crcs(memoryview(buf[a:b]), run, fill, 4096)
+        closed += got
+        fill = (fill + b - a) % 4096
+    assert (closed, run) == whole
+
+
+@pytest.mark.parametrize("block", [256, 4096, 256 * 1024])
+def test_sidecar_builder_native_crc_is_bit_identical(monkeypatch, block):
+    """SidecarBuilder stores the same table with the native crc (one
+    crc32c_blocks call an update, blocks left open across calls) as
+    with the google_crc32c path it falls back to: contiguous rows,
+    bytes, strided rows (which take the fallback) and u32 words, in any
+    chunking."""
+    rng = np.random.default_rng(block)
+    data = rng.integers(0, 256, (2, 600_001), dtype=np.uint8)
+    pairs = rng.integers(0, 256, (600_001, 2), dtype=np.uint8)
+    words = rng.integers(0, 1 << 32, 40_000, dtype=np.uint32)
+    cuts = [0, 1, 4097, 262_144, 500_000, 600_001]
+
+    def build():
+        b = SidecarBuilder(3, block)
+        for lo, hi in zip(cuts, cuts[1:]):
+            b.update(0, data[0, lo:hi])
+            b.update(1, memoryview(data[1, lo:hi].tobytes()))
+            b.update(2, pairs[lo:hi, 0])  # stride 2
+        w = SidecarBuilder(1, block)
+        w.update(0, words)
+        return np.concatenate([b.finalize().crcs.ravel(),
+                               w.finalize().crcs.ravel()])
+
+    from seaweedfs_tpu.storage import crc
+
+    monkeypatch.setattr(crc, "_blocks", False)
+    want = build()
+    monkeypatch.setattr(crc, "_blocks", None)
+    got = build()
+    assert crc.block_crc_path() == "native"  # the library was compared
+    np.testing.assert_array_equal(got, want)

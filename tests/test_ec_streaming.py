@@ -20,6 +20,7 @@ from seaweedfs_tpu.ec import encoder
 from seaweedfs_tpu.ec.codec import ReedSolomon
 from seaweedfs_tpu.ec.layout import to_ext
 from seaweedfs_tpu.ec.streaming import (StreamingEncoder, _plan_entries,
+                                        default_data_lanes,
                                         default_drain_pool)
 from seaweedfs_tpu.observability import Tracer
 from seaweedfs_tpu.utils import faultinject as fi
@@ -264,6 +265,14 @@ def test_async_drain_slow_link_overlap_and_fifo(tmp_path):
         open(sidecar_path(ref), "rb").read()
 
 
+def test_default_data_lanes_bounds():
+    assert default_data_lanes(1) == 1
+    assert default_data_lanes(2) == 1
+    assert default_data_lanes(4) == 3
+    assert default_data_lanes(13) == 5
+    assert default_data_lanes(64) == 5
+
+
 def test_default_drain_pool_bounds():
     assert default_drain_pool(1) == 1
     assert default_drain_pool(2) == 1
@@ -501,6 +510,7 @@ def test_map_closed_after_clean_and_failed_attempts(tmp_path, monkeypatch,
     that failed with a mapped dispatch in flight (the retry resumes from
     the checkpoint): no BufferError, no map of the .dat left, whether the
     data writes keep pace with the drain or lag it."""
+    import threading
     import time
 
     base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
@@ -509,12 +519,14 @@ def test_map_closed_after_clean_and_failed_attempts(tmp_path, monkeypatch,
     enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
     assert not _dat_mapped(base + ".dat")
 
-    real, calls = StreamingEncoder._write_data, []
+    real, calls, lock = StreamingEncoder._write_data, [], threading.Lock()
 
     def failing_third(self, outputs, sb, src, used):
-        calls.append(1)
+        with lock:  # the data lanes call it concurrently
+            calls.append(1)
+            n = len(calls)
         time.sleep(lag_s)
-        if len(calls) == 3:  # dispatch 2's data
+        if n == 3:  # dispatch 2's data on one lane, an early one on several
             raise OSError("injected data write failure")
         return real(self, outputs, sb, src, used)
 
@@ -682,3 +694,195 @@ def test_failed_mapped_rebuild_leaves_nothing(tmp_path, at):
     assert enc.rebuild_files(base) == [3, 12]
     assert _files(base) == want
     _assert_released(enc, base)
+
+
+# --- data shards written on shard-owned lanes ---
+# (dat bytes, devices): whole groups only, so every dispatch is mapped;
+# groups and a tail filled into a slot; the per-device queue plane
+LANE_PLANES = {
+    "map": (3 * GROUP, "1"),
+    "tail": (3 * GROUP + 3 * ROW + 77, "1"),
+    "queue": (3 * GROUP + 3 * ROW + 77, "4"),
+}
+
+
+def _data_writes(spans):
+    return [s for s in spans
+            if s.name == "pipeline.write" and s.attrs.get("kind") == "data"]
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 5])
+@pytest.mark.parametrize("plane", sorted(LANE_PLANES))
+def test_laned_encode_byte_identical(tmp_path, plane, lanes):
+    """At one, two and five data lanes, on mapped row groups, a slot
+    tail and the queue plane: shards and .eci equal the CPU encoder's;
+    every dispatch is written once by each lane, under a span that
+    says its lane, on the lane's own thread, never the producer's."""
+    size, devices = LANE_PLANES[plane]
+    base = _write_dat(tmp_path, size)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = StreamingEncoder(10, 4, engine="device", devices=devices,
+                           tracer=tracer)
+    enc.dispatch_b = MAP_B
+    enc._data_lanes = lanes
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    assert _files(base) == _files(ref)
+    assert enc.stats["data_lanes"] == lanes
+    assert enc.stats["mapped_dispatches"] == \
+        (0 if plane == "queue" else 3)
+    spans = tracer.snapshot()
+    writes = _data_writes(spans)
+    d = enc.stats["dispatches"]
+    assert sorted((s.attrs["dispatch"], s.attrs["lane"]) for s in writes) \
+        == [(i, j) for i in range(d) for j in range(lanes)]
+    producer = next(s.tid for s in spans if s.name == "pipeline.encode_file")
+    assert not any(s.tid == producer for s in writes)
+    assert len({s.tid for s in writes}) == lanes
+    assert any(s.name == "pipeline.data_wait" and s.tid == producer
+               and s.attrs.get("wait") == "final" for s in spans)
+
+
+@pytest.mark.parametrize("lanes", [1, 5])
+def test_lane_data_bytes_add_up(tmp_path, lanes):
+    """The lanes' data-bytes counter adds up to the data shards' bytes,
+    at one lane as at five, and /status EcPipeline reports it as
+    lane_data_bytes."""
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+
+    base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
+    enc = _device_enc()
+    enc._data_lanes = lanes
+    m = ec_pipeline_metrics()
+    c0 = m.lane_data_bytes.value()
+    t0 = m.totals()
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    shard = os.path.getsize(base + to_ext(0))
+    assert m.lane_data_bytes.value() - c0 == 10 * shard
+    assert m.totals()["lane_data_bytes"] - t0["lane_data_bytes"] == \
+        10 * shard
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 5])
+def test_lane_write_failure_resumes_from_checkpoint(tmp_path, monkeypatch,
+                                                    lanes):
+    """A data write that raises on a lane, once the checkpoint has
+    passed byte 0, fails the attempt as a drain fault would: the encode
+    retries from the checkpoint, and shards and .eci come out as the
+    CPU encoder's, with the .dat's map closed."""
+    import threading
+
+    base = _write_dat(tmp_path, 6 * GROUP + 3 * ROW + 77)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = _device_enc(tracer=tracer, depth=1)
+    enc._data_lanes = lanes
+    real, lock = StreamingEncoder._write_data, threading.Lock()
+    per_thread: dict = {}
+    fired = []
+
+    def failing(self, outputs, sb, src, used):
+        with lock:
+            n = per_thread[threading.get_ident()] = \
+                per_thread.get(threading.get_ident(), 0) + 1
+            fire = n == 3 and not fired  # this lane's dispatch 2
+            if fire:
+                fired.append(1)
+        if fire:
+            deadline = time.monotonic() + 30
+            while enc._ckpt[1] == 0 and time.monotonic() < deadline:
+                time.sleep(0.002)
+            raise OSError("injected lane write failure")
+        return real(self, outputs, sb, src, used)
+
+    monkeypatch.setattr(StreamingEncoder, "_write_data", failing)
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    assert fired
+    assert enc.stats["retries"] == 1
+    retries = [s for s in tracer.snapshot() if s.name == "pipeline.retry"]
+    assert len(retries) == 1 and retries[0].attrs["resume_byte"] > 0
+    assert "injected lane write failure" in retries[0].attrs["error"]
+    assert _files(base) == _files(ref)
+    assert not _dat_mapped(base + ".dat")
+
+
+@pytest.mark.parametrize("plane", ["slot", "queue"])
+def test_slow_lanes_hold_slots_until_written(tmp_path, monkeypatch, plane):
+    """Lanes that write slower than the device fetches: a slot goes back
+    to its pool only once the lanes have written it (a `pipeline.
+    data_wait` with wait=slot), so the producer never refills one the
+    lanes still read, and the bytes equal the CPU encoder's.  "slot":
+    large-block rows chunked into the staged plane's slots; "queue":
+    the per-device queue plane, whose every dispatch is a slot."""
+    real = StreamingEncoder._write_data
+
+    def slow(self, outputs, sb, src, used):
+        time.sleep(0.01)
+        return real(self, outputs, sb, src, used)
+
+    monkeypatch.setattr(StreamingEncoder, "_write_data", slow)
+    large = 2 * MAP_B
+    base = _write_dat(tmp_path, 3 * 10 * large)  # 2 large rows, 8 small
+    ref = _cpu_reference(tmp_path, base, large, MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = StreamingEncoder(10, 4, engine="device", tracer=tracer,
+                           devices="1" if plane == "slot" else "2",
+                           depth=1)
+    enc.dispatch_b = MAP_B
+    enc._data_lanes = 2
+    enc.encode_file(base + ".dat", base, large, MAP_SMALL)
+    assert _files(base) == _files(ref)
+    waits = [s.attrs.get("wait") for s in tracer.snapshot()
+             if s.name == "pipeline.data_wait"]
+    assert "slot" in waits
+
+
+def test_lanes_stress_at_short_switch_interval(tmp_path):
+    """Ten lanes, more than this host's cores may hold, switching threads
+    every few microseconds, over the queue plane's slots and a tail:
+    each shard's bytes and crc state stay with their lane, so shards and
+    .eci still equal the CPU encoder's, and no data byte is counted
+    twice or lost."""
+    import sys
+
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+
+    base = _write_dat(tmp_path, 6 * GROUP + 3 * ROW + 77)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    enc = StreamingEncoder(10, 4, engine="device", devices="2", depth=1)
+    enc.dispatch_b = MAP_B
+    enc._data_lanes = 10
+    lane0 = ec_pipeline_metrics().lane_data_bytes.value()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(5e-6)
+    try:
+        enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    finally:
+        sys.setswitchinterval(interval)
+    assert _files(base) == _files(ref)
+    assert ec_pipeline_metrics().lane_data_bytes.value() - lane0 == \
+        10 * os.path.getsize(base + to_ext(0))
+
+
+@pytest.mark.parametrize("native", [True, False])
+def test_encode_names_its_block_crc(tmp_path, monkeypatch, native):
+    """The path of the .eci block crcs shows where an encode ran: on
+    its `pipeline.encode_file` span and in /status EcPipeline, native
+    where the data plane library is loaded and the fallback's name where
+    it is not; the sidecar is the same either way."""
+    from seaweedfs_tpu.stats import ec_pipeline_metrics
+    from seaweedfs_tpu.storage import crc
+
+    assert crc.load_block_crc() == "native"  # the library is built here
+    if not native:
+        monkeypatch.setattr(crc, "_blocks", False)
+    want = "native" if native else crc._FALLBACK
+    base = _write_dat(tmp_path, 3 * GROUP + 3 * ROW + 77)
+    ref = _cpu_reference(tmp_path, base, MAP_LARGE, MAP_SMALL)
+    tracer = Tracer(capacity=1 << 12)
+    enc = _device_enc(tracer=tracer)
+    enc.encode_file(base + ".dat", base, MAP_LARGE, MAP_SMALL)
+    assert _files(base) == _files(ref)
+    roots = [s for s in tracer.snapshot() if s.name == "pipeline.encode_file"]
+    assert [s.attrs["crc"] for s in roots] == [want]
+    assert ec_pipeline_metrics().totals()["block_crc"] == want

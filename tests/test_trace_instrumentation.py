@@ -67,12 +67,12 @@ class TestPipelineSpans:
             assert fill.attrs["bytes"] > 0
 
     def test_span_sum_explains_wall_within_10pct(self, tmp_path):
-        """Acceptance: the producer thread's spans — per-dispatch fill,
-        dispatch and data write, and the drain waits — sum to within
-        10% of the pipeline's reported wall_s on a CPU-only run (they
-        are disjoint on that thread, so the sum IS the wall minus
+        """Acceptance: the producer thread's spans — per-dispatch fill
+        and dispatch, and its drain and data waits — sum to within 10%
+        of the pipeline's reported wall_s on a CPU-only run (they are
+        disjoint on that thread, so the sum IS the wall minus
         setup/teardown); the drain and parity write spans land on the
-        drainer's threads.
+        drainer's threads, the data writes on the data lanes.
 
         Measured in a FRESH SUBPROCESS on tmpfs: late in a full suite
         run this pytest process carries dozens of lingering daemon
@@ -117,7 +117,8 @@ try:
                 by_stage[st] = by_stage.get(st, 0.0) + sp.duration
                 if sp.tid == producer:
                     on_producer += sp.duration
-            elif sp.name == "pipeline.drain_wait":
+            elif sp.name in ("pipeline.drain_wait", "pipeline.data_wait") \
+                    and sp.tid == producer:
                 on_producer += sp.duration
         counted = on_producer + sum(
             sp.duration for sp in spans
@@ -165,8 +166,9 @@ finally:
         t0 = time.perf_counter()
         enc.encode_file(base + ".dat", base)
         wall = time.perf_counter() - t0
-        sites_per_dispatch = 6  # fill/dispatch/write(x2)/drain + slack
-        n_spans = enc.stats["dispatches"] * sites_per_dispatch + 1
+        # fill/dispatch/parity write/drain, a data write a lane, + slack
+        sites_per_dispatch = 5 + enc.stats["data_lanes"]
+        n_spans = enc.stats["dispatches"] * sites_per_dispatch + 2
         tr = Tracer(enabled=False)
         t0 = time.perf_counter()
         for i in range(20_000):
